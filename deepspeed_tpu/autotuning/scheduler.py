@@ -134,6 +134,22 @@ class ResourceManager:
         """Run all scheduled-but-not-done experiments; returns them."""
         todo = [e for e in self.experiments if not e.done]
         lock = threading.Lock()
+        local = self.cmd_template is not None and any(
+            h in ("localhost", "127.0.0.1")
+            for h in (self.hosts or ["localhost"]))
+        if local and todo:
+            from deepspeed_tpu.utils.chip import holds_accelerator
+            if holds_accelerator():
+                # one process per chip: a child spawned on this machine
+                # would fail or hang on the chips this parent holds
+                raise RuntimeError(
+                    "this process has initialised a jax accelerator "
+                    "backend and holds its chips, so experiment "
+                    "subprocesses on this machine cannot open them. Spawn "
+                    "them from a parent that stays off jax (no "
+                    "jax.device_count()/Autotuner.tune() before "
+                    "ResourceManager.run), or run experiments in-process "
+                    "with run_fn")
         if self.run_fn is not None and self.num_slots > 1:
             logger.warning(
                 "in-process experiments share one device; forcing "

@@ -28,7 +28,7 @@ def op_report():
     for name, builder_cls in ALL_OPS.items():
         b = builder_cls()
         compatible = OKAY if b.is_compatible() else NO
-        built = OKAY if (b.lib_path().exists() and
+        built = OKAY if (b.source_path().exists() and
                          not b.needs_build()) else NO
         print(name + "." * (max_dots - len(name)) +
               f" {compatible}  | {built}")
@@ -117,7 +117,7 @@ def telemetry_report():
         "ttft_slo_breach failover across replicas)")
     row("fleet flight recorder", True,
         "(telemetry.fleet block; per-rank record shipping + skew/desync "
-        "sentinels -> FLEET_HEALTH.json; bench_diff CLI)")
+        "sentinels -> FLEET_HEALTH.json)")
     row("goodput autotuner (2-stage)", True,
         "(autotuning block; compile-time pruning + measured probes -> "
         "TUNE_REPORT.json)")

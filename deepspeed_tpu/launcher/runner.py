@@ -34,6 +34,7 @@ DLTS_HOSTFILE = "/job/hostfile"
 EXPORT_ENVS = ["NCCL", "PYTHON", "JAX", "XLA", "TPU", "PATH", "LD_LIBRARY"]
 DEEPSPEED_ENVIRONMENT_NAME = ".deepspeed_env"
 PDSH_MAX_FAN_OUT = 1024
+TPU_PROCESS_PORT0 = 8476   # libtpu's own inter-process ports, local mode
 
 
 def parse_args(args=None):
@@ -285,12 +286,53 @@ def main(args=None):
         else:  # local
             procs.append(subprocess.Popen(
                 [sys.executable, args.user_script] + args.user_args,
-                env=dict(os.environ, **env)))
+                env=dict(os.environ, **env,
+                         **local_chip_pinning(idx, len(workers)))))
+    sys.exit(wait_all(procs))
+
+
+def local_chip_pinning(idx, n_procs):
+    """Env that gives child ``idx`` of ``n_procs`` on THIS machine its own
+    TPU chip. A chip belongs to one process at a time: unpinned, every
+    child opens every chip, all but one die on libtpu's lockfile and the
+    survivor waits for them in the rendezvous (seen on a four-chip v5e
+    host; pinned, four children formed one four-device job — PERF.md
+    bring-up). libtpu reads these at backend start; the CPU backend
+    ignores them."""
+    # process grid libtpu is told about: (n,1,1), except four processes on
+    # a 2x2 host, which must match the physical mesh
+    bounds = "2,2,1" if n_procs == 4 else f"{n_procs},1,1"
+    return {
+        "TPU_VISIBLE_CHIPS": str(idx),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": bounds,
+        "TPU_PROCESS_ADDRESSES": ",".join(
+            f"localhost:{TPU_PROCESS_PORT0 + i}" for i in range(n_procs)),
+        "TPU_PROCESS_PORT": str(TPU_PROCESS_PORT0 + idx),
+        "CLOUD_TPU_TASK_ID": str(idx),
+    }
+
+
+def wait_all(procs):
+    """Wait for every worker; when one fails, stop the rest instead of
+    leaving them blocked in a rendezvous whose peer is gone. Returns the
+    first non-zero exit code (0 when all succeeded)."""
+    import time
     rc = 0
-    for p in procs:
-        p.wait()
-        rc = rc or p.returncode
-    sys.exit(rc)
+    live = list(procs)
+    while live:
+        for p in list(live):
+            code = p.poll()
+            if code is None:
+                continue
+            live.remove(p)
+            if code and not rc:
+                rc = code
+                for q in live:
+                    q.terminate()
+        if live:
+            time.sleep(0.2)
+    return rc
 
 
 if __name__ == "__main__":

@@ -185,34 +185,17 @@ _warned_grouped_ep = False
 
 # dw = x^T @ dy contracted over the RAGGED token dim, grouped output
 # [E, in, out] — the '[m,k],[k,n]->[g,m,n]' ragged_dot_general mode.
-# jax < 0.5 has ragged_dot but not ragged_dot_general / its dimension-
-# numbers type; _ragged_dw falls back to a one-hot-membership einsum
-# there (same contraction, E x the flops — a compat path, not the fast
-# one) so importing this module never crashes on an older jax.
-_DW_DIMS = None
-if hasattr(jax.lax, "RaggedDotDimensionNumbers") \
-        and hasattr(jax.lax, "ragged_dot_general"):
-    _DW_DIMS = jax.lax.RaggedDotDimensionNumbers(
-        dot_dimension_numbers=(((0,), (0,)), ((), ())),
-        lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
+_DW_DIMS = jax.lax.RaggedDotDimensionNumbers(
+    dot_dimension_numbers=(((0,), (0,)), ((), ())),
+    lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
 
 
 def _ragged_dw(lhs, rhs, group_sizes, out_dtype):
     """Grouped weight-grad contraction: ``dw[e] = lhs[rows of group e]^T
     @ rhs[rows of group e]`` -> [E, M, N], accumulated in fp32."""
-    if _DW_DIMS is not None:
-        return jax.lax.ragged_dot_general(
-            lhs, rhs, group_sizes, _DW_DIMS,
-            preferred_element_type=jnp.float32).astype(out_dtype)
-    # one-hot group membership from the ragged boundaries; rows past
-    # sum(group_sizes) belong to no group, matching ragged semantics
-    ends = jnp.cumsum(group_sizes)
-    starts = ends - group_sizes
-    rows = jnp.arange(lhs.shape[0])
-    member = ((rows[:, None] >= starts[None, :])
-              & (rows[:, None] < ends[None, :])).astype(jnp.float32)
-    return jnp.einsum("se,sm,sn->emn", member, lhs.astype(jnp.float32),
-                      rhs.astype(jnp.float32)).astype(out_dtype)
+    return jax.lax.ragged_dot_general(
+        lhs, rhs, group_sizes, _DW_DIMS,
+        preferred_element_type=jnp.float32).astype(out_dtype)
 
 
 @jax.custom_vjp

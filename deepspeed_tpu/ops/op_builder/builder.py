@@ -1,24 +1,28 @@
 """JIT build system for native host ops.
 
 Rebuild of op_builder/builder.py (``OpBuilder`` :119, ``jit_load`` :405):
-compiles csrc/*.cpp into shared libraries with g++ on first use, caches by
-source mtime, and loads them via ctypes (the reference uses torch
-cpp_extension + pybind11; this build is torch-free so the ABI is plain C).
+compiles csrc/*.cpp into shared libraries with g++ on first use and loads
+them via ctypes (the reference uses torch cpp_extension + pybind11; this
+build is torch-free so the ABI is plain C). Libraries are built under the
+checkout (``.ds_build/``, git-ignored) and named by the hash of their
+source, so a ``.so`` built from another checkout's source is never loaded
+against this one — mtimes say nothing in a freshly unpacked tree.
 SIMD width is whatever -march=native provides (reference simd_width
 detection, builder.py:318); ops degrade to scalar loops when AVX2 is
 absent.
 """
 
 import ctypes
+import hashlib
 import os
 import subprocess
 from pathlib import Path
 
+from deepspeed_tpu.utils.chip import CHECKOUT
 from deepspeed_tpu.utils.logging import logger
 
-CSRC = Path(__file__).resolve().parents[3] / "csrc"
-BUILD_DIR = Path(os.environ.get(
-    "DS_BUILD_DIR", Path.home() / ".cache" / "deepspeed_tpu" / "build"))
+CSRC = CHECKOUT / "csrc"
+BUILD_DIR = Path(os.environ.get("DS_BUILD_DIR", CHECKOUT / ".ds_build"))
 
 
 class OpBuilderError(RuntimeError):
@@ -36,22 +40,25 @@ class CPUOpBuilder:
         return CSRC / self.SOURCE
 
     def lib_path(self) -> Path:
-        return BUILD_DIR / f"{self.NAME}.so"
+        digest = hashlib.sha256(self.source_path().read_bytes()).hexdigest()
+        return BUILD_DIR / f"{self.NAME}-{digest[:16]}.so"
 
     def is_compatible(self) -> bool:
         return self.source_path().exists() and _has_compiler()
 
     def needs_build(self) -> bool:
-        lib, src = self.lib_path(), self.source_path()
-        return (not lib.exists() or
-                src.stat().st_mtime > lib.stat().st_mtime)
+        return not self.lib_path().exists()
 
     def build(self) -> Path:
         BUILD_DIR.mkdir(parents=True, exist_ok=True)
         src, lib = self.source_path(), self.lib_path()
+        # build beside the target and rename: another process (the
+        # multi-process tests build concurrently) never dlopens a
+        # half-written library
+        tmp = lib.with_name(f"{lib.name}.{os.getpid()}.tmp")
         cmd = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC",
                "-march=native", "-fopenmp", "-pthread",
-               str(src), "-o", str(lib)] + list(self.EXTRA_FLAGS)
+               str(src), "-o", str(tmp)] + list(self.EXTRA_FLAGS)
         try:
             subprocess.run(cmd, check=True, capture_output=True, text=True)
         except subprocess.CalledProcessError as e:  # fall back: no -march
@@ -64,10 +71,11 @@ class CPUOpBuilder:
                     f"building {self.NAME} failed:\n{e2.stderr}") from e2
             logger.warning(f"{self.NAME}: built without -march=native "
                            f"({e.stderr.splitlines()[-1] if e.stderr else ''})")
+        os.replace(tmp, lib)
         return lib
 
     def load(self) -> ctypes.CDLL:
-        """jit_load (builder.py:405): build if stale, dlopen, memoise."""
+        """jit_load (builder.py:405): build if absent, dlopen, memoise."""
         if self.NAME in _LOADED:
             return _LOADED[self.NAME]
         if not self.is_compatible():

@@ -19,11 +19,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _on_tpu():
@@ -68,8 +64,10 @@ def _quantize_rows(x, num_bits, symmetric, stochastic, noise):
 def _quant_kernel(seed_ref, x_ref, y_ref, *, num_bits, symmetric, stochastic):
     if stochastic:
         pltpu.prng_seed(seed_ref[0, 0] + pl.program_id(0))
+        # int32 bits; the logical shift keeps the top 24 as a non-negative
+        # int32 (Mosaic has no uint32 -> float32 cast)
         bits = pltpu.prng_random_bits(x_ref.shape)
-        noise = (pltpu.bitcast(bits, jnp.uint32) >> 8).astype(jnp.float32) \
+        noise = jax.lax.shift_right_logical(bits, 8).astype(jnp.float32) \
             * (1.0 / (1 << 24))
     else:
         noise = None
@@ -99,7 +97,10 @@ def quantize(x, num_bits=8, groups=1, symmetric=True, stochastic=False,
 
     if _on_tpu() and row % 128 == 0 and groups >= 1:
         bg = 1
-        while groups % (bg * 2) == 0 and bg * 2 * row <= (1 << 20):
+        # rows per block: <= 1 MiB of fp32, since the in and out blocks
+        # are double-buffered beside the kernel's own fp32 temporaries
+        # (a 4 MiB block ran out of the 16 MiB scoped VMEM on a v5e)
+        while groups % (bg * 2) == 0 and bg * 2 * row <= (1 << 18):
             bg *= 2
         kernel = functools.partial(_quant_kernel, num_bits=num_bits,
                                    symmetric=symmetric, stochastic=stochastic)

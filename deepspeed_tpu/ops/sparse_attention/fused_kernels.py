@@ -48,14 +48,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-from jax.sharding import PartitionSpec as P
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # pltpu imports on TPU-enabled jaxlibs; interpret mode still uses the
-    # same code path on CPU
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
-
+from deepspeed_tpu.ops._mesh import map_over_mesh
 from deepspeed_tpu.ops._platform import interpret as _interpret
 
 NEG_INF = -1e30
@@ -64,13 +59,6 @@ _MAX_BITS = 32   # fine blocks per (q-tile, kv-tile) pair — one int32 word
 _F_LIVE = 1      # flags: this step does real work
 _F_BEGIN = 2     # flags: first step of its output-tile run (reset scratch)
 _F_END = 4       # flags: last step of its run (write the output tile)
-
-
-def _require_pltpu():
-    if pltpu is None:  # pragma: no cover — guarded import above
-        raise RuntimeError(
-            "fused block-sparse attention needs jax.experimental.pallas.tpu "
-            "(scalar prefetch + VMEM scratch); this jaxlib cannot import it")
 
 
 # ---------------------------------------------------------------- LUT builder
@@ -447,7 +435,6 @@ class _FusedSparse:
         return own_q, own_qstat, own_kv, oth_kv, oth_q, oth_qstat
 
     def _fwd(self, q, k, v, kpb):
-        _require_pltpu()
         B, H, Sq, D = q.shape
         Skv = k.shape[2]
         assert (H, Sq, Skv) == (self.H, self.Sq, self.Skv), (
@@ -512,7 +499,6 @@ class _FusedSparse:
             g, g_lse = g
         else:
             g_lse = None
-        _require_pltpu()
         q, k, v, kpb, out, lse = res
         B, H, Sq, D = q.shape
         Skv = k.shape[2]
@@ -606,7 +592,6 @@ class _FusedSparse:
 
 
 _strategy_cache = {}
-_replicate_warned = set()
 
 
 def _get_strategy(layout, block, causal, sm_scale, causal_nblocks=None):
@@ -771,7 +756,7 @@ def block_sparse_attention_fused(q, k, v, layout, key_padding_bias=None,
 
     if len(gc) == 0 and len(gr) == 0:
         strat = _get_strategy(rem, block, causal, sm_scale)
-        return _map_over_data_axis(strat.attend, B)(q, k, v, kpb)
+        return map_over_mesh(strat.attend, B)(q, k, v, kpb)
 
     if len(gc):
         # pack the global columns after the real sequence: per-head
@@ -829,52 +814,4 @@ def block_sparse_attention_fused(q, k, v, layout, key_padding_bias=None,
 
     # the dense global-row part's [B,H,R,S] fp32 score tensor must not be
     # saved for backward across every layer — recompute, like flash
-    return _map_over_data_axis(jax.checkpoint(_attend), B)(q, k, v, kpb)
-
-
-def _map_over_data_axis(fn, batch):
-    """shard_map ``fn(q, k, v, kpb)`` over the mesh data axis when one is
-    active: GSPMD cannot partition a pallas_call, so under a dp mesh the
-    unwrapped kernel would silently REPLICATE — every chip all-gathering
-    the batch and computing all of it. The kernel is independent per
-    (batch, head), so batch sharding maps exactly. No-op without a mesh,
-    with a 1-wide data axis, or when the batch does not divide (e.g.
-    sequence-parallel configs that borrow the data axis)."""
-    from deepspeed_tpu.utils import groups
-    from deepspeed_tpu.utils.jax_compat import (get_shard_map,
-                                                under_manual_sharding)
-    if not groups.mesh_is_initialized() or under_manual_sharding():
-        # already inside a shard_map body (1-bit / sparse-grad step fns
-        # shard the whole model over the data axis themselves): a nested
-        # shard_map over the same axes crashes at trace time
-        return fn
-    mesh = groups.get_mesh()
-    axes = tuple(a for a in groups.data_parallel_axes()
-                 if mesh.shape[a] > 1)
-    dp = 1
-    for a in axes:
-        dp *= mesh.shape[a]
-    if dp <= 1:
-        return fn
-    if batch % dp:
-        key = ("nondivisible", batch, dp)
-        if key not in _replicate_warned:
-            _replicate_warned.add(key)
-            from deepspeed_tpu.utils.logging import logger
-            logger.warning(
-                "fused block-sparse attention: batch %d does not divide "
-                "the data-parallel world %d — the pallas kernel will run "
-                "REPLICATED (every device computes the full batch); size "
-                "the per-device batch to a multiple of dp", batch, dp)
-        return fn
-    shard_map, smap_kw = get_shard_map()
-    spec4 = P(axes, None, None, None)
-    spec2 = P(axes, None)
-
-    def wrapped(q, k, v, kpb):
-        in_specs = (spec4, spec4, spec4,
-                    None if kpb is None else spec2)
-        return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=spec4, **smap_kw)(q, k, v, kpb)
-
-    return wrapped
+    return map_over_mesh(jax.checkpoint(_attend), B)(q, k, v, kpb)

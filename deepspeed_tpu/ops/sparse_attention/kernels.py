@@ -20,11 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-
-try:  # needed for SMEM layout residency on TPU; interpret mode works without
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 from deepspeed_tpu.ops._platform import interpret as _interpret
 
@@ -291,7 +287,7 @@ def _specs(H, block, nq, D, S):
     # from scalar memory (a VMEM i32 load must be 128-lane aligned —
     # failed to compile at seq 512). nq^2 i32 is a few KB.
     lay = pl.BlockSpec((1, nq, nq), lambda b, i: (b % H, 0, 0),
-                       memory_space=(pltpu.SMEM if pltpu else None))
+                       memory_space=pltpu.SMEM)
     qb = pl.BlockSpec((1, block, D), lambda b, i: (b, i, 0))
     full = pl.BlockSpec((1, S, D), lambda b, i: (b, 0, 0))
     stat = pl.BlockSpec((1, block, LANES), lambda b, i: (b, i, 0))

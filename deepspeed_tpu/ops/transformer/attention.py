@@ -12,7 +12,6 @@ identical jnp path runs.
 Layout convention: ``[batch, heads, seq, head_dim]`` throughout.
 """
 
-import functools
 from typing import Optional
 
 import jax
@@ -44,21 +43,14 @@ def mha_reference(q, k, v, *, causal=True, sm_scale=None, bias=None,
     return jnp.einsum("bhqk,bhkd->bhqd", weights.astype(v.dtype), v)
 
 
-@functools.lru_cache(maxsize=None)
-def _flash_importable():
-    try:
-        from deepspeed_tpu.ops.transformer import flash  # noqa: F401
-        return True
-    except Exception:
-        return False
-
-
 def _flash_available():
     # effective_platform (not default_backend): code hosted onto the CPU
     # device of a TPU process — e.g. the layered-offload zero_init — must
-    # not pick TPU Pallas lowering
+    # not pick TPU Pallas lowering. On a TPU the kernel is THE path: a
+    # flash module that fails to import raises at the call, it does not
+    # drop to O(S^2) XLA attention.
     from deepspeed_tpu.ops._platform import effective_platform
-    return effective_platform() == "tpu" and _flash_importable()
+    return effective_platform() == "tpu"
 
 
 def _want_flash(seq_k: int, has_bias: bool, has_mask: bool) -> bool:
@@ -95,7 +87,10 @@ def attention(q, k, v, *, causal=True, sm_scale=None, bias=None, mask=None,
             raise ValueError(
                 "the flash kernel has no bias/mask input; drop "
                 "DS_ATTN_IMPL=flash / use_flash=True for masked attention")
+        from deepspeed_tpu.ops._mesh import map_over_mesh
         from deepspeed_tpu.ops.transformer import flash
-        return flash.flash_attention(q, k, v, causal=causal, sm_scale=sm_scale)
+        return map_over_mesh(
+            lambda q, k, v: flash.flash_attention(q, k, v, causal, sm_scale),
+            batch=q.shape[0], heads=q.shape[1])(q, k, v)
     return mha_reference(q, k, v, causal=causal, sm_scale=sm_scale,
                          bias=bias, mask=mask)

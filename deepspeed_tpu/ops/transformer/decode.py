@@ -14,9 +14,11 @@ Design notes (TPU):
   the score GEMM is MXU/VPU tile-aligned (one wasted factor of 8 on a
   bandwidth-bound op — the kernel streams K/V once, which is the actual
   cost at decode time).
-* ``cache_len`` arrives in SMEM; the kv loop runs ``cdiv(len, block_k)``
-  iterations, so per-token work scales with the *live* cache length, not
-  the allocated cache size.
+* the per-sequence ``cache_len`` vector is scalar-prefetched into SMEM
+  whole (a rank-1 SMEM *block* of one element is refused by Mosaic's
+  128-tiling rule); the kv loop runs ``cdiv(len, block_k)`` iterations,
+  so per-token work scales with the *live* cache length, not the
+  allocated cache size.
 * off-TPU the mathematically identical masked jnp path runs (also the
   parity oracle in tests/unit/test_inference.py).
 """
@@ -26,16 +28,10 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from deepspeed_tpu.ops._platform import interpret as _interpret
 from deepspeed_tpu.ops.transformer.attention import mha_reference
-
-try:  # pltpu imports on TPU-enabled jaxlibs; interpret mode needs no TPU
-    from jax.experimental.pallas import tpu as pltpu
-    _SMEM = pltpu.SMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _SMEM = None
 
 NEG_INF = -1e30
 QROWS = 8  # sublane tile height; the 1 live query row is replicated into it
@@ -52,8 +48,11 @@ def aligned_cache_len(n_positions: int) -> int:
 
 
 def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *, sm_scale,
-                   block_k, quantized=False, ks_ref=None, vs_ref=None):
-    length = len_ref[0]
+                   block_k, n_head, quantized=False, ks_ref=None,
+                   vs_ref=None):
+    # len_ref: the whole [B] length vector (scalar prefetch); program
+    # b*H + h serves sequence b
+    length = len_ref[pl.program_id(0) // n_head]
     q = q_ref[0]  # [QROWS, D]
 
     def body(j, carry):
@@ -165,19 +164,15 @@ def decode_attention(q, k_cache, v_cache, cache_len, *, k_scale=None,
     qf = jnp.broadcast_to(q.reshape(B * H, 1, D), (B * H, QROWS, D))
     kf = k_cache.reshape(B * H, Tp, D)
     vf = v_cache.reshape(B * H, Tp, D)
-    # one length per (b, h) program: a scalar broadcasts to every program,
-    # a [B] vector repeats per head — the kernel body reads len_ref[0]
-    # either way, so the per-sequence path costs nothing extra
-    if lens.ndim == 1:
-        len_arr = jnp.broadcast_to(lens[:, None], (B, H)).reshape(B * H)
-    else:
-        len_arr = jnp.broadcast_to(lens, (B * H,))
+    # one length per sequence: a scalar broadcasts to every sequence, so
+    # the per-sequence path costs nothing extra
+    len_arr = jnp.broadcast_to(lens, (B,))
 
-    cache_spec = pl.BlockSpec((1, Tp, D), lambda b: (b, 0, 0))
-    scale_spec = pl.BlockSpec((1, 1, Tp), lambda b: (b, 0, 0))
-    in_specs = [pl.BlockSpec((1,), lambda b: (b,), memory_space=_SMEM),
-                pl.BlockSpec((1, QROWS, D), lambda b: (b, 0, 0)),
-                cache_spec, cache_spec]
+    # index maps take the scalar-prefetch ref as a trailing argument
+    cache_spec = pl.BlockSpec((1, Tp, D), lambda b, lens: (b, 0, 0))
+    scale_spec = pl.BlockSpec((1, 1, Tp), lambda b, lens: (b, 0, 0))
+    q_spec = pl.BlockSpec((1, QROWS, D), lambda b, lens: (b, 0, 0))
+    in_specs = [q_spec, cache_spec, cache_spec]
     operands = [len_arr, qf, kf, vf]
     if quantized:
         in_specs += [scale_spec, scale_spec]
@@ -186,17 +181,19 @@ def decode_attention(q, k_cache, v_cache, cache_len, *, k_scale=None,
 
         def kernel(len_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref):
             _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
-                           sm_scale=sm_scale, block_k=block_k,
+                           sm_scale=sm_scale, block_k=block_k, n_head=H,
                            quantized=True, ks_ref=ks_ref, vs_ref=vs_ref)
     else:
         kernel = functools.partial(_decode_kernel, sm_scale=sm_scale,
-                                   block_k=block_k)
+                                   block_k=block_k, n_head=H)
 
     out = pl.pallas_call(
         kernel,
-        grid=(B * H,),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, QROWS, D), lambda b: (b, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B * H,),
+            in_specs=in_specs,
+            out_specs=q_spec),
         out_shape=jax.ShapeDtypeStruct((B * H, QROWS, D), q.dtype),
         interpret=_interpret(),
     )(*operands)

@@ -25,21 +25,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pltpu only imports on TPU-enabled jaxlibs; interpret mode needs no
-    # TPU — only the STREAMING kernels (VMEM scratch) require it
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
-
-
-def _require_pltpu():
-    if pltpu is None:  # pragma: no cover — guarded import above
-        raise RuntimeError(
-            "the streaming flash kernels (seq > 4096) need "
-            "jax.experimental.pallas.tpu for VMEM scratch accumulators; "
-            "this jaxlib cannot import it")
-
+from jax.experimental.pallas import tpu as pltpu
 
 from deepspeed_tpu.ops._platform import interpret as _interpret
 
@@ -404,7 +390,6 @@ def _flash_fwd(q, k, v, causal, sm_scale):
         out = o.reshape(B, H, Sq, D)
         return out, (q, k, v, out, lse)
 
-    _require_pltpu()
     num_kv = Sk // bk
     kernel = functools.partial(_fwd_kernel, sm_scale=sm_scale, causal=causal,
                                block_q=bq, block_k=bk, num_kv=num_kv,
@@ -510,7 +495,6 @@ def _flash_bwd(causal, sm_scale, res, g, g_lse=None):
         return (dq.reshape(B, H, Sq, D), dk.reshape(B, H, Sk, D),
                 dv.reshape(B, H, Sk, D))
 
-    _require_pltpu()
     num_kv = Sk // bk
     num_q = Sq // bq
     dq = pl.pallas_call(

@@ -91,8 +91,8 @@ class FlopsProfiler:
                         self._scope_durations = profile_durations_by_scope(
                             micro_fn, state, batch,
                             jax.random.PRNGKey(0), jnp.float32(1.0))
-                except Exception as e:  # profiling is best-effort: some
-                    # backends (remote tunnels) cannot trace
+                except Exception as e:  # profiling is best-effort: the
+                    # flops table must survive a failed trace capture
                     from deepspeed_tpu.utils.logging import logger
                     logger.warning(
                         "per-module duration profiling unavailable "

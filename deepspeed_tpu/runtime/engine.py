@@ -120,17 +120,17 @@ def _default_sparse_ids_fn(batch):
 class _AOTStep:
     """AOT execution wrapper around ONE jitted step entry point.
 
-    jax 0.4.x keeps the eager-jit executable cache and the AOT
-    (``lower().compile()``) cache fully separate — asking a live engine
-    "what did you compile?" via the AOT path would silently pay a full
-    DUPLICATE XLA compile (this is exactly what the old flops profiler
-    did). The fix is ownership: when the cost explorer is enabled, the
-    engine's first dispatch for a signature goes ``lower -> compile ->
-    call`` so the ``jax.stages.Compiled`` artifact is KEPT — same single
-    compile the jit would have done, but now ``cost_analysis()`` /
-    ``memory_analysis()`` / ``as_text()`` are readable forever at zero
-    cost, and the HBM pre-flight can run BETWEEN compile and first
-    execution.
+    When the cost explorer is enabled, the engine's first dispatch for a
+    signature goes ``lower -> compile -> call`` so the
+    ``jax.stages.Compiled`` artifact is KEPT — the same single compile
+    the jit would have done, but ``cost_analysis()`` /
+    ``memory_analysis()`` / ``as_text()`` stay readable at zero cost, and
+    the HBM pre-flight can run BETWEEN compile and first execution. (The
+    wrapper was written when the eager-jit and AOT executable caches
+    were disjoint and the AOT path paid a duplicate XLA compile. Under
+    the installed jax 0.9 ``lower().compile()`` of a signature that has
+    already run is served from the same cache — re-checked in PR 21 —
+    so only the pre-flight ordering still needs this ownership.)
 
     Per-call cost is one tree_flatten signature check (~µs, measured
     +0.6µs vs the raw jit fastpath) — only paid when the cost explorer
@@ -2832,8 +2832,8 @@ class DeepSpeedEngine:
         prefetch.py) arrives here as global jax arrays with exactly the
         shardings this function computes — the single-process
         ``device_put`` below then returns the SAME buffers without a
-        transfer (verified same-object in jax 0.4.37), so re-entering is
-        the cheap, validation-preserving way to "skip" placement."""
+        transfer (same object, re-checked under jax 0.9), so re-entering
+        is the cheap, validation-preserving way to "skip" placement."""
         import numpy as _np
         shardings = self._batch_sharding(batch)
         n_proc = jax.process_count()

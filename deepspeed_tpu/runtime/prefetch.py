@@ -22,7 +22,7 @@ worker-backed dataloaders):
   is the SAME pytree with device-placed global leaves — not a wrapper —
   so user code that inspects batches keeps working, and the engine's own
   ``device_put`` against the identical sharding is a no-transfer no-op
-  (verified same-buffer in jax 0.4.37). The stage runs on multi-process
+  (same buffer, re-checked under jax 0.9). The stage runs on multi-process
   meshes too: the engine passes ``verify=False`` placement, which is
   collective-free by construction — the broadcast-leaf checksum
   allgather and eval row-count agreement are deferred to the MAIN thread

@@ -54,9 +54,6 @@ Four pieces (see the per-module docstrings):
   (``python -m deepspeed_tpu.telemetry.memory_observatory`` is the
   CLI). Lazy like xplane/step_anatomy — only loads at the first cadence
   tick;
-* ``bench_diff`` — bench-regression differ over committed BENCH_r*.json
-  rounds (``python -m deepspeed_tpu.telemetry.bench_diff`` exits
-  non-zero past the regression threshold);
 * ``clock`` — the shared monotonic integer-µs axis every cross-stream
   timestamp joins on (plus the one wall anchor for rendering);
 * ``escalation`` — the ONE escalation protocol all five observatories

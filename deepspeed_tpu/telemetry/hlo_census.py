@@ -377,15 +377,12 @@ def census_compiled(compiled, mesh=None) -> HloCensus:
     """Census a ``jax.stages.Compiled`` (or anything exposing
     ``cost_analysis`` / ``memory_analysis`` / ``as_text``). Pure reading:
     never triggers tracing or compilation. Each analysis is best-effort —
-    a backend refusing one (some remote clients) zeroes that section
-    instead of failing the census."""
+    a backend refusing one zeroes that section instead of failing the
+    census."""
     from deepspeed_tpu.utils.logging import logger
     census = HloCensus()
     try:
-        costs = compiled.cost_analysis()
-        if isinstance(costs, (list, tuple)):   # older jax returns [dict]
-            costs = costs[0] if costs else {}
-        costs = dict(costs or {})
+        costs = dict(compiled.cost_analysis() or {})
         census.flops = float(costs.get("flops", 0.0))
         census.transcendentals = float(costs.get("transcendentals", 0.0))
         census.bytes_accessed = float(costs.get("bytes accessed", 0.0))

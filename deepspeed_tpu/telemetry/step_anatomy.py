@@ -183,20 +183,44 @@ class LaneEvent:
     end_ps: int
 
 
+# The op lane of an accelerator plane. A ``/device:TPU:n`` plane also
+# carries 'Steps', 'XLA Modules' (one event per program run), 'Async XLA
+# Ops' (start->done spans that OVERLAP the ops) and 'TC Overlay' lines;
+# counting those as lanes multiplies the device wall and books every op
+# two or three times (seen on a v5e, jax 0.9 / libtpu 0.0.34).
+DEVICE_OP_LINE = "XLA Ops"
+
+# Host executor lines interleave each op with bookkeeping events
+# ('end: <op>', ThreadpoolListener::*, Rendezvous) since jax 0.9, so ops
+# are 30-45% of such a line's events; the python thread, which also runs
+# small programs inline, sits under 1%.
+_HOST_LANE_MIN_OP_SHARE = 0.1
+
+
+def _instruction_name(event_name: str) -> str:
+    """The HLO instruction name of a device-plane event. The TPU plane
+    names an op by its full HLO text (``%fusion.3 = f32[8]{0} fusion(...),
+    kind=kLoop``); the join key is the bare name (``fusion.3``), which is
+    also what host executor lines already carry."""
+    m = _HLO_INSTR.match(event_name)
+    return m.group("name") if m else event_name
+
+
 def extract_events(space, step_mark: str = STEP_MARK):
     """Pull (steps, lanes) out of a parsed XSpace.
 
-    Device lanes are either lines of a ``/device:`` plane or host-plane
-    executor lines where ≥ half the events carry an ``hlo_op`` stat
-    (CPU jax runs XLA:CPU executors on host threads — ``tf_XLAEigen`` /
-    ``tf_XLATfrtCpuClient`` lines; the ``python`` line's few hlo-op
-    events are annotation echoes and stay excluded).  Steps come from
-    *step_mark* annotation events anywhere in the trace.
+    Device lanes are the ``XLA Ops`` line of each ``/device:`` plane, or
+    — CPU jax runs XLA:CPU executors on host threads (``tf_XLAEigen`` /
+    ``tf_XLAPjRtCpuClient`` lines) — host-plane lines where at least a
+    tenth of the events carry an ``hlo_op`` stat; only those events are
+    taken. Steps come from *step_mark* annotation events anywhere in the
+    trace.
 
     Returns ``(steps, lanes)`` where steps is
     ``[(label, start_ps, end_ps)]`` and lanes is
     ``{lane_name: [LaneEvent, ...]}`` with absolute-ps timestamps
-    (line timestamp_ns · 1000 + offset).
+    (line timestamp_ns · 1000 + offset) and events named by HLO
+    instruction.
     """
     steps: List[Tuple[object, int, int]] = []
     lanes: Dict[str, List[LaneEvent]] = {}
@@ -206,6 +230,8 @@ def extract_events(space, step_mark: str = STEP_MARK):
             if not line.events:
                 continue
             base = line.timestamp_ns * 1000
+            op_line = is_device and (line.display_name or line.name) \
+                == DEVICE_OP_LINE
             hlo_events = []
             for ev in line.events:
                 name = plane.event_name(ev)
@@ -214,14 +240,16 @@ def extract_events(space, step_mark: str = STEP_MARK):
                     label = stats.get("step")
                     start = base + ev.offset_ps
                     steps.append((label, start, start + ev.duration_ps))
-                elif is_device or "hlo_op" in plane.event_stats(ev):
+                elif op_line or (not is_device
+                                 and "hlo_op" in plane.event_stats(ev)):
                     hlo_events.append(LaneEvent(
-                        name, base + ev.offset_ps,
+                        _instruction_name(name), base + ev.offset_ps,
                         base + ev.offset_ps + ev.duration_ps))
             if not hlo_events:
                 continue
-            if not is_device and len(hlo_events) < 0.5 * len(line.events):
-                continue    # host line with incidental hlo stats
+            if not is_device and len(hlo_events) < \
+                    _HOST_LANE_MIN_OP_SHARE * len(line.events):
+                continue    # the python thread's few inline programs
             lane = f"{plane.name}/{line.display_name or line.name}"
             lanes.setdefault(lane, []).extend(hlo_events)
     steps.sort(key=lambda s: s[1])

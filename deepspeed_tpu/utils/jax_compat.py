@@ -1,29 +1,19 @@
-"""Version-compat shims for jax APIs that moved/renamed across releases.
+"""One home for jax APIs whose spelling has moved between releases, so a
+rename is fixed once instead of at every call site. Written for the
+installed jax (0.9): no branch for any other version."""
 
-One home for the dance (previously copy-pasted at every call site), so
-the next jax rename is fixed once."""
+from jax import shard_map
+from jax.sharding import AxisType, get_abstract_mesh
 
 
 def get_shard_map():
     """(shard_map, kwargs): the callable plus the replication-check-off
-    keyword spelled the way THIS jax spells it (``check_vma=False`` on
-    jax >= 0.8's ``jax.shard_map``, ``check_rep=False`` on the older
-    ``jax.experimental.shard_map``)."""
-    try:
-        from jax import shard_map
-        return shard_map, {"check_vma": False}
-    except ImportError:  # pragma: no cover — pre-0.8 jax
-        from jax.experimental.shard_map import shard_map
-        return shard_map, {"check_rep": False}
+    keyword (``check_vma=False``)."""
+    return shard_map, {"check_vma": False}
 
 
 def under_manual_sharding():
     """True when tracing INSIDE a shard_map body (the abstract mesh has
     Manual axes) — a nested shard_map over the same axes would crash at
     trace time, so mesh-aware wrappers must no-op there."""
-    try:
-        from jax.sharding import AxisType, get_abstract_mesh
-        return AxisType.Manual in tuple(
-            getattr(get_abstract_mesh(), "axis_types", ()) or ())
-    except Exception:  # pragma: no cover — very old jax
-        return False
+    return AxisType.Manual in get_abstract_mesh().axis_types

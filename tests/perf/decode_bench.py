@@ -4,10 +4,11 @@ The training bench (bench.py) covers the reference's training-kernel
 claims; this measures the inference side (the csrc/transformer/inference
 kernel surface): per-token latency of cached greedy decoding on one chip.
 
-Run on the TPU:  python tests/perf/decode_bench.py
+Run on the TPU:  python tests/perf/decode_bench.py  (exits non-zero
+without an accelerator — it measures a chip and does not fall back)
 Env: DECODE_MODEL (gpt2|gpt2-medium), DECODE_BS, DECODE_PROMPT,
 DECODE_NEW (defaults 8 / 32 / 128 new tokens).
-Prints one JSON line: tokens/s and ms/token.
+Prints one JSON line: tokens/s and ms/token, and the device it ran on.
 """
 
 import json
@@ -22,6 +23,11 @@ import numpy as np
 
 
 def main():
+    from deepspeed_tpu.utils.chip import (enable_compile_cache,
+                                          require_accelerator)
+    enable_compile_cache()
+    device = require_accelerator()
+
     import deepspeed_tpu
     from deepspeed_tpu.models.gpt2 import PRESETS
 
@@ -50,13 +56,13 @@ def main():
         0, cfg.vocab_size, (bs, prompt_len)), jnp.int32)
 
     def timed(n_new):
-        out = eng.generate(prompt, max_new_tokens=n_new)    # compile
-        jax.device_get(out[0, -1])   # drain the dispatch queue fully
+        jax.block_until_ready(
+            eng.generate(prompt, max_new_tokens=n_new))     # compile
         t0 = time.perf_counter()
         reps = 3
         for _ in range(reps):
             out = eng.generate(prompt, max_new_tokens=n_new)
-        jax.device_get(out[0, -1])
+        jax.block_until_ready(out)
         return (time.perf_counter() - t0) / reps
 
     dt = timed(new_tokens)
@@ -74,6 +80,7 @@ def main():
     print(json.dumps({
         "metric": f"{name} cached decode (bs={bs} prompt={prompt_len} "
                   f"new={new_tokens}, {dt_name}, kv={kv})",
+        "device": device,
         "tokens_per_s": round(total_new / dt, 1),
         "ms_per_token_step": round(per_step_ms, 3),
         "batch_latency_s": round(dt, 3),

@@ -1495,11 +1495,9 @@ class TestRepoRootArtifactLint:
 
     # families that predate the schema tag (pinned structurally above)
     NO_SCHEMA = {"BASELINE.json", "EXPLAIN.json", "NORTHSTAR_AOT.json"}
-    NO_SCHEMA_PREFIXES = ("BENCH_r", "MULTICHIP_r")
 
-    # every name (or prefix) some test pins — here, or for
-    # BENCH_r*/MULTICHIP_r* the bench-diff tests, or for BASELINE.json
-    # the model-parity tests (tests/model)
+    # every name some test pins — here, or for BASELINE.json the
+    # model-parity tests (tests/model)
     COVERED = {"EXPLAIN.json", "HEALTH.json", "GOODPUT.json",
                "SERVING_HEALTH.json", "FLEET_HEALTH.json",
                "TUNE_REPORT.json", "GUARDIAN.json", "STEP_ANATOMY.json",
@@ -1508,7 +1506,6 @@ class TestRepoRootArtifactLint:
                "SERVING_BENCH.json", "MEMORY_ANATOMY.json",
                "CHRONICLE.json", "INCIDENTS.json", "SLO_REPORT.json",
                "FLEET_CONTROL.json", "BASELINE.json"}
-    COVERED_PREFIXES = ("BENCH_r", "MULTICHIP_r")
 
     def _root_artifacts(self):
         import glob
@@ -1525,8 +1522,7 @@ class TestRepoRootArtifactLint:
 
     def test_schema_tag_where_the_family_defines_one(self):
         for name in self._root_artifacts():
-            if name in self.NO_SCHEMA or \
-                    name.startswith(self.NO_SCHEMA_PREFIXES):
+            if name in self.NO_SCHEMA:
                 continue
             doc = _load(name)
             schema = doc.get("schema")
@@ -1539,8 +1535,7 @@ class TestRepoRootArtifactLint:
 
     def test_every_artifact_is_pinned(self):
         for name in self._root_artifacts():
-            assert name in self.COVERED or \
-                name.startswith(self.COVERED_PREFIXES), (
+            assert name in self.COVERED, (
                     f"{name} is committed at the repo root but no test "
                     f"in test_artifacts.py pins it — add a pin class "
                     f"and register it in COVERED")

@@ -160,14 +160,8 @@ def test_per_token_flops_independent_of_generated_length(tiny_lm):
     full_ids = jnp.zeros((2, 128), jnp.int32)
     full_cost = jax.jit(full).lower(full_ids).compile().cost_analysis()
 
-    def flops(cost):
-        # older jaxlibs return [dict] (the hlo_census normalisation)
-        if isinstance(cost, (list, tuple)):
-            cost = cost[0] if cost else {}
-        return float(cost["flops"])
-
-    step_flops = flops(step_cost)
-    full_flops = flops(full_cost)
+    step_flops = float(step_cost["flops"])
+    full_flops = float(full_cost["flops"])
     # one cached step must be dramatically cheaper than a 128-token
     # recompute; 8x is a loose bound (the true ratio is ~seq_len)
     assert step_flops * 8 < full_flops, (step_flops, full_flops)

@@ -445,12 +445,11 @@ class TestEngineIntegration:
             engine, "_verify_prefetched_batch",
             lambda b, for_train=True: calls.append(for_train))
 
-        class _FakeGlobal:                    # a non-addressable jax.Array
+        class _FakeGlobal(jax.Array):         # a non-addressable jax.Array
             is_fully_addressable = False
             shape = (8, HIDDEN)
             ndim = 2
             dtype = np.dtype(np.float32)
-        jax.Array.register(_FakeGlobal)
         batch = {"x": _FakeGlobal(), "y": _FakeGlobal()}
         out = engine._globalize_batch(batch, verify=False)  # background
         assert out is batch and calls == []

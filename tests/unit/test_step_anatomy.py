@@ -29,6 +29,8 @@ from deepspeed_tpu.telemetry.tracer import (_LANE_TID_BASE, _reset_lane_tids,
 
 FIXTURE = os.path.join(os.path.dirname(__file__), "data",
                        "tiny_capture.xplane.pb")
+TPU_FIXTURE = os.path.join(os.path.dirname(__file__), "data",
+                           "tiny_tpu_capture.xplane.pb")
 
 _PS_S = 1e-12
 
@@ -313,6 +315,89 @@ class TestLaneTids:
 
 
 # ---------------------------------------------------------------------------
+# extract_events: which lines of a plane are op lanes
+# ---------------------------------------------------------------------------
+
+def _plane(name, lines, stat_names=("hlo_op", "step")):
+    """XPlane from {line_name: [(event_name, offset_ps, dur_ps, stats)]}."""
+    from deepspeed_tpu.telemetry import xplane as xp
+    plane = xp.XPlane(name=name,
+                      stat_metadata={i + 1: n
+                                     for i, n in enumerate(stat_names)})
+    stat_id = {n: i for i, n in plane.stat_metadata.items()}
+    ids = {}
+    for line_name, events in lines.items():
+        line = xp.XLine(name=line_name)
+        for ev_name, off, dur, stats in events:
+            mid = ids.setdefault(ev_name, len(ids) + 1)
+            plane.event_metadata[mid] = {"name": ev_name}
+            line.events.append(xp.XEvent(
+                metadata_id=mid, offset_ps=off, duration_ps=dur,
+                stats=[xp.XStat(metadata_id=stat_id[k], value=v)
+                       for k, v in stats.items()]))
+        plane.lines.append(line)
+    return plane
+
+
+class TestExtractEvents:
+    def test_tpu_plane_only_the_xla_ops_line_is_a_lane(self):
+        """A /device:TPU:n plane (v5e, jax 0.9) also carries Steps, XLA
+        Modules and Async XLA Ops lines whose events span or overlap the
+        ops; only 'XLA Ops' is the op lane, and its events — named by
+        their full HLO text — join on the bare instruction name."""
+        from deepspeed_tpu.telemetry import xplane as xp
+        dot = ("%dot.1 = f32[8,32]{1,0:T(8,128)} dot(f32[8,32]{1,0} %p0, "
+               "f32[8,32]{1,0} %p0), lhs_contracting_dims={1}")
+        fus = ("%loop_add_fusion = f32[8,32]{1,0} fusion(f32[8,32]{1,0} "
+               "%dot.1), kind=kLoop, calls=%fused_computation")
+        ar = ("%all-reduce-start.2 = f32[8,32]{1,0} all-reduce-start("
+              "f32[8,32]{1,0} %loop_add_fusion), replica_groups={}")
+        device = _plane("/device:TPU:0", {
+            "Steps": [("0", 0, 1000, {})],
+            "XLA Modules": [("jit_step(123)", 0, 1000, {})],
+            "XLA Ops": [(dot, 100, 300, {}), (fus, 400, 100, {}),
+                        (ar, 500, 10, {})],
+            "Async XLA Ops": [(ar, 500, 400, {"hlo_op": "all-reduce-done.2"})],
+            "TC Overlay": [],
+        })
+        host = _plane("/host:CPU", {
+            "python": [(sa.STEP_MARK, 0, 1000, {"step": 7})]})
+        steps, lanes = sa.extract_events(xp.XSpace(planes=[device, host]))
+        assert steps == [(7, 0, 1000)]
+        assert list(lanes) == ["/device:TPU:0/XLA Ops"]
+        assert [ev.name for ev in lanes["/device:TPU:0/XLA Ops"]] == [
+            "dot.1", "loop_add_fusion", "all-reduce-start.2"]
+        rep = analyze_events(steps, lanes, op_table=hlo_op_table(HLO_SNIPPET))
+        assert rep["device_wall_s"] == pytest.approx(1000 * _PS_S)
+        assert rep["categories_s"]["matmul_convolution"] == \
+            pytest.approx(300 * _PS_S)
+        assert rep["categories_s"]["collective"] == pytest.approx(10 * _PS_S)
+        assert rep["ops_joined_to_hlo"] == 2      # dot.1, loop_add_fusion
+        _sum_close(rep)
+
+    def test_host_executor_lane_by_op_share(self):
+        """jax 0.9 executor threads interleave every op with 'end:' and
+        ThreadpoolListener events (ops are about a third of the line);
+        the python thread, which runs a few programs inline, is not a
+        lane — and only the ops themselves are taken."""
+        from deepspeed_tpu.telemetry import xplane as xp
+        executor = []
+        for i in range(10):
+            t = i * 100
+            executor += [(f"dot.{i}", t, 60, {"hlo_op": f"dot.{i}"}),
+                         (f"end: dot.{i}", t + 60, 1, {}),
+                         ("ThreadpoolListener::Record", t + 61, 0, {})]
+        python = [("$builtins isinstance", i, 1, {}) for i in range(200)]
+        python.append(("copy", 300, 5, {"hlo_op": "copy"}))
+        host = _plane("/host:CPU", {"tf_XLAPjRtCpuClient/1": executor,
+                                    "python": python})
+        _, lanes = sa.extract_events(xp.XSpace(planes=[host]))
+        assert list(lanes) == ["/host:CPU/tf_XLAPjRtCpuClient/1"]
+        assert [ev.name for ev in lanes["/host:CPU/tf_XLAPjRtCpuClient/1"]] \
+            == [f"dot.{i}" for i in range(10)]
+
+
+# ---------------------------------------------------------------------------
 # summarize_capture on the committed fixture
 # ---------------------------------------------------------------------------
 
@@ -326,6 +411,22 @@ class TestSummarizeCapture:
         assert rep["lanes"], "no executor lane extracted from the fixture"
         assert rep["device_wall_s"] > 0
         assert rep["ops_total"] >= 1
+        _sum_close(rep)
+
+    def test_tpu_fixture_end_to_end(self, tmp_path):
+        """A capture recorded on a TPU v5e (jax 0.9, libtpu 0.0.34; two
+        steps of one small jitted program): the device plane's op line
+        is the only lane, so busy time is booked once."""
+        shutil.copy(TPU_FIXTURE, tmp_path / "cap.xplane.pb")
+        rep = summarize_capture(str(tmp_path))
+        assert rep is not None and "error" not in rep
+        assert "/device:TPU:0" in rep["source"]["planes"]
+        assert rep["captured_steps"] == 2
+        assert [l["name"] for l in rep["lanes"]] == ["/device:TPU:0/XLA Ops"]
+        assert rep["lanes"][0]["events"] == 6
+        assert {o["name"] for o in rep["top_ops"]} == {
+            "copy-start", "copy-done", "multiply_reduce_fusion"}
+        assert 0 < rep["lanes"][0]["busy_s"] < rep["device_wall_s"]
         _sum_close(rep)
 
     def test_empty_dir_returns_none(self, tmp_path):
