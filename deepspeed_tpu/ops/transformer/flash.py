@@ -385,6 +385,7 @@ def _flash_fwd(q, k, v, causal, sm_scale):
                 jax.ShapeDtypeStruct((B * H, Sq, D), q.dtype),
                 jax.ShapeDtypeStruct((B * H, Sq, LANES), jnp.float32),
             ],
+            name="flash_fwd",
             interpret=_interpret(),
         )(qf, kf, vf)
         out = o.reshape(B, H, Sq, D)
@@ -420,6 +421,7 @@ def _flash_fwd(q, k, v, causal, sm_scale):
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="flash_fwd",
         interpret=_interpret(),
     )(qf, kf, vf)
     out = o.reshape(B, H, Sq, D)
@@ -467,6 +469,7 @@ def _flash_bwd(causal, sm_scale, res, g, g_lse=None):
             ],
             out_specs=pl.BlockSpec((1, bq, D), lambda b, i: (b, i, 0)),
             out_shape=jax.ShapeDtypeStruct((B * H, Sq, D), q.dtype),
+            name="flash_dq",
             interpret=_interpret(),
         )(qf, kf, vf, dof, lse, delta)
         dk, dv = pl.pallas_call(
@@ -490,6 +493,7 @@ def _flash_bwd(causal, sm_scale, res, g, g_lse=None):
                 jax.ShapeDtypeStruct((B * H, Sk, D), k.dtype),
                 jax.ShapeDtypeStruct((B * H, Sk, D), v.dtype),
             ],
+            name="flash_dkv",
             interpret=_interpret(),
         )(qf, kf, vf, dof, lse, delta)
         return (dq.reshape(B, H, Sq, D), dk.reshape(B, H, Sk, D),
@@ -515,6 +519,7 @@ def _flash_bwd(causal, sm_scale, res, g, g_lse=None):
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="flash_dq",
         interpret=_interpret(),
     )(qf, kf, vf, dof, lse, delta)
 
@@ -546,6 +551,7 @@ def _flash_bwd(causal, sm_scale, res, g, g_lse=None):
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="flash_dkv",
         interpret=_interpret(),
     )(qf, kf, vf, dof, lse, delta)
 

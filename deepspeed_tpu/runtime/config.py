@@ -107,8 +107,6 @@ class DeepSpeedTelemetryConfig(DeepSpeedConfigObject):
         self.job_name = t.get(C.TELEMETRY_JOB_NAME,
                               C.TELEMETRY_JOB_NAME_DEFAULT)
         self.trace = t.get(C.TELEMETRY_TRACE, C.TELEMETRY_TRACE_DEFAULT)
-        self.jax_annotations = t.get(C.TELEMETRY_JAX_ANNOTATIONS,
-                                     C.TELEMETRY_JAX_ANNOTATIONS_DEFAULT)
         self.compile_watch = t.get(C.TELEMETRY_COMPILE_WATCH,
                                    C.TELEMETRY_COMPILE_WATCH_DEFAULT)
         self.jsonl = t.get(C.TELEMETRY_JSONL, C.TELEMETRY_JSONL_DEFAULT)

@@ -102,8 +102,6 @@ TELEMETRY_JOB_NAME = "job_name"
 TELEMETRY_JOB_NAME_DEFAULT = "DeepSpeedJobName"
 TELEMETRY_TRACE = "trace"
 TELEMETRY_TRACE_DEFAULT = True
-TELEMETRY_JAX_ANNOTATIONS = "jax_annotations"
-TELEMETRY_JAX_ANNOTATIONS_DEFAULT = False
 TELEMETRY_COMPILE_WATCH = "compile_watch"
 TELEMETRY_COMPILE_WATCH_DEFAULT = True
 TELEMETRY_JSONL = "jsonl"

@@ -3179,11 +3179,14 @@ class DeepSpeedEngine:
             self._guardian.tick(self.global_steps)
 
     def _fused_train_batch(self, data_iter, batch):
-        """gas=1 fast path: one fused compiled program per global step."""
+        """gas=1 fast path: one fused compiled program per global step.
+        Returns the loss and what ``_post_apply`` takes (the caller runs
+        it, under its ``train_post`` span)."""
+        span = self.telemetry.span
         if batch is not None:
             micro = batch
         else:
-            with self._led_attr("input_wait"):
+            with span("train_input"), self._led_attr("input_wait"):
                 micro = next(data_iter)
         if self.curriculum_scheduler is not None:
             micro = self._apply_curriculum(micro)
@@ -3192,9 +3195,10 @@ class DeepSpeedEngine:
         theta = jnp.float32(
             self.progressive_layer_drop.get_theta()
             if self.progressive_layer_drop is not None else 1.0)
-        with self.telemetry.span("fused_step", global_step=self.global_steps):
-            with self.mesh:
+        with span("fused_step", global_step=self.global_steps), self.mesh:
+            with span("train_place"):
                 gbatch = self._globalize_batch(micro)
+            with span("train_dispatch"):
                 if self._health_on:
                     (self.state, loss, grad_norm, overflow,
                      stats) = self._jit_train(
@@ -3207,8 +3211,7 @@ class DeepSpeedEngine:
         self._pending_loss = None
         self._last_batch = gbatch   # flops profiler reads this
         self.micro_steps += 1
-        self._post_apply(grad_norm, overflow)
-        return loss
+        return loss, (grad_norm, overflow)
 
     def train_batch(self, data_iter=None, batch=None):
         """One full global step: gas micro-batches + optimizer step."""
@@ -3222,12 +3225,14 @@ class DeepSpeedEngine:
         tel = self.telemetry
         if not tel.enabled:
             if self._fleet is None:
-                return self._train_batch(data_iter, batch)
+                with tel.span("train_batch", global_step=self.global_steps):
+                    return self._train_batch(data_iter, batch)
             # non-zero fleet ranks: the manager (and ledger) are rank-0
             # only, but the fleet needs THIS rank's step wall times —
             # two clock reads, nothing else
             t0 = time.perf_counter()
-            mean_loss = self._train_batch(data_iter, batch)
+            with tel.span("train_batch", global_step=self.global_steps):
+                mean_loss = self._train_batch(data_iter, batch)
             step_s = time.perf_counter() - t0
             self._fleet.note_step_time(step_s)
             self._note_first_compile(step_s)
@@ -3319,9 +3324,10 @@ class DeepSpeedEngine:
                      and self.global_steps == fp_cfg.profile_step)
         profile_t0 = time.perf_counter() if profiling else 0.0
         self.tput_timer.start()
+        span = self.telemetry.span
+        applied = None
         if self._jit_train is not None:
-            mean_loss = self._fused_train_batch(data_iter, batch)
-            self.tput_timer.stop(global_step=True)
+            mean_loss, applied = self._fused_train_batch(data_iter, batch)
         else:
             losses = []
             for _ in range(self.gradient_accumulation_steps()):
@@ -3329,14 +3335,25 @@ class DeepSpeedEngine:
                     micro = batch
                 else:
                     assert data_iter is not None
-                    with self._led_attr("input_wait"):
+                    with span("train_input"), self._led_attr("input_wait"):
                         micro = next(data_iter)
                 loss = self.forward(micro)
                 self.backward(loss)
                 losses.append(loss)
             self.step()
-            self.tput_timer.stop(global_step=True)
             mean_loss = jnp.mean(jnp.stack(losses))
+        with span("train_post"):
+            if applied is not None:
+                self._post_apply(*applied)
+            self.tput_timer.stop(global_step=True)
+            self._report_step(mean_loss, profiling, profile_t0)
+        return mean_loss
+
+    def _report_step(self, mean_loss, profiling, profile_t0):
+        """What a finished step owes its observers: the print-cadence log
+        line, the one-shot flops profile, the wall-clock breakdown and the
+        monitor's scalars."""
+        fp_cfg = self.config.flops_profiler_config
         if self.global_steps % self.steps_per_print() == 0:
             # float(mean_loss) is a blocking device fetch: wall time spent
             # here is the device catching up — good time, device_compute
@@ -3396,7 +3413,6 @@ class DeepSpeedEngine:
                     ("Train/Samples/skipped_steps",
                      float(self.skipped_steps), self.global_samples),
                 ])
-        return mean_loss
 
     def eval_batch(self, batch):
         with self._led_attr("eval"), self.telemetry.span("eval_batch"):

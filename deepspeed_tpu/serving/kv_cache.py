@@ -480,6 +480,7 @@ class PagedKVCache:
 
     write_chunk = write_decode      # [C, H, D]: C plays B's role
 
+    @jax.named_scope("kv_write")
     def write_all_layers(self, pools, k_all, v_all, block_ids, offsets):
         """Write EVERY layer's K/V for this step in one scatter apiece.
 
@@ -524,24 +525,25 @@ class PagedKVCache:
         if n == self.n_layer:
             return self.write_all_layers(pools, k_all, v_all, block_ids,
                                          offsets)
-        out = dict(pools)
-        if self.int8_kv:
-            kq, ks = quantize_kv(k_all)        # scales [n, B, H]
-            vq, vs = quantize_kv(v_all)
-            out["k"] = pools["k"].at[:n, block_ids, :, offsets, :].set(
-                kq.transpose(1, 0, 2, 3))
-            out["v"] = pools["v"].at[:n, block_ids, :, offsets, :].set(
-                vq.transpose(1, 0, 2, 3))
-            out["k_scale"] = pools["k_scale"].at[
-                :n, block_ids, :, offsets].set(ks.transpose(1, 0, 2))
-            out["v_scale"] = pools["v_scale"].at[
-                :n, block_ids, :, offsets].set(vs.transpose(1, 0, 2))
-        else:
-            dt = pools["k"].dtype
-            out["k"] = pools["k"].at[:n, block_ids, :, offsets, :].set(
-                k_all.transpose(1, 0, 2, 3).astype(dt))
-            out["v"] = pools["v"].at[:n, block_ids, :, offsets, :].set(
-                v_all.transpose(1, 0, 2, 3).astype(dt))
+        with jax.named_scope("kv_write"):
+            out = dict(pools)
+            if self.int8_kv:
+                kq, ks = quantize_kv(k_all)        # scales [n, B, H]
+                vq, vs = quantize_kv(v_all)
+                out["k"] = pools["k"].at[:n, block_ids, :, offsets, :].set(
+                    kq.transpose(1, 0, 2, 3))
+                out["v"] = pools["v"].at[:n, block_ids, :, offsets, :].set(
+                    vq.transpose(1, 0, 2, 3))
+                out["k_scale"] = pools["k_scale"].at[
+                    :n, block_ids, :, offsets].set(ks.transpose(1, 0, 2))
+                out["v_scale"] = pools["v_scale"].at[
+                    :n, block_ids, :, offsets].set(vs.transpose(1, 0, 2))
+            else:
+                dt = pools["k"].dtype
+                out["k"] = pools["k"].at[:n, block_ids, :, offsets, :].set(
+                    k_all.transpose(1, 0, 2, 3).astype(dt))
+                out["v"] = pools["v"].at[:n, block_ids, :, offsets, :].set(
+                    v_all.transpose(1, 0, 2, 3).astype(dt))
         return out
 
     # ------------------------------------------------------ traced gather

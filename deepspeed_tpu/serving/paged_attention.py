@@ -27,6 +27,10 @@ full precision (it is quantised only when written, exactly like the
 flax decode path, which attends to the quantised value from the NEXT
 step on).
 
+Each function traces under ``jax.named_scope("paged_attention")`` (and the
+stacked write under ``"kv_write"``): trace-time only, the name a profile's
+operations carry in their ``tf_op`` stat.
+
 Both impls are selectable per engine (``serving.attention_impl``) and
 pinned equal by tests/unit/test_serving.py.
 """
@@ -45,6 +49,7 @@ def _merge(m1, l1, a1, m2, l2, a2):
     return m, l1 * w1 + l2 * w2, a1 * w1[..., None] + a2 * w2[..., None]
 
 
+@jax.named_scope("paged_attention")
 def paged_decode_attention(q, k_cur, v_cur, layer, k_pool, v_pool,
                            block_tables, past_lens, *, k_scale_pool=None,
                            v_scale_pool=None, sm_scale=None):
@@ -106,6 +111,7 @@ def paged_decode_attention(q, k_cur, v_cur, layer, k_pool, v_pool,
     return acc / l[..., None]
 
 
+@jax.named_scope("paged_attention")
 def paged_verify_attention(q, k_chunk, v_chunk, layer, k_pool, v_pool,
                            block_tables, past_lens, *, k_scale_pool=None,
                            v_scale_pool=None, sm_scale=None):
@@ -176,6 +182,7 @@ def paged_verify_attention(q, k_chunk, v_chunk, layer, k_pool, v_pool,
     return acc / l[..., None]
 
 
+@jax.named_scope("paged_attention")
 def paged_prefill_attention(q, k_chunk, v_chunk, layer, k_pool, v_pool,
                             bt_row, pos, start, *, k_scale_pool=None,
                             v_scale_pool=None, sm_scale=None):

@@ -325,8 +325,9 @@ class ServingEngine:
         still-prefilling slot, one decode dispatch. Returns True when
         any work was done."""
         with trace_span("serving_step"):
-            plan = self.scheduler.schedule()
-            progress = self._drain_failed()
+            with trace_span("serving_schedule"):
+                plan = self.scheduler.schedule()
+                progress = self._drain_failed()
             # acts: slot -> what it did this step (("prefill"|"recompute",
             # n_valid) or ("decode", delivered)) — the slot-step ledger's
             # input; collected DURING the step because finished requests
@@ -342,30 +343,37 @@ class ServingEngine:
             if plan.decode_slots:
                 self._run_decode(plan.decode_slots, acts)
                 progress = True
-            self._publish_gauges()
-            if self.observatory is not None:
-                occupied = {i for i, r in enumerate(self.scheduler.slots)
-                            if r is not None}
-                self.observatory.end_step(
-                    acts, occupied,
-                    queue_depth=self.scheduler.num_waiting,
-                    active=self.scheduler.num_active,
-                    kv_occupancy=self.cache.allocator.occupancy(),
-                    kv_fragmentation=self._kv_fragmentation(),
-                    progress=progress)
-            if self.guardian is not None or self._slo is not None:
-                # serving's own step clock (NOT training steps): the
-                # pause policy fires here, and recovery is measured in
-                # quiet serving steps
-                self._serving_steps += 1
-                if self._slo is not None:
-                    # burn-rate eval BEFORE the guardian tick so a page
-                    # fired this step pauses admission this step
-                    self._slo.tick(step=self._serving_steps)
-                if self.guardian is not None:
-                    self.guardian.serving_tick(self._serving_steps)
-            self._memory_tick()
+            with trace_span("serving_publish"):
+                self._publish(acts, progress)
         return progress
+
+    def _publish(self, acts, progress):
+        """The step's book-keeping once its dispatches are out: gauges,
+        the observatory's slot-step ledger, the SLO and guardian ticks,
+        the memory tick (the ``serving_publish`` span)."""
+        self._publish_gauges()
+        if self.observatory is not None:
+            occupied = {i for i, r in enumerate(self.scheduler.slots)
+                        if r is not None}
+            self.observatory.end_step(
+                acts, occupied,
+                queue_depth=self.scheduler.num_waiting,
+                active=self.scheduler.num_active,
+                kv_occupancy=self.cache.allocator.occupancy(),
+                kv_fragmentation=self._kv_fragmentation(),
+                progress=progress)
+        if self.guardian is not None or self._slo is not None:
+            # serving's own step clock (NOT training steps): the
+            # pause policy fires here, and recovery is measured in
+            # quiet serving steps
+            self._serving_steps += 1
+            if self._slo is not None:
+                # burn-rate eval BEFORE the guardian tick so a page
+                # fired this step pauses admission this step
+                self._slo.tick(step=self._serving_steps)
+            if self.guardian is not None:
+                self.guardian.serving_tick(self._serving_steps)
+        self._memory_tick()
 
     def _pause_admission(self, rule):
         """Guardian overload action: refuse new submits (fail fast with
@@ -502,7 +510,9 @@ class ServingEngine:
     def _run_prefill(self, req, acts=None) -> bool:
         slot, start = req.slot, req.cached_len
         t0 = time.perf_counter_ns()
-        with trace_span("serving_prefill", req=req.req_id):
+        with trace_span("serving_prefill", req=req.req_id, start=start,
+                        tokens=min(self.prefill.chunk_size,
+                                   self.prefill.remaining(req))):
             with self.engine.mesh:
                 self.pools, n_valid, n_recompute, done = self.prefill.run(
                     self.engine.params, self.engine.quant_scales,
@@ -535,7 +545,9 @@ class ServingEngine:
             req.state = RequestState.RUNNING
         return True
 
-    def _run_decode(self, decode_slots, acts=None):
+    def _decode_inputs(self, decode_slots):
+        """The decode program's host-built arguments: the block tables
+        and the per-slot vectors, zero (inactive) off ``decode_slots``."""
         B = self.max_batch
         MB = self.max_blocks_per_seq
         slots = self.scheduler.slots
@@ -558,11 +570,40 @@ class ServingEngine:
             top_p[i] = r.top_p
             lanes[i] = self._lanes[r.req_id]
             budget[i] = r.step_budget
-        spec = (self.speculative
-                if self._spec_disabled_rule is None else None)
-        t0 = time.perf_counter_ns()
-        with trace_span("serving_decode", batch=len(decode_slots)):
-            with self.engine.mesh:
+        return bt, pos, active, tok, temp, top_p, lanes, budget
+
+    def _paged_block_counts(self, pos, active):
+        """KV blocks the active slots hold tokens in, and blocks the paged
+        loop visits for them: its trip count is the LONGEST sequence's
+        (paged_attention.py) and every trip gathers a block for each of the
+        ``max_batch`` rows. The ratio is the share of the loop's gathers
+        that read live tokens."""
+        BS = self.cache.block_size
+        blocks = -(-pos[active].astype(np.int64) // BS)
+        needed = int(blocks.sum())
+        visited = self.max_batch * int(blocks.max(initial=0))
+        self.registry.counter(
+            "serving_paged_blocks_needed_total",
+            "KV blocks holding the decoding slots' tokens, summed over "
+            "decode dispatches").inc(needed)
+        self.registry.counter(
+            "serving_paged_blocks_visited_total",
+            "KV blocks the paged loop gathered (max_batch x its trip "
+            "count), summed over decode dispatches").inc(visited)
+        return needed, visited
+
+    def _run_decode(self, decode_slots, acts=None):
+        with trace_span("serving_decode", batch=len(decode_slots)) as span:
+            with trace_span("serving_decode_inputs"):
+                bt, pos, active, tok, temp, top_p, lanes, budget = \
+                    self._decode_inputs(decode_slots)
+            needed, visited = self._paged_block_counts(pos, active)
+            span.set(blocks_needed=needed, blocks_visited=visited)
+            spec = (self.speculative
+                    if self._spec_disabled_rule is None else None)
+            accepted = None
+            t0 = time.perf_counter_ns()
+            with trace_span("serving_decode_dispatch"), self.engine.mesh:
                 if spec is not None:
                     # draft -> verify, device-to-device: the drafted
                     # tokens feed the verify program WITHOUT a host
@@ -586,10 +627,22 @@ class ServingEngine:
                         self.engine.params, self.engine.quant_scales,
                         self.pools, bt, pos, active, tok, temp, top_p,
                         lanes, budget)
-            if spec is not None:
-                accepted = np.asarray(accepted)    # [B]
-            toks = np.asarray(toks)        # [K, B]; the one host sync
-        t1 = time.perf_counter_ns()
+            with trace_span("serving_decode_wait"):
+                if spec is not None:
+                    accepted = np.asarray(accepted)    # [B]
+                toks = np.asarray(toks)        # [K, B]; the one host sync
+            t1 = time.perf_counter_ns()
+            with trace_span("serving_deliver"):
+                self._deliver_decoded(decode_slots, toks, budget, accepted,
+                                      t0, t1, acts)
+
+    def _deliver_decoded(self, decode_slots, toks, budget, accepted, t0, t1,
+                         acts):
+        """Hand one decode dispatch's tokens to its requests;
+        ``accepted`` is the verify program's per-slot count under
+        speculation, else None."""
+        slots = self.scheduler.slots
+        spec = self.speculative if accepted is not None else None
         now = time.perf_counter()
         self.registry.counter("serving_decode_steps_total",
                               "compiled decode dispatches executed").inc()

@@ -3,7 +3,8 @@
 Four pieces (see the per-module docstrings):
 
 * ``tracer`` — nested ``trace_span`` contexts -> Chrome-trace JSON
-  (+ optional ``jax.profiler.TraceAnnotation`` forwarding);
+  (live when enabled or while a JAX profiler session runs, when each span
+  is also a ``jax.profiler.TraceAnnotation`` in the capture);
 * ``compile_watch`` — XLA compile counting + retrace culprit reports;
 * ``metrics`` — counters / gauges / histograms + device-memory stats;
 * ``sinks`` — JSONL event writer and Prometheus text-format exporter

@@ -35,7 +35,7 @@ class TelemetryManager:
                             and getattr(config, "enabled", False)
                             and rank == 0)
         if not self.enabled:
-            self.tracer = _tracer_mod.Tracer(enabled=False)
+            self.tracer = None      # spans go to the process-global tracer
             self.registry = None
             self.compile_watch = None
             self.trace_path = None
@@ -55,7 +55,6 @@ class TelemetryManager:
         _metrics.set_registry(self.registry)
         self.tracer = _tracer_mod.Tracer(
             enabled=bool(config.trace),
-            jax_annotations=bool(config.jax_annotations),
             max_events=int(config.max_trace_events))
         _tracer_mod.set_tracer(self.tracer)
         self.compile_watch = (_cw.CompileWatch(self.registry)
@@ -113,11 +112,13 @@ class TelemetryManager:
         atexit.register(self.close)
 
     # ---------------------------------------------------------------- spans
+    # a disabled manager owns no tracer: its spans are the process-global
+    # tracer's, live while a profiler session runs, so there is ONE tracer
     def span(self, name, **args):
-        return self.tracer.span(name, **args)
+        return (self.tracer or _tracer_mod.get_tracer()).span(name, **args)
 
     def instant(self, name, **args):
-        self.tracer.instant(name, **args)
+        (self.tracer or _tracer_mod.get_tracer()).instant(name, **args)
 
     # -------------------------------------------------------------- compile
     def wrap_compiled(self, fn, name):

@@ -343,7 +343,7 @@ class ServingObservatory:
         if not self.trace_lanes:
             return
         tracer = _tracer_mod.get_tracer()
-        if not tracer.enabled:
+        if not tracer.live:
             return
         if not self._lanes_named:
             self._name_lanes(tracer)
@@ -358,7 +358,7 @@ class ServingObservatory:
         if not self.trace_lanes:
             return
         tracer = _tracer_mod.get_tracer()
-        if not tracer.enabled:
+        if not tracer.live:
             return
         if not self._lanes_named:
             self._name_lanes(tracer)

@@ -2,19 +2,29 @@
 
 Emits Chrome-trace/Perfetto-compatible "X" (complete) events
 (``{"name", "ph", "ts", "dur", "pid", "tid", "args"}``, timestamps in
-microseconds) and can forward each span to ``jax.profiler.TraceAnnotation``
-so host-side phases line up with device traces in the XLA profiler UI.
+microseconds on ``perf_counter_ns``) into a bounded in-memory list.
 
-The disabled path is the hot path: ``trace_span`` on a disabled tracer
-returns one shared no-op context manager — no allocation, no clock read
-(tests/perf/telemetry_overhead.py asserts < 2 µs/span). Enabled spans cost
-two ``perf_counter_ns`` reads and one locked list append.
+A tracer is LIVE when it was enabled explicitly (``telemetry.trace``) or
+while a JAX profiler session runs (``jax.profiler.start_trace`` ...
+``stop_trace``). While a session runs every span is also a
+``jax.profiler.TraceAnnotation``, so it lies in the capture's ``/host:``
+plane on the profiler's own clock, beside ``/device:TPU:n``, with its
+``args`` as the event's stats; outside one the annotation is a no-op by
+itself.
+
+The hot path is the one that is not live: ``trace_span`` then costs one
+``TraceAnnotation.is_enabled()`` call and returns one shared no-op context
+manager — no allocation, no clock read (tests/perf/telemetry_overhead.py
+asserts < 2 µs/span). A live span costs two ``perf_counter_ns`` reads, the
+annotation and one locked list append.
 """
 
 import json
 import os
 import threading
 import time
+
+from jax.profiler import TraceAnnotation
 
 
 class _NullSpan:
@@ -27,6 +37,9 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def set(self, **args):
+        pass
+
 
 _NULL_SPAN = _NullSpan()
 
@@ -38,23 +51,21 @@ class _Span:
         self._tracer = tracer
         self.name = name
         self.args = args
-        self._ann = None
+        self._ann = TraceAnnotation(name, **args)
 
     def __enter__(self):
-        if self._tracer._annotate:
-            try:
-                from jax.profiler import TraceAnnotation
-                self._ann = TraceAnnotation(self.name)
-                self._ann.__enter__()
-            except Exception:
-                self._ann = None
+        self._ann.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
+    def set(self, **args):
+        """Add to an open span's args what its work has shown since."""
+        self.args.update(args)
+        self._ann.set_metadata(**args)
+
     def __exit__(self, *exc):
         t1 = time.perf_counter_ns()
-        if self._ann is not None:
-            self._ann.__exit__(*exc)
+        self._ann.__exit__(*exc)
         self._tracer._record(self.name, self._t0, t1, self.args)
         return False
 
@@ -63,10 +74,8 @@ class Tracer:
     """Collects spans into a bounded in-memory buffer; ``export`` writes
     the Chrome-trace JSON (loadable in chrome://tracing / Perfetto)."""
 
-    def __init__(self, enabled=False, jax_annotations=False,
-                 max_events=100_000):
+    def __init__(self, enabled=False, max_events=100_000):
         self.enabled = enabled
-        self._annotate = jax_annotations
         self.max_events = max_events
         self.dropped = 0
         self._events = []
@@ -83,10 +92,16 @@ class Tracer:
         self._process_label = str(name)
         self._process_sort = sort_index
 
+    @property
+    def live(self):
+        """Enabled explicitly, or a JAX profiler session is running: the
+        one predicate ``span``, ``emit`` and ``instant`` ask."""
+        return self.enabled or TraceAnnotation.is_enabled()
+
     def span(self, name, **args):
-        if not self.enabled:
+        if not self.live:
             return _NULL_SPAN
-        return _Span(self, name, args or None)
+        return _Span(self, name, args)
 
     def _record(self, name, t0_ns, t1_ns, args):
         ev = {"name": name, "ph": "X", "ts": t0_ns // 1000,
@@ -105,7 +120,7 @@ class Tracer:
         serving observatory synthesizes per-slot lane events with its
         own pid/tid (and "M" metadata naming the lanes) — those cannot
         go through span()/instant(), which stamp the CURRENT thread."""
-        if not self.enabled:
+        if not self.live:
             return
         with self._lock:
             if len(self._events) >= self.max_events:
@@ -115,7 +130,7 @@ class Tracer:
 
     def instant(self, name, **args):
         """Zero-duration marker event (ph="i")."""
-        if not self.enabled:
+        if not self.live:
             return
         ev = {"name": name, "ph": "i", "s": "t",
               "ts": time.perf_counter_ns() // 1000,
@@ -201,10 +216,11 @@ def _reset_lane_tids():
         _LANE_NEXT[0] = _LANE_TID_BASE
 
 
-# Module-level default tracer: DISABLED until a TelemetryManager (or a
-# test) installs an enabled one. Library code (engine, checkpoint_io)
-# calls ``trace_span`` unconditionally; the cost without telemetry is one
-# global lookup + a shared no-op context manager.
+# Module-level default tracer: not enabled until a TelemetryManager (or a
+# test) installs an enabled one, and live all the same while a profiler
+# session runs. Library code (engine, server, checkpoint_io) calls
+# ``trace_span`` unconditionally; the cost with neither is one global
+# lookup, one ``is_enabled()`` + a shared no-op context manager.
 _GLOBAL = Tracer(enabled=False)
 
 
