@@ -5,9 +5,10 @@ decode KV cache is ONE pool of fixed-size blocks shared by every request,
 and each request owns an ordered *block table* mapping its logical token
 positions onto pool blocks. Heterogeneous prompt/generation lengths then
 share a single static-shaped compiled decode step — the per-step program
-always sees ``[num_blocks, H, block_size, D]`` pools plus small int32
-tables, and only the *values* change as requests come and go, so XLA
-compiles the decode step exactly once for the whole serving lifetime.
+always sees the same ``[n_layer*num_blocks, block_size, W]`` pools plus
+small int32 tables, and only the *values* change as requests come and
+go, so XLA compiles the decode step exactly once for the whole serving
+lifetime.
 
 Split of responsibilities:
 
@@ -31,13 +32,13 @@ Split of responsibilities:
   *request* finished stays reusable until LRU eviction or
   ``reclaim()`` — which the scheduler calls before any preemption
   fires.
-* ``PagedKVCache`` — owns the device pools (per layer: K, V, and for the
-  int8 KV layout the per-row fp32 scales, riding the same lane-dim
-  convention as ops/transformer/decode.py) plus the scatter/gather
-  helpers the runner traces into the compiled step: ``write_decode``
-  (one token per slot), ``write_chunk`` (a prefill chunk for one slot)
-  and ``gather`` (block table -> contiguous ``[B, H, T, D]`` view that
-  composes with ``decode_attention``'s per-sequence lengths).
+* ``PagedKVCache`` — owns the device pools (K and V as token rows, and
+  for the int8 KV layout the per-row fp32 scales) plus the
+  scatter/gather helpers the runner traces into the compiled step:
+  ``write_layers`` (one scatter per pool for a step's tokens, in one
+  layer or in all of them) and ``gather`` (block table -> contiguous
+  ``[B, H, T, D]`` view that composes with ``decode_attention``'s
+  per-sequence lengths).
 
 The gather materialises each slot's logical cache contiguously per step.
 Attention has to stream those bytes anyway — decode is KV-bandwidth
@@ -53,6 +54,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from deepspeed_tpu.ops.transformer.decode import quantize_kv
+
+# a TPU vreg's lane count: the pools' minor dimension is kept a whole
+# number of them (PagedKVCache docstring)
+_LANES = 128
 
 
 class BlockAllocatorError(RuntimeError):
@@ -379,14 +384,37 @@ class PrefixCache:
 class PagedKVCache:
     """Device block pools + the traced scatter/gather helpers.
 
-    Pools are layer-STACKED arrays (one pytree leaf each, one scatter
-    per step via :meth:`write_all_layers`):
+    Each pool is ONE array of token rows, blocks major, the layer folded
+    into the block index (layer ``l``'s block ``b`` is row ``l*N + b``):
 
-    * ``k``/``v``: ``[n_layer, num_blocks, H, block_size, D]`` in the
-      activation dtype, or int8 when ``int8_kv`` (the lane-dim int8 KV
-      layout that measured 1.33x on the decode bench);
-    * ``k_scale``/``v_scale`` (int8 only): ``[n_layer, num_blocks, H,
-      block_size]`` fp32 per-row absmax scales.
+    * ``k``/``v``: ``[n_layer*num_blocks, block_size, W]`` in the
+      activation dtype, or int8 when ``int8_kv``; ``W`` is
+      ``n_head*head_dim`` rounded up to whole 128-lane vregs
+      (``row_width``), the pad lanes written as zeros and sliced off
+      before any product;
+    * ``k_scale``/``v_scale`` (int8 only): ``[n_layer*num_blocks,
+      block_size, n_head rounded up to 128]`` fp32 per-row absmax
+      scales (``scale_width``), padded the same way.
+
+    Why this shape: on the TPU an array lives in tiles of 8 sublanes by
+    128 lanes (16 rows of bf16), and with ``block_size`` rows of whole
+    lanes per block the pool's device layout is plain row-major. The
+    step's one write indexes only the two LEADING dimensions and the
+    paged loop's read only the first, so the compiler updates and
+    gathers the donated pool where it lies. The former ``[L, N, H, BS,
+    D]`` pool (minor dimensions 16 x 64: no whole tile) was laid out
+    ``{1,4,3,2,0}`` on the device; its read ``pool[layer, ids]`` wanted
+    it row-major and its write ``.at[:, blk, :, off, :]`` (a ``:`` over
+    layers and heads ahead of the indexed dimensions) wanted
+    ``{4,2,3,1,0}``, so both serving programs converted the whole pool
+    on the way in and back on the way out: ten pool-sized ``copy`` ops,
+    73-80% of device time, 8.1 GB of temporaries beside 4.9 GB of
+    arguments at gpt2-medium with 40 slots (PERF.md, PR 27). The device
+    pads a 1,600-wide row to 1,664 lanes whatever the shape says; the
+    pad in the shape keeps ``pool_bytes`` the bytes the device holds.
+    For the scale pools it decides more: at a minor dimension of 16 or
+    25 heads the compiler lays them out otherwise and copies them whole
+    in every program, at 128 it does not.
     """
 
     def __init__(self, n_layer, n_head, head_dim, block_size, num_blocks,
@@ -394,6 +422,8 @@ class PagedKVCache:
         self.n_layer = n_layer
         self.n_head = n_head
         self.head_dim = head_dim
+        self.row_width = -(-n_head * head_dim // _LANES) * _LANES
+        self.scale_width = -(-n_head // _LANES) * _LANES
         self.block_size = int(block_size)
         self.num_blocks = int(num_blocks)
         self.int8_kv = bool(int8_kv)
@@ -416,22 +446,22 @@ class PagedKVCache:
         return self.prefix_cache
 
     # -------------------------------------------------- pool construction
+    def _pool_shapes(self):
+        rows = (self.n_layer * self.num_blocks, self.block_size)
+        shapes = {"k": (rows + (self.row_width,), self.dtype),
+                  "v": (rows + (self.row_width,), self.dtype)}
+        if self.int8_kv:
+            shapes["k_scale"] = (rows + (self.scale_width,), jnp.float32)
+            shapes["v_scale"] = (rows + (self.scale_width,), jnp.float32)
+        return shapes
+
     def init_pools(self, sharding=None):
         """Zeroed device pools; pass through the jitted step and thread
-        the returned (donated) pools back in. Layer-STACKED arrays
-        (``[L, N, H, BS, D]``): all layers of a step's K/V land in ONE
-        scatter (XLA scatter dispatch is the dominant per-step host cost
-        once attention streams only live blocks — 2 scatters/step beats
-        2-per-layer by the layer count)."""
-        L, N, H, BS, D = (self.n_layer, self.num_blocks, self.n_head,
-                          self.block_size, self.head_dim)
-        pools = {
-            "k": jnp.zeros((L, N, H, BS, D), self.dtype),
-            "v": jnp.zeros((L, N, H, BS, D), self.dtype),
-        }
-        if self.int8_kv:
-            pools["k_scale"] = jnp.zeros((L, N, H, BS), jnp.float32)
-            pools["v_scale"] = jnp.zeros((L, N, H, BS), jnp.float32)
+        the returned (donated) pools back in. Two leaves (four with int8
+        KV), whatever the depth: the dispatch call does not grow with
+        the layer count."""
+        pools = {name: jnp.zeros(shape, dtype)
+                 for name, (shape, dtype) in self._pool_shapes().items()}
         # COMMIT the arrays (to the caller's sharding — the server passes
         # a mesh-replicated one matching the engine params): a donated
         # program's outputs are committed, and feeding a committed pool
@@ -442,108 +472,55 @@ class PagedKVCache:
             else jax.local_devices()[0])
 
     def pool_bytes(self) -> int:
-        """Total HBM the pools occupy (for the serving metrics)."""
-        N, H, BS, D = (self.num_blocks, self.n_head, self.block_size,
-                       self.head_dim)
-        per_layer = 2 * N * H * BS * D * jnp.dtype(self.dtype).itemsize
-        if self.int8_kv:
-            per_layer += 2 * N * H * BS * 4
-        return per_layer * self.n_layer
+        """Total HBM the pools occupy (for the serving metrics), the pad
+        lanes of ``row_width`` included."""
+        return sum(int(np.prod(shape)) * jnp.dtype(dtype).itemsize
+                   for shape, dtype in self._pool_shapes().values())
+
+    def layer_rows(self, block_ids, first_layer=0, n_layers=1):
+        """Pool rows of ``block_ids`` in ``n_layers`` consecutive layers:
+        ``[n_layers, *block_ids.shape]`` int32."""
+        layers = first_layer + np.arange(n_layers, dtype=np.int32)
+        return (layers * self.num_blocks).reshape(
+            (-1,) + (1,) * jnp.ndim(block_ids)) + block_ids
 
     # ------------------------------------------------------ traced writes
-    def write_decode(self, pools, layer, k_new, v_new, block_ids, offsets):
-        """Write one token's (or one chunk's) K/V into ONE layer's pages.
-
-        k_new/v_new: ``[B, H, D]``; block_ids/offsets: ``[B]`` int32 (the
-        scheduler routes inactive slots / pad positions to the null block
-        0). Used by the ``gather`` attention impl, whose kernel needs the
-        current token in the pool before it reads. The ``paged`` impl
-        batches all layers through :meth:`write_all_layers` instead.
-        """
-        out = dict(pools)
-        if self.int8_kv:
-            kq, ks = quantize_kv(k_new)                 # scales [B, H]
-            vq, vs = quantize_kv(v_new)
-            out["k"] = pools["k"].at[layer, block_ids, :, offsets, :].set(kq)
-            out["v"] = pools["v"].at[layer, block_ids, :, offsets, :].set(vq)
-            out["k_scale"] = pools["k_scale"].at[
-                layer, block_ids, :, offsets].set(ks)
-            out["v_scale"] = pools["v_scale"].at[
-                layer, block_ids, :, offsets].set(vs)
-        else:
-            dt = pools["k"].dtype
-            out["k"] = pools["k"].at[layer, block_ids, :, offsets, :].set(
-                k_new.astype(dt))
-            out["v"] = pools["v"].at[layer, block_ids, :, offsets, :].set(
-                v_new.astype(dt))
-        return out
-
-    write_chunk = write_decode      # [C, H, D]: C plays B's role
-
     @jax.named_scope("kv_write")
-    def write_all_layers(self, pools, k_all, v_all, block_ids, offsets):
-        """Write EVERY layer's K/V for this step in one scatter apiece.
+    def write_layers(self, pools, k_new, v_new, block_ids, offsets,
+                     first_layer=0):
+        """Write one step's K/V for ``n`` consecutive layers, starting at
+        ``first_layer``, in ONE scatter per pool.
 
-        k_all/v_all: ``[L, B, H, D]`` (decode) or ``[L, C, H, D]``
-        (prefill chunk); block_ids/offsets: ``[B]``/``[C]`` int32. The
-        advanced indices land on pool dims 1 and 3, so the update tensor
-        is expected batch-major — ``[B, L, H, D]``."""
+        k_new/v_new: ``[n, B, H, D]`` (decode: a token per slot; prefill
+        or verify: the ``B`` positions of a chunk); block_ids/offsets:
+        ``[B]`` int32 (the scheduler routes inactive slots / pad
+        positions to the null block 0). The paged impl defers every
+        layer's write to one call at the end of the step (``n`` = the
+        layers it ran: all of them, or the self-draft's prefix, whose
+        K/V are bit-identical to the target's for those layers); the
+        gather impl, whose kernel needs the current token in the pool
+        before it reads, calls it per layer with ``n`` = 1.
+
+        The scatter indexes the pool's two leading dimensions only (the
+        folded row ``layer*N + block`` and the offset) and the update is
+        ``[n*B, W]``, untransposed: that is what lets the TPU compiler
+        update the donated pool in place (class docstring)."""
+        n, B = k_new.shape[:2]
+        rows = self.layer_rows(block_ids, first_layer, n).reshape(-1)
+        offs = jnp.broadcast_to(offsets, (n, B)).reshape(-1)
+
+        def token_rows(x, width):   # [n, B, ...] -> [n*B, width], 0-padded
+            x = x.reshape(n * B, -1)
+            return jnp.pad(x, ((0, 0), (0, width - x.shape[1])))
+
         out = dict(pools)
-        if self.int8_kv:
-            kq, ks = quantize_kv(k_all)        # scales [L, B, H]
-            vq, vs = quantize_kv(v_all)
-            out["k"] = pools["k"].at[:, block_ids, :, offsets, :].set(
-                kq.transpose(1, 0, 2, 3))
-            out["v"] = pools["v"].at[:, block_ids, :, offsets, :].set(
-                vq.transpose(1, 0, 2, 3))
-            out["k_scale"] = pools["k_scale"].at[
-                :, block_ids, :, offsets].set(ks.transpose(1, 0, 2))
-            out["v_scale"] = pools["v_scale"].at[
-                :, block_ids, :, offsets].set(vs.transpose(1, 0, 2))
-        else:
-            dt = pools["k"].dtype
-            out["k"] = pools["k"].at[:, block_ids, :, offsets, :].set(
-                k_all.transpose(1, 0, 2, 3).astype(dt))
-            out["v"] = pools["v"].at[:, block_ids, :, offsets, :].set(
-                v_all.transpose(1, 0, 2, 3).astype(dt))
-        return out
-
-    def write_first_layers(self, pools, k_all, v_all, block_ids, offsets,
-                           n_layers):
-        """Write the FIRST ``n_layers`` layers' K/V in one scatter apiece
-        — the truncated-layer self-draft's write (serving/speculative.py):
-        a draft that is the target's first ``n_layers`` layers produces
-        bit-identical K/V for those layers, so its speculative positions
-        land in the SAME pools and the verify pass simply overwrites all
-        layers at the accepted positions.
-
-        k_all/v_all: ``[n_layers, B, H, D]``; block_ids/offsets: ``[B]``
-        int32; ``n_layers`` is a static Python int (the static slice
-        keeps this the same one-scatter shape as
-        :meth:`write_all_layers`, just over a layer prefix)."""
-        n = int(n_layers)
-        if n == self.n_layer:
-            return self.write_all_layers(pools, k_all, v_all, block_ids,
-                                         offsets)
-        with jax.named_scope("kv_write"):
-            out = dict(pools)
+        for name, new in (("k", k_new), ("v", v_new)):
             if self.int8_kv:
-                kq, ks = quantize_kv(k_all)        # scales [n, B, H]
-                vq, vs = quantize_kv(v_all)
-                out["k"] = pools["k"].at[:n, block_ids, :, offsets, :].set(
-                    kq.transpose(1, 0, 2, 3))
-                out["v"] = pools["v"].at[:n, block_ids, :, offsets, :].set(
-                    vq.transpose(1, 0, 2, 3))
-                out["k_scale"] = pools["k_scale"].at[
-                    :n, block_ids, :, offsets].set(ks.transpose(1, 0, 2))
-                out["v_scale"] = pools["v_scale"].at[
-                    :n, block_ids, :, offsets].set(vs.transpose(1, 0, 2))
-            else:
-                dt = pools["k"].dtype
-                out["k"] = pools["k"].at[:n, block_ids, :, offsets, :].set(
-                    k_all.transpose(1, 0, 2, 3).astype(dt))
-                out["v"] = pools["v"].at[:n, block_ids, :, offsets, :].set(
-                    v_all.transpose(1, 0, 2, 3).astype(dt))
+                new, scale = quantize_kv(new)           # scales [n, B, H]
+                out[name + "_scale"] = pools[name + "_scale"].at[
+                    rows, offs].set(token_rows(scale, self.scale_width))
+            out[name] = pools[name].at[rows, offs].set(token_rows(
+                new.astype(pools[name].dtype), self.row_width))
         return out
 
     # ------------------------------------------------------ traced gather
@@ -560,23 +537,23 @@ class PagedKVCache:
         squeeze = block_tables.ndim == 1
         bt = block_tables[None] if squeeze else block_tables
         B, MB = bt.shape
+        H, D = self.n_head, self.head_dim
         T = MB * self.block_size
+        rows = self.layer_rows(bt, layer)[0]            # [B, MB]
 
-        def _g4(pool):   # [N,H,BS,D] -> [B,H,T,D]
-            g = pool[bt]                      # [B, MB, H, BS, D]
-            g = g.transpose(0, 2, 1, 3, 4)    # [B, H, MB, BS, D]
-            return g.reshape(B, self.n_head, T, self.head_dim)
+        def _g4(pool):   # [L*N,BS,W] -> [B,H,T,D]
+            g = pool[rows][..., :H * D]                 # [B, MB, BS, H*D]
+            return g.reshape(B, T, H, D).transpose(0, 2, 1, 3)
 
-        def _g3(pool):   # [N,H,BS] -> [B,H,T]
-            g = pool[bt].transpose(0, 2, 1, 3)
-            return g.reshape(B, self.n_head, T)
+        def _g3(pool):   # [L*N,BS,H] -> [B,H,T]
+            return pool[rows][..., :H].reshape(B, T, H).transpose(0, 2, 1)
 
-        k = _g4(pools["k"][layer])
-        v = _g4(pools["v"][layer])
+        k = _g4(pools["k"])
+        v = _g4(pools["v"])
         ks = vs = None
         if self.int8_kv:
-            ks = _g3(pools["k_scale"][layer])
-            vs = _g3(pools["v_scale"][layer])
+            ks = _g3(pools["k_scale"])
+            vs = _g3(pools["v_scale"])
         if squeeze:
             k, v = k[0], v[0]
             if ks is not None:

@@ -13,22 +13,27 @@ flash-style online-softmax loop over KV *blocks* with a DYNAMIC trip
 count — ``ceil(max_past_len / block_size)`` is a traced scalar, so XLA
 lowers the ``fori_loop`` to a while loop whose iterations touch only
 blocks that actually hold tokens. One block gather per iteration
-(``[B, H, block_size, D]``, consumed immediately — never a full-window
-materialisation), one compiled program regardless of how lengths evolve.
+(``[B, block_size, W]`` token rows, consumed immediately — never a
+full-window materialisation), one compiled program regardless of how
+lengths evolve.
 
-Both functions attend over the PAST pool only and fold the current
+The functions attend over the PAST pool only and fold the current
 token/chunk from registers (an extra online-softmax term / an intra-chunk
 causal piece merged in). That lets the runner defer every layer's KV
-write into ONE stacked scatter per step (kv_cache.write_all_layers) —
-XLA scatter dispatch was the dominant per-step cost once attention
-stopped reading dead columns. The int8 KV layout dequantises per block
-from the per-row scale pools; the current token stays in registers at
-full precision (it is quantised only when written, exactly like the
-flax decode path, which attends to the quantised value from the NEXT
-step on).
+write into ONE scatter per pool per step (kv_cache.write_layers). The
+pools are the row-shaped arrays of kv_cache.PagedKVCache, read as
+``pool[first_block + ids]``: a gather on the leading dimension alone,
+which the TPU compiler serves from the donated pool where it lies. (The
+former ``pool[layer, ids]`` on a ``[L, N, H, BS, D]`` pool had every
+program convert the whole pool to row-major first, and the stacked write
+convert it twice more — PERF.md, PR 27.) The int8 KV layout dequantises
+per block from the per-row scale pools; the current token stays in
+registers at full precision (it is quantised only when written, exactly
+like the flax decode path, which attends to the quantised value from the
+NEXT step on).
 
 Each function traces under ``jax.named_scope("paged_attention")`` (and the
-stacked write under ``"kv_write"``): trace-time only, the name a profile's
+step's KV write under ``"kv_write"``): trace-time only, the name a profile's
 operations carry in their ``tf_op`` stat.
 
 Both impls are selectable per engine (``serving.attention_impl``) and
@@ -41,6 +46,17 @@ import jax.numpy as jnp
 NEG_INF = -1e30
 
 
+def _read_blocks(pool, scale_pool, rows, H, D):
+    """``pool[rows]`` as float32 ``[*rows.shape, BS, H, D]``: the pad
+    lanes past ``H*D`` sliced off, int8 rows dequantised by their
+    per-row scales."""
+    kb = pool[rows][..., :H * D]
+    kb = kb.reshape(kb.shape[:-1] + (H, D)).astype(jnp.float32)
+    if scale_pool is not None:
+        kb = kb * scale_pool[rows][..., :H, None]
+    return kb
+
+
 def _merge(m1, l1, a1, m2, l2, a2):
     """Combine two online-softmax partials over disjoint key sets."""
     m = jnp.maximum(m1, m2)
@@ -50,41 +66,31 @@ def _merge(m1, l1, a1, m2, l2, a2):
 
 
 @jax.named_scope("paged_attention")
-def paged_decode_attention(q, k_cur, v_cur, layer, k_pool, v_pool,
+def paged_decode_attention(q, k_cur, v_cur, first_block, k_pool, v_pool,
                            block_tables, past_lens, *, k_scale_pool=None,
                            v_scale_pool=None, sm_scale=None):
     """One decode token per slot over the paged pools.
 
     q/k_cur/v_cur: ``[B, H, D]`` (the current token's K/V stay in
-    registers — the pool write is deferred); pools: the layer-STACKED
-    ``[L, N, H, BS, D]`` arrays indexed as ``pool[layer, ids]`` inside
-    the loop (slicing the stacked pool outside the loop would
-    materialise a per-layer copy); block_tables: ``[B, MB]`` int32;
-    past_lens: ``[B]`` int32 tokens ALREADY in the pool. Returns
+    registers — the pool write is deferred); pools: the
+    ``[L*N, BS, W]`` row arrays; first_block: the pool row of this
+    layer's block 0 (``layer * num_blocks``); block_tables: ``[B, MB]``
+    int32; past_lens: ``[B]`` int32 tokens ALREADY in the pool. Returns
     ``[B, H, D]`` fp32.
     """
     B, H, D = q.shape
-    BS = k_pool.shape[3]
+    BS = k_pool.shape[1]
     if sm_scale is None:
         sm_scale = D ** -0.5
-    quantized = k_scale_pool is not None
     qf = q.astype(jnp.float32)
     n_blocks = ((jnp.max(past_lens) + BS - 1) // BS).astype(jnp.int32)
 
     def body(i, carry):
         m, l, acc = carry
-        ids = block_tables[:, i]                       # [B]
-        kb = k_pool[layer, ids]                        # [B, H, BS, D]
-        vb = v_pool[layer, ids]
-        if quantized:
-            kb = kb.astype(jnp.float32) \
-                * k_scale_pool[layer, ids][..., None]
-            vb = vb.astype(jnp.float32) \
-                * v_scale_pool[layer, ids][..., None]
-        else:
-            kb = kb.astype(jnp.float32)
-            vb = vb.astype(jnp.float32)
-        s = jnp.einsum("bhd,bhsd->bhs", qf, kb) * sm_scale
+        rows = first_block + block_tables[:, i]        # [B]
+        kb = _read_blocks(k_pool, k_scale_pool, rows, H, D)  # [B,BS,H,D]
+        vb = _read_blocks(v_pool, v_scale_pool, rows, H, D)
+        s = jnp.einsum("bhd,bshd->bhs", qf, kb) * sm_scale
         col = i * BS + jnp.arange(BS, dtype=jnp.int32)
         s = jnp.where(col[None, None, :] < past_lens[:, None, None],
                       s, NEG_INF)
@@ -92,7 +98,7 @@ def paged_decode_attention(q, k_cur, v_cur, layer, k_pool, v_pool,
         p = jnp.exp(s - m_new[..., None])
         alpha = jnp.exp(m - m_new)
         l_new = l * alpha + jnp.sum(p, axis=-1)
-        acc = acc * alpha[..., None] + jnp.einsum("bhs,bhsd->bhd", p, vb)
+        acc = acc * alpha[..., None] + jnp.einsum("bhs,bshd->bhd", p, vb)
         return m_new, l_new, acc
 
     m0 = jnp.full((B, H), NEG_INF, jnp.float32)
@@ -112,7 +118,7 @@ def paged_decode_attention(q, k_cur, v_cur, layer, k_pool, v_pool,
 
 
 @jax.named_scope("paged_attention")
-def paged_verify_attention(q, k_chunk, v_chunk, layer, k_pool, v_pool,
+def paged_verify_attention(q, k_chunk, v_chunk, first_block, k_pool, v_pool,
                            block_tables, past_lens, *, k_scale_pool=None,
                            v_scale_pool=None, sm_scale=None):
     """Speculative verify: ``C = K+1`` queries PER SLOT over each slot's
@@ -131,27 +137,18 @@ def paged_verify_attention(q, k_chunk, v_chunk, layer, k_pool, v_pool,
     ``[B, H, C, D]`` fp32.
     """
     B, H, C, D = q.shape
-    BS = k_pool.shape[3]
+    BS = k_pool.shape[1]
     if sm_scale is None:
         sm_scale = D ** -0.5
-    quantized = k_scale_pool is not None
     qf = q.astype(jnp.float32)
     n_blocks = ((jnp.max(past_lens) + BS - 1) // BS).astype(jnp.int32)
 
     def body(i, carry):
         m, l, acc = carry
-        ids = block_tables[:, i]                       # [B]
-        kb = k_pool[layer, ids]                        # [B, H, BS, D]
-        vb = v_pool[layer, ids]
-        if quantized:
-            kb = kb.astype(jnp.float32) \
-                * k_scale_pool[layer, ids][..., None]
-            vb = vb.astype(jnp.float32) \
-                * v_scale_pool[layer, ids][..., None]
-        else:
-            kb = kb.astype(jnp.float32)
-            vb = vb.astype(jnp.float32)
-        s = jnp.einsum("bhcd,bhsd->bhcs", qf, kb) * sm_scale
+        rows = first_block + block_tables[:, i]        # [B]
+        kb = _read_blocks(k_pool, k_scale_pool, rows, H, D)  # [B,BS,H,D]
+        vb = _read_blocks(v_pool, v_scale_pool, rows, H, D)
+        s = jnp.einsum("bhcd,bshd->bhcs", qf, kb) * sm_scale
         col = i * BS + jnp.arange(BS, dtype=jnp.int32)
         s = jnp.where(col[None, None, None, :]
                       < past_lens[:, None, None, None], s, NEG_INF)
@@ -160,7 +157,7 @@ def paged_verify_attention(q, k_chunk, v_chunk, layer, k_pool, v_pool,
         alpha = jnp.exp(m - m_new)
         l_new = l * alpha + jnp.sum(p, axis=-1)
         acc = acc * alpha[..., None] \
-            + jnp.einsum("bhcs,bhsd->bhcd", p, vb)
+            + jnp.einsum("bhcs,bshd->bhcd", p, vb)
         return m_new, l_new, acc
 
     m0 = jnp.full((B, H, C), NEG_INF, jnp.float32)
@@ -183,7 +180,7 @@ def paged_verify_attention(q, k_chunk, v_chunk, layer, k_pool, v_pool,
 
 
 @jax.named_scope("paged_attention")
-def paged_prefill_attention(q, k_chunk, v_chunk, layer, k_pool, v_pool,
+def paged_prefill_attention(q, k_chunk, v_chunk, first_block, k_pool, v_pool,
                             bt_row, pos, start, *, k_scale_pool=None,
                             v_scale_pool=None, sm_scale=None):
     """Chunk attention for ONE slot: ``C`` queries at positions ``pos``
@@ -195,34 +192,25 @@ def paged_prefill_attention(q, k_chunk, v_chunk, layer, k_pool, v_pool,
     fp32.
     """
     H, C, D = q.shape
-    BS = k_pool.shape[3]
+    BS = k_pool.shape[1]
     if sm_scale is None:
         sm_scale = D ** -0.5
-    quantized = k_scale_pool is not None
     qf = q.astype(jnp.float32)
     n_blocks = ((start + BS - 1) // BS).astype(jnp.int32)
 
     def body(i, carry):
         m, l, acc = carry
-        bid = bt_row[i]
-        kb = k_pool[layer, bid]                        # [H, BS, D]
-        vb = v_pool[layer, bid]
-        if quantized:
-            kb = kb.astype(jnp.float32) \
-                * k_scale_pool[layer, bid][..., None]
-            vb = vb.astype(jnp.float32) \
-                * v_scale_pool[layer, bid][..., None]
-        else:
-            kb = kb.astype(jnp.float32)
-            vb = vb.astype(jnp.float32)
-        s = jnp.einsum("hcd,hsd->hcs", qf, kb) * sm_scale
+        row = first_block + bt_row[i]
+        kb = _read_blocks(k_pool, k_scale_pool, row, H, D)   # [BS, H, D]
+        vb = _read_blocks(v_pool, v_scale_pool, row, H, D)
+        s = jnp.einsum("hcd,shd->hcs", qf, kb) * sm_scale
         col = i * BS + jnp.arange(BS, dtype=jnp.int32)
         s = jnp.where(col[None, None, :] < start, s, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1))
         p = jnp.exp(s - m_new[..., None])
         alpha = jnp.exp(m - m_new)
         l_new = l * alpha + jnp.sum(p, axis=-1)
-        acc = acc * alpha[..., None] + jnp.einsum("hcs,hsd->hcd", p, vb)
+        acc = acc * alpha[..., None] + jnp.einsum("hcs,shd->hcd", p, vb)
         return m_new, l_new, acc
 
     m0 = jnp.full((H, C), NEG_INF, jnp.float32)

@@ -110,31 +110,38 @@ class PagedGPT2Runner:
         self.use_flash = use_flash
         self.n_head = cfg.n_head
         self.head_dim = cfg.n_embd // cfg.n_head
-        # donating the pools makes every KV scatter a true in-place
-        # update instead of a whole-pool copy per layer per step
-        # (measured 14x on the CPU backend, which aliases fine too); the
-        # server re-threads the returned pools so the stale buffers are
-        # never touched
+        # the pools are donated and the server re-threads the returned
+        # ones, so the stale buffers are never touched. Donation alone
+        # does not make the KV scatter an in-place update on the TPU: it
+        # gives the compiler the alias, and the pools' row shape
+        # (kv_cache.PagedKVCache) is what lets it write there without
+        # converting the pool — the decode program's compile memory at
+        # gpt2-medium, 40 slots: 4.74 GB of arguments + 0.04 GB of
+        # temporaries, where the ``[L, N, H, BS, D]`` pool took 4.9 +
+        # 8.1 GB (PERF.md, PR 27; tests/unit/test_serving_pool_layout.py
+        # keeps the check)
         self._decode = jax.jit(self._decode_impl, donate_argnums=(2,))
         self._prefill = jax.jit(self._prefill_impl, donate_argnums=(2,))
         # copy-on-write block fork (prefix cache): ONE device block copy
-        # across every pool leaf (all layers in one update apiece, the
-        # same stacked layout the write scatters ride). A third tiny
+        # across every pool leaf (all layers in one update apiece, on
+        # the same folded rows the write scatters index). A third tiny
         # program — deliberately NOT part of decode/prefill, whose
         # signatures the one-program acceptance pins.
         self._copy_block = jax.jit(self._copy_block_impl,
                                    donate_argnums=(0,))
 
     # -------------------------------------------------------- block copy
-    @staticmethod
-    def _copy_block_impl(pools, src, dst):
-        """``pools[leaf][:, dst] = pools[leaf][:, src]`` for every leaf
-        (K, V and the int8 scales ride the same ``[L, N, ...]`` block
-        dim). src/dst are traced int32 scalars, so every fork reuses one
-        compiled program."""
-        return {name: p.at[:, dst].set(
-            jax.lax.dynamic_index_in_dim(p, src, axis=1, keepdims=False))
-            for name, p in pools.items()}
+    def _copy_block_impl(self, pools, src, dst):
+        """Block ``src`` -> block ``dst`` in every layer (rows
+        ``arange(L)*N + src`` -> ``+ dst``) of every leaf: K, V and the
+        int8 scales share the leading ``[L*N]`` block dim. src/dst are
+        traced int32 scalars, so every fork reuses one compiled
+        program."""
+        L = self.cache.n_layer
+        src_rows = self.cache.layer_rows(src, n_layers=L)
+        dst_rows = self.cache.layer_rows(dst, n_layers=L)
+        return {name: p.at[dst_rows].set(p[src_rows])
+                for name, p in pools.items()}
 
     def copy_block(self, pools, src, dst):
         """Fork one block's bytes: the COW path's single device op."""
@@ -170,7 +177,8 @@ class PagedGPT2Runner:
         if self.attention_impl == "paged":
             out = paged_decode_attention(
                 q, self._requant(k), self._requant(v),
-                layer, pools["k"], pools["v"], bt, pos,
+                layer * self.cache.num_blocks, pools["k"], pools["v"],
+                bt, pos,
                 k_scale_pool=pools["k_scale"] if int8 else None,
                 v_scale_pool=pools["v_scale"] if int8 else None)
             out = out.reshape(B, E).astype(x.dtype)
@@ -179,7 +187,8 @@ class PagedGPT2Runner:
         bs = self.cache.block_size
         row = jnp.take_along_axis(bt, (pos // bs)[:, None], axis=1)[:, 0]
         blk = jnp.where(active, row, 0)
-        pools = self.cache.write_decode(pools, layer, k, v, blk, pos % bs)
+        pools = self.cache.write_layers(pools, k[None], v[None], blk,
+                                        pos % bs, first_layer=layer)
         lens = pos + 1
         kg, vg, ksg, vsg = self.cache.gather(pools, layer, bt)
         q4 = q[:, :, None, :]
@@ -208,7 +217,8 @@ class PagedGPT2Runner:
             out = paged_prefill_attention(
                 qh, self._requant(k).transpose(1, 0, 2),
                 self._requant(v).transpose(1, 0, 2),
-                layer, pools["k"], pools["v"], bt_row, pos, start,
+                layer * self.cache.num_blocks, pools["k"], pools["v"],
+                bt_row, pos, start,
                 k_scale_pool=pools["k_scale"] if int8 else None,
                 v_scale_pool=pools["v_scale"] if int8 else None)
             out = out.transpose(1, 0, 2).reshape(C, E).astype(x.dtype)
@@ -219,7 +229,8 @@ class PagedGPT2Runner:
         valid = jnp.arange(C) < n_valid
         blk = jnp.where(valid,
                         bt_row[jnp.minimum(pos // bs, MB - 1)], 0)
-        pools = self.cache.write_chunk(pools, layer, k, v, blk, pos % bs)
+        pools = self.cache.write_layers(pools, k[None], v[None], blk,
+                                        pos % bs, first_layer=layer)
         kg, vg, ksg, vsg = self.cache.gather(pools, layer, bt_row)
         if int8:
             kg = (kg.astype(jnp.float32) * ksg[..., None]).astype(x.dtype)
@@ -269,14 +280,14 @@ class PagedGPT2Runner:
             x = x + a
             x = x + self._mlp(p, s, x)
         if kv_stack:
-            # paged impl: ONE stacked scatter for all layers; non-live
-            # slots land in the null block
+            # paged impl: ONE scatter per pool for the layers that ran;
+            # non-live slots land in the null block
             row = jnp.take_along_axis(bt, (pos // bs)[:, None],
                                       axis=1)[:, 0]
             blk = jnp.where(live, row, 0)
-            pools = self.cache.write_first_layers(
+            pools = self.cache.write_layers(
                 pools, jnp.stack([k for k, _ in kv_stack]),
-                jnp.stack([v for _, v in kv_stack]), blk, pos % bs, L)
+                jnp.stack([v for _, v in kv_stack]), blk, pos % bs)
         x = _ln(x, params["ln_f"])
         logits = jnp.einsum("be,ve->bv", x, params["wte"],
                             preferred_element_type=jnp.float32)
@@ -351,7 +362,7 @@ class PagedGPT2Runner:
             valid = jnp.arange(C) < n_valid
             blk = jnp.where(valid,
                             bt_row[jnp.minimum(pos // bs, MB - 1)], 0)
-            pools = self.cache.write_all_layers(
+            pools = self.cache.write_layers(
                 pools, jnp.stack([k for k, _ in kv_stack]),
                 jnp.stack([v for _, v in kv_stack]), blk, pos % bs)
         return pools

@@ -14,7 +14,8 @@ Two compiled programs, both static-shaped for the serving lifetime:
   first ``draft_layers`` of the target's own params pytree plus the
   shared ``ln_f``/tied head — zero extra weights to load, and its layer
   K/V are bit-identical to the target's, so draft writes land in the
-  same pools (``write_first_layers``) at the speculative positions.
+  same pools (``write_layers`` over a layer prefix) at the speculative
+  positions.
   An explicitly configured small model (``draft_params``) rides the same
   program; draft quality only moves the ACCEPTANCE RATE, never
   correctness — the verify pass decides every delivered token.
@@ -117,7 +118,7 @@ class SpeculativeDecoder:
                     budget):
         """K greedy steps through the first ``draft_layers`` of
         ``params`` (the scan body is the runner's own ``_stack_decode``
-        over a layer prefix). Writes ride ``write_first_layers`` at the
+        over a layer prefix). Writes ride ``write_layers`` at the
         speculative positions, budget-masked to the null block beyond
         each slot's allocation. Returns ``(pools, drafted [K, B])``."""
         r = self.runner
@@ -159,7 +160,7 @@ class SpeculativeDecoder:
         if r.attention_impl == "paged":
             out = paged_verify_attention(
                 heads(q), heads(r._requant(k)), heads(r._requant(v)),
-                layer, pools["k"], pools["v"], bt, pos,
+                layer * cache.num_blocks, pools["k"], pools["v"], bt, pos,
                 k_scale_pool=pools["k_scale"] if int8 else None,
                 v_scale_pool=pools["v_scale"] if int8 else None)
             out = out.transpose(0, 2, 1, 3).reshape(N, E).astype(x.dtype)
@@ -170,8 +171,9 @@ class SpeculativeDecoder:
         row = jnp.take_along_axis(bt, jnp.minimum(poss // bs, MB - 1),
                                   axis=1)                # [B, C]
         blk = jnp.where(live_w, row, 0).reshape(-1)
-        pools = cache.write_decode(pools, layer, k, v, blk,
-                                   (poss % bs).reshape(-1))
+        pools = cache.write_layers(pools, k[None], v[None], blk,
+                                   (poss % bs).reshape(-1),
+                                   first_layer=layer)
         kg, vg, ksg, vsg = cache.gather(pools, layer, bt)  # [B, H, T, D]
         if int8:
             kg = (kg.astype(jnp.float32) * ksg[..., None]).astype(x.dtype)
@@ -231,7 +233,7 @@ class SpeculativeDecoder:
             row = jnp.take_along_axis(bt, jnp.minimum(poss // bs, MB - 1),
                                       axis=1)
             blk = jnp.where(live_w, row, 0).reshape(-1)
-            pools = cache.write_all_layers(
+            pools = cache.write_layers(
                 pools, jnp.stack([k for k, _ in kv_stack]),
                 jnp.stack([v for _, v in kv_stack]), blk,
                 (poss % bs).reshape(-1))

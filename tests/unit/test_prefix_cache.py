@@ -355,6 +355,34 @@ def _cache_on(eng, **over):
     return ServingEngine(eng, config=cfg, registry=MetricsRegistry())
 
 
+@pytest.mark.parametrize("int8_kv", [False, True],
+                         ids=["kv-float", "kv-int8"])
+def test_cow_fork_copies_every_layers_rows_and_nothing_else(int8_kv):
+    """The fork's device copy on pools whose rows are padded (5 heads of
+    64 = 2.5 lanes): block ``src``'s rows of EVERY layer land in block
+    ``dst`` of the same layer, in every leaf (K, V and the int8 scales),
+    and no other row of any pool changes."""
+    from deepspeed_tpu.serving.runner import PagedGPT2Runner
+    L, N, BS, src, dst = 3, 7, 4, 2, 5
+    cfg = GPT2Config(vocab_size=256, n_positions=64, n_embd=320,
+                     n_layer=L, n_head=5)
+    cache = PagedKVCache(L, 5, 64, BS, N, dtype=jnp.float32,
+                         int8_kv=int8_kv)
+    runner = PagedGPT2Runner(GPT2LMHeadModel(cfg), cache)
+    rng = np.random.default_rng(17)
+    before = {name: rng.integers(-100, 100, p.shape).astype(p.dtype)
+              for name, p in cache.init_pools().items()}
+    assert before["k"].shape == (L * N, BS, 384)
+    after = runner.copy_block(
+        {name: jnp.asarray(p) for name, p in before.items()}, src, dst)
+    dst_rows = np.arange(L) * N + dst
+    for name, old in before.items():
+        new = np.asarray(after[name])
+        assert np.array_equal(new[dst_rows], old[np.arange(L) * N + src])
+        untouched = np.setdiff1d(np.arange(L * N), dst_rows)
+        assert np.array_equal(new[untouched], old[untouched]), name
+
+
 def test_e2e_cow_parity_one_program_and_counters(tiny_engine):
     """The acceptance guard: shared-prefix traffic (including a
     fully-cached prompt, the COW-fork path) stays greedy-bit-exact vs
@@ -458,7 +486,9 @@ def test_e2e_int8_shared_blocks_bit_exact():
         np.random.default_rng(11).integers(0, 256, (16,)), np.int32)
 
     def prefix_pool_bytes(srv, blocks):
-        return {name: np.asarray(p)[:, blocks]
+        rows = np.asarray(srv.cache.layer_rows(
+            np.asarray(blocks), n_layers=cfg.n_layer))
+        return {name: np.asarray(p)[rows]
                 for name, p in srv.pools.items()}
 
     srv_a = _cache_on(eng)

@@ -530,6 +530,50 @@ def test_e2e_int8_kv_and_int8_weights_parity():
     assert srv.compile_stats()["decode_signatures"] == 1
 
 
+@pytest.mark.parametrize("kv", ["auto", "int8"], ids=["kv-float", "kv-int8"])
+@pytest.mark.parametrize("impl", ["paged", "gather"])
+def test_e2e_lanes_that_do_not_divide(impl, kv):
+    """5 heads of 64: ``n_head*head_dim`` = 320 is 2.5 lanes of 128, so
+    the pools' rows are padded to 384 (gpt2-xl's 1,600 -> 1,664 is the
+    deployed case). Both attention impls serve the flax decode path's
+    tokens one for one, and the pad lanes of every row written stay
+    zero: nothing past lane 320 ever reaches a product."""
+    groups.destroy()
+    groups.initialize()
+    cfg = GPT2Config(vocab_size=256, n_positions=64, n_embd=320,
+                     n_layer=2, n_head=5, kv_cache_dtype=kv)
+    model = GPT2LMHeadModel(cfg)
+    params = model.init(jax.random.PRNGKey(5),
+                        {"input_ids": jnp.zeros((1, 8), jnp.int32)})["params"]
+    eng = deepspeed_tpu.init_inference(model, params=params,
+                                       dtype=jnp.float32)
+    from deepspeed_tpu.serving.server import ServingEngine
+    srv = ServingEngine(eng, config={"max_batch": 2, "block_size": 8,
+                                     "prefill_chunk": 6,
+                                     "attention_impl": impl},
+                        registry=MetricsRegistry())
+    cache = srv.cache
+    assert cache.int8_kv == (kv == "int8")
+    assert cache.row_width == 384
+    rows = (cfg.n_layer * cache.num_blocks, 8)
+    assert srv.pools["k"].shape == srv.pools["v"].shape == rows + (384,)
+    assert cache.pool_bytes() == sum(p.nbytes for p in srv.pools.values())
+    rng = np.random.default_rng(29)
+    cases = [(13, 6), (5, 9), (21, 4)]
+    prompts = [rng.integers(0, 256, (n,)).astype(np.int32)
+               for n, _ in cases]
+    rids = [srv.submit(p, max_new_tokens=g)
+            for p, (_, g) in zip(prompts, cases)]
+    outs = {o.req_id: o for o in srv.serve_forever()}
+    for rid, p, (_, g) in zip(rids, prompts, cases):
+        assert outs[rid].tokens == _baseline(eng, p, g), (impl, kv, rid)
+    for name, pool in srv.pools.items():
+        used = cfg.n_head if name.endswith("_scale") else 320
+        pool = np.asarray(pool)
+        assert pool[..., :used].any(), f"{name}: nothing was written"
+        assert not pool[..., used:].any(), f"{name}: pad lanes written"
+
+
 def test_e2e_eviction_parity_and_allocator_clean():
     """Tiny pool forces preemption mid-generation; recompute-on-resume
     must reproduce the uncontended greedy tokens exactly, and the

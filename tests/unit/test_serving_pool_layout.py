@@ -1,0 +1,161 @@
+"""The KV pools stay where they lie: a TPU-compile rehearsal, no chip.
+
+Each serving program is lowered with abstract parameters and pools
+(``jax.ShapeDtypeStruct`` on a described ``v5e`` device) at the published
+widths of the benchmark's two configurations, with the cells' slot and
+block counts, and compiled by the installed libtpu from this CPU process.
+Nothing runs; what is asserted is the compile's own account:
+
+* the temporaries stay under 5% of the pools' bytes,
+* every pool is aliased to its output (the donation is honoured), and
+* no ``copy`` in the compiled program has a pool's shape.
+
+The ``[L, N, H, BS, D]`` pool and its ``.at[:, blk, :, off, :]`` write,
+which this shape replaced, failed all three: both programs converted the
+whole pool there and back (PERF.md, PR 27), and ``test_detector_sees_*``
+keeps that pattern as the control that the detector is not blind.
+
+Depth is cut to 2 layers (the layer count multiplies the pool's leading
+dimension and nothing else) and the vocabulary to 128 rows: at gpt2-xl's
+width the compiler also relayouts the 1,600-wide ``wte`` (161 MB at the
+published vocabulary), which is no pool and would drown what is measured,
+and the sampler's sort over 50,257 logits is most of a compile's time.
+
+Every libtpu call sits in a fixture or a test: only the worker that is
+given this file may load the library (on-chip-measurement guide, §2).
+"""
+
+import functools
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2LMHeadModel
+from deepspeed_tpu.serving.kv_cache import PagedKVCache
+from deepspeed_tpu.serving.runner import PagedGPT2Runner
+from deepspeed_tpu.serving.speculative import SpeculativeDecoder
+
+# benchmark/configs/*.json widths; slots and blocks as the serve cells run
+# them (40 and 8 slots of 64 blocks of 16, plus the null block)
+CELLS = {
+    "gpt2-medium": dict(n_embd=1024, n_head=16, slots=40, num_blocks=2561),
+    "gpt2-xl": dict(n_embd=1600, n_head=25, slots=8, num_blocks=513),
+}
+N_LAYER, BLOCK_SIZE, MAX_BLOCKS, CHUNK, SPEC_K = 2, 16, 64, 32, 3
+HLO_DTYPE = {"bfloat16": "bf16", "int8": "s8", "float32": "f32"}
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _spec(one_chip):
+    """``spec(shape, dtype)``: an abstract array on the described chip."""
+    return functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+
+
+def _pool_copies(hlo_text, pool_specs):
+    """The ``copy`` instructions whose result has a pool's dtype and
+    shape."""
+    shapes = {f"{HLO_DTYPE[jnp.dtype(s.dtype).name]}"
+              f"[{','.join(map(str, s.shape))}]" for s in pool_specs}
+    found = []
+    for line in hlo_text.splitlines():
+        m = re.search(r"= (\w+\[[\d,]*\])\S* copy\(", line)
+        if m and m.group(1) in shapes:
+            found.append(line.strip()[:160])
+    return found
+
+
+def _programs(cell, int8_kv, one_chip):
+    """name -> (jitted program, abstract arguments), and the pools."""
+    spec = _spec(one_chip)
+    cfg = GPT2Config(vocab_size=128, n_positions=1024,
+                     n_embd=cell["n_embd"], n_layer=N_LAYER,
+                     n_head=cell["n_head"])
+    model = GPT2LMHeadModel(cfg)
+    params = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0),
+        {"input_ids": jnp.zeros((1, 8), jnp.int32)})["params"])
+    params = jax.tree.map(lambda a: spec(a.shape, jnp.bfloat16), params)
+    cache = PagedKVCache(N_LAYER, cfg.n_head, cfg.n_embd // cfg.n_head,
+                         BLOCK_SIZE, cell["num_blocks"],
+                         dtype=jnp.bfloat16, int8_kv=int8_kv)
+    runner = PagedGPT2Runner(model, cache)
+    spec_dec = SpeculativeDecoder(runner, k=SPEC_K, draft_layers=1)
+    pools = {name: spec(shape, dtype)
+             for name, (shape, dtype) in cache._pool_shapes().items()}
+    B, i32, f32 = cell["slots"], jnp.int32, jnp.float32
+    slot = [spec((B, MAX_BLOCKS), i32), spec((B,), i32),
+            spec((B,), jnp.bool_)]                      # bt, pos, active
+    sampling = [spec((B,), f32), spec((B,), f32),
+                spec((B, 2), jnp.uint32), spec((B,), i32)]
+    return cache, pools, {
+        "decode": (runner._decode,
+                   [params, {}, pools, *slot, spec((B,), i32), *sampling]),
+        "prefill": (runner._prefill,
+                    [params, {}, pools, spec((MAX_BLOCKS,), i32),
+                     spec((CHUNK,), i32), spec((), i32), spec((), i32)]),
+        "copy_block": (runner._copy_block,
+                       [pools, spec((), i32), spec((), i32)]),
+        "draft": (spec_dec._draft,
+                  [params, {}, pools, *slot, spec((B,), i32),
+                   spec((B,), i32)]),
+        "verify": (spec_dec._verify,
+                   [params, {}, pools, *slot, spec((SPEC_K, B), i32),
+                    spec((B,), i32), *sampling]),
+    }
+
+
+@pytest.mark.parametrize("int8_kv", [False, True], ids=["kv-bf16", "kv-int8"])
+@pytest.mark.parametrize("program", ["decode", "prefill", "copy_block",
+                                     "draft", "verify"])
+@pytest.mark.parametrize("config", list(CELLS))
+def test_program_leaves_the_pools_in_place(one_chip, config, program,
+                                           int8_kv):
+    cache, pools, programs = _programs(CELLS[config], int8_kv, one_chip)
+    fn, args = programs[program]
+    compiled = fn.lower(*args).compile()
+    mem = compiled.memory_analysis()
+    pool_bytes = cache.pool_bytes()
+    assert mem.temp_size_in_bytes < 0.05 * pool_bytes, (
+        f"{mem.temp_size_in_bytes / 1e6:.1f} MB of temporaries beside "
+        f"{pool_bytes / 1e6:.1f} MB of pools")
+    assert mem.alias_size_in_bytes >= pool_bytes, (
+        f"only {mem.alias_size_in_bytes} of the pools' {pool_bytes} "
+        f"bytes are updated in place")
+    copies = _pool_copies(compiled.as_text(), pools.values())
+    assert not copies, "whole-pool copies:\n" + "\n".join(copies)
+
+
+def test_detector_sees_the_stacked_write_convert_the_pool(one_chip):
+    """The control: the pool as it was (``[L, N, H, BS, D]``, one scatter
+    for all layers with a ``:`` over layers and over heads ahead of the
+    indexed dimensions) compiles to whole-pool copies and temporaries of
+    the pools' own size, and the detector above says so."""
+    L, N, H, BS, D, B = 4, 2561, 16, 16, 64, 40
+    spec = _spec(one_chip)
+
+    def step(k_pool, new, ids, blk, off):
+        read = k_pool[1, ids].astype(jnp.float32).sum()
+        return k_pool.at[:, blk, :, off, :].set(new), read
+
+    pool = spec((L, N, H, BS, D), jnp.bfloat16)
+    compiled = jax.jit(step, donate_argnums=(0,)).lower(
+        pool, spec((B, L, H, D), jnp.bfloat16), spec((B,), jnp.int32),
+        spec((B,), jnp.int32), spec((B,), jnp.int32)).compile()
+    assert _pool_copies(compiled.as_text(), [pool])
+    pool_bytes = L * N * H * BS * D * 2
+    assert compiled.memory_analysis().temp_size_in_bytes >= pool_bytes

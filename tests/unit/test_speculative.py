@@ -39,11 +39,12 @@ SPEC_COMPILE = {"decode_signatures": 0, "prefill_signatures": 1,
                 "verify_signatures": 1}
 
 
-def _make_engine(seed=0, n_layer=4, kv="auto", dtype=jnp.float32):
+def _make_engine(seed=0, n_layer=4, kv="auto", dtype=jnp.float32,
+                 n_embd=32, n_head=2):
     groups.destroy()
     groups.initialize()
-    cfg = GPT2Config(vocab_size=256, n_positions=64, n_embd=32,
-                     n_layer=n_layer, n_head=2, kv_cache_dtype=kv)
+    cfg = GPT2Config(vocab_size=256, n_positions=64, n_embd=n_embd,
+                     n_layer=n_layer, n_head=n_head, kv_cache_dtype=kv)
     model = GPT2LMHeadModel(cfg)
     params = model.init(jax.random.PRNGKey(seed),
                         {"input_ids": jnp.zeros((1, 8), jnp.int32)})["params"]
@@ -154,6 +155,31 @@ def test_int8_weights_int8_kv_parity():
         return [outs[r].tokens for r in rids]
 
     assert serve(spec=True) == serve(spec=False)
+
+
+@pytest.mark.parametrize("impl", ["paged", "gather"])
+@pytest.mark.parametrize("kv", ["auto", "int8"], ids=["kv-float", "kv-int8"])
+def test_lanes_that_do_not_divide_parity(kv, impl):
+    """5 heads of 64 (2.5 lanes of 128: the pools' rows are padded to
+    384): the draft's write over a layer prefix, the verify read and its
+    write of all layers at K+1 positions serve generate()'s greedy
+    tokens one for one, under both attention impls."""
+    cfg, eng = _make_engine(seed=4, kv=kv, n_embd=320, n_head=5)
+    srv = ServingEngine(eng,
+                        config=_spec_cfg(extra={"attention_impl": impl}),
+                        registry=MetricsRegistry())
+    assert srv.cache.row_width == 384
+    rng = np.random.default_rng(31)
+    cases = [(11, 6), (3, 9), (22, 4)]
+    prompts = [rng.integers(0, cfg.vocab_size, (p,)).astype(np.int32)
+               for p, _ in cases]
+    rids = [srv.submit(p, max_new_tokens=g)
+            for p, (_, g) in zip(prompts, cases)]
+    outs = {o.req_id: o for o in srv.serve_forever()}
+    for rid, p, (_, g) in zip(rids, prompts, cases):
+        assert outs[rid].tokens == _baseline(eng, p, g), (kv, impl, rid)
+    assert srv.compile_stats() == SPEC_COMPILE
+    assert not np.asarray(srv.pools["k"])[..., 320:].any()
 
 
 def test_prefix_cache_composition(tiny):
