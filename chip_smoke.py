@@ -447,6 +447,29 @@ def _kernel_cases():
             return [("out", f(True), f(False))]
         return thunk
 
+    def paged_decode():
+        """The server's decode walk at gpt2-xl's row (25 heads in 1,664
+        lanes), ragged lengths: the kernel against the jnp loop, which on
+        the chip rounds nothing the kernel keeps."""
+        from deepspeed_tpu.serving import paged_attention as pa
+        H, D, W, BS, N = 25, 64, 1664, 16, 513
+        lens = np.array([0, 1, 16, 17, 150, 333, 640, 0], np.int32)
+        bt = np.zeros((len(lens), 64), np.int32)
+        blocks = iter(np.random.default_rng(0).permutation(np.arange(1, N)))
+        for b, n in enumerate(lens):
+            for i in range(-(-int(n) // BS)):
+                bt[b, i] = next(blocks)
+        lanes = (jnp.arange(W) < H * D).astype(jnp.bfloat16)
+        k_pool, v_pool = (rnd((2 * N, BS, W), i, jnp.bfloat16) * lanes
+                          for i in (0, 1))
+        q, k_cur, v_cur = (rnd((len(lens), H, D), i, jnp.bfloat16)
+                           for i in (2, 3, 4))
+        args = (q, k_cur, v_cur, N, k_pool, v_pool, jnp.asarray(bt),
+                jnp.asarray(lens))
+        return [("out", pa._decode_kernel_call(*args, D ** -0.5),
+                 jax.jit(lambda *a: pa._decode_loop(
+                     *a, None, None, D ** -0.5))(*args))]
+
     def layer_norm():
         from deepspeed_tpu.ops.transformer.fused import fused_layer_norm
         x, g, b = rnd((8192, 1024), 0), rnd((1024,), 1) + 1.0, rnd((1024,), 2)
@@ -545,6 +568,8 @@ def _kernel_cases():
          sparse(2048, 16, 64, 4, "predicated")),
         ("decode attention bf16 cache", "bfloat16", decode(False)),
         ("decode attention int8 cache", "bfloat16", decode(True)),
+        ("paged decode walk, 25 heads in 1,664 lanes", "float32",
+         paged_decode),
         ("fused layer norm fwd+bwd", "float32", layer_norm),
         ("fused bias-gelu fwd+bwd", "float32", bias_gelu),
         ("fused softmax", "float32", softmax),

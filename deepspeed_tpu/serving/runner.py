@@ -78,9 +78,10 @@ class PagedGPT2Runner:
     def __init__(self, model, cache, use_flash=None,
                  attention_impl="paged", decode_steps=1):
         """``attention_impl``: ``"paged"`` (default) streams attention
-        over LIVE KV blocks with a dynamic-trip-count loop — per-step
-        traffic scales with how many tokens actually exist
-        (serving/paged_attention.py). ``"gather"`` materialises each
+        over LIVE KV blocks — per-step traffic scales with how many
+        tokens actually exist (serving/paged_attention.py: a Pallas
+        kernel over each slot's own blocks for decode on a TPU, a
+        dynamic-trip-count loop elsewhere). ``"gather"`` materialises each
         slot's pages into the contiguous view the
         ops/transformer/decode.py Pallas kernel reads — fixed
         ``T_max``-window traffic, but the decode GEMMs run in the tuned

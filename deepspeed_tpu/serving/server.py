@@ -27,6 +27,7 @@ from typing import List, Optional
 import numpy as np
 
 from deepspeed_tpu.serving.kv_cache import PagedKVCache
+from deepspeed_tpu.serving.paged_attention import decode_kernel_runs
 from deepspeed_tpu.serving.prefill import ChunkedPrefill
 from deepspeed_tpu.serving.runner import PagedGPT2Runner
 from deepspeed_tpu.serving.sampling import make_rng_lane
@@ -573,23 +574,27 @@ class ServingEngine:
         return bt, pos, active, tok, temp, top_p, lanes, budget
 
     def _paged_block_counts(self, pos, active):
-        """KV blocks the active slots hold tokens in, and blocks the paged
-        loop visits for them: its trip count is the LONGEST sequence's
-        (paged_attention.py) and every trip gathers a block for each of the
-        ``max_batch`` rows. The ratio is the share of the loop's gathers
-        that read live tokens."""
+        """KV blocks the active slots hold tokens in, and blocks the
+        decode walk fetches for them (paged_attention.py): the kernel
+        fetches each slot's own blocks and no other, so the two are
+        equal; the jnp loop's trip count is the LONGEST sequence's and
+        every trip gathers a block for each of the ``max_batch`` rows.
+        The ratio is the share of fetched blocks that hold a live
+        token."""
         BS = self.cache.block_size
         blocks = -(-pos[active].astype(np.int64) // BS)
         needed = int(blocks.sum())
-        visited = self.max_batch * int(blocks.max(initial=0))
+        visited = (needed if decode_kernel_runs(self.cache.dtype)
+                   else self.max_batch * int(blocks.max(initial=0)))
         self.registry.counter(
             "serving_paged_blocks_needed_total",
             "KV blocks holding the decoding slots' tokens, summed over "
             "decode dispatches").inc(needed)
         self.registry.counter(
             "serving_paged_blocks_visited_total",
-            "KV blocks the paged loop gathered (max_batch x its trip "
-            "count), summed over decode dispatches").inc(visited)
+            "KV blocks the decode walk fetched (each slot's own in the "
+            "kernel, max_batch x the trip count in the jnp loop), summed "
+            "over decode dispatches").inc(visited)
         return needed, visited
 
     def _run_decode(self, decode_slots, acts=None):

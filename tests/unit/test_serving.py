@@ -677,3 +677,54 @@ def test_inference_checkpoint_load_telemetry(tmp_path):
     # engine is usable after the instrumented load
     loss = eng({"input_ids": jnp.zeros((1, 4), jnp.int32)})
     assert np.isfinite(float(loss))
+
+
+@pytest.mark.parametrize("kernel", [False, True], ids=["jnp-loop", "kernel"])
+def test_paged_block_counts_state_the_walk_that_runs(monkeypatch, kernel):
+    """A ragged batch: ``needed`` is each decoding slot's own block count
+    either way; ``visited`` is the same under the decode kernel, which
+    fetches no other block, and ``max_batch`` x the longest slot's under
+    the jnp loop. The ``serving_decode`` span's arguments and the registry
+    counters carry the same two numbers."""
+    from deepspeed_tpu.serving import server
+    from deepspeed_tpu.telemetry.tracer import Tracer, set_tracer
+    monkeypatch.setattr(server, "decode_kernel_runs", lambda dtype: kernel)
+    groups.destroy()
+    groups.initialize()
+    cfg = GPT2Config(vocab_size=256, n_positions=64, n_embd=32,
+                     n_layer=2, n_head=2)
+    model = GPT2LMHeadModel(cfg)
+    params = model.init(jax.random.PRNGKey(0),
+                        {"input_ids": jnp.zeros((1, 8), jnp.int32)})["params"]
+    eng = deepspeed_tpu.init_inference(model, params=params,
+                                       dtype=jnp.float32)
+    srv = server.ServingEngine(eng, config={"max_batch": 4, "block_size": 8},
+                               registry=MetricsRegistry())
+    pos = np.array([3, 0, 17, 40], np.int32)
+    active = np.array([True, False, True, True])
+    # blocks of 8: 1 + 3 + 5 needed; the loop walks 4 slots x 5 trips
+    assert srv._paged_block_counts(pos, active) == (9, 9 if kernel else 20)
+    assert srv._paged_block_counts(pos, np.zeros(4, bool)) == (0, 0)
+
+    rng = np.random.default_rng(2)
+    for n, new in [(5, 7), (19, 4), (30, 9)]:
+        srv.submit(rng.integers(0, 256, (n,)).astype(np.int32),
+                   max_new_tokens=new)
+    tracer = Tracer(enabled=True)
+    old = set_tracer(tracer)
+    try:
+        assert len(list(srv.serve_forever())) == 3
+    finally:
+        set_tracer(old)
+    spans = [e["args"] for e in tracer.events()
+             if e["name"] == "serving_decode"]
+    assert spans
+    needed = sum(a["blocks_needed"] for a in spans)
+    visited = sum(a["blocks_visited"] for a in spans)
+    assert (needed == visited) == kernel
+    assert all(a["blocks_needed"] <= a["blocks_visited"] for a in spans)
+    assert srv.registry.counter(
+        "serving_paged_blocks_needed_total").value == 9 + needed
+    assert srv.registry.counter(
+        "serving_paged_blocks_visited_total").value == (
+            (9 if kernel else 20) + visited)

@@ -10,6 +10,12 @@ Nothing runs; what is asserted is the compile's own account:
 * every pool is aliased to its output (the donation is honoured), and
 * no ``copy`` in the compiled program has a pool's shape.
 
+The decode and draft programs over bfloat16 pools are compiled as the
+chip runs them, with the decode walk's Pallas kernel in them
+(``paged_attention`` asks the platform, which is the CPU here, so the
+test answers for it): Mosaic builds the kernel for the v5e, and it reads
+the pools from HBM where they lie.
+
 The ``[L, N, H, BS, D]`` pool and its ``.at[:, blk, :, off, :]`` write,
 which this shape replaced, failed all three: both programs converted the
 whole pool there and back (PERF.md, PR 27), and ``test_detector_sees_*``
@@ -35,9 +41,11 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2LMHeadModel
+from deepspeed_tpu.serving import paged_attention
 from deepspeed_tpu.serving.kv_cache import PagedKVCache
 from deepspeed_tpu.serving.runner import PagedGPT2Runner
 from deepspeed_tpu.serving.speculative import SpeculativeDecoder
+from deepspeed_tpu.utils import groups
 
 # benchmark/configs/*.json widths; slots and blocks as the serve cells run
 # them (40 and 8 slots of 64 blocks of 16, plus the null block)
@@ -123,11 +131,19 @@ def _programs(cell, int8_kv, one_chip):
 @pytest.mark.parametrize("program", ["decode", "prefill", "copy_block",
                                      "draft", "verify"])
 @pytest.mark.parametrize("config", list(CELLS))
-def test_program_leaves_the_pools_in_place(one_chip, config, program,
-                                           int8_kv):
+def test_program_leaves_the_pools_in_place(one_chip, monkeypatch, config,
+                                           program, int8_kv):
+    # one described chip and no mesh, as the serve cells run
+    monkeypatch.setattr(paged_attention, "_interpret", lambda: False)
+    monkeypatch.setattr(groups, "_MESH", None)
     cache, pools, programs = _programs(CELLS[config], int8_kv, one_chip)
     fn, args = programs[program]
     compiled = fn.lower(*args).compile()
+    kernels = compiled.as_text().count('custom_call_target="tpu_custom_call"')
+    # the draft is one layer under a scan over its steps
+    walks = {"decode": N_LAYER, "draft": 1}.get(program, 0)
+    assert kernels == (0 if int8_kv else walks), (
+        f"{kernels} Mosaic calls in the {program} program")
     mem = compiled.memory_analysis()
     pool_bytes = cache.pool_bytes()
     assert mem.temp_size_in_bytes < 0.05 * pool_bytes, (
