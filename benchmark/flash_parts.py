@@ -1,8 +1,9 @@
 """One flash kernel's share of its roofline, for the readers that tell the
 forward from the backward: the kernels carry names of their own on the
 trace's ``XLA Ops`` line (``flash_fwd.N``, ``flash_dq.N``, ``flash_dkv.N``,
-the ``name=`` of each ``pl.pallas_call``), where ``train_flash_roofline``
-has to sum every custom call of the step."""
+the ``name=`` of each ``pl.pallas_call``). One reading of all three
+together (``train_flash_roofline``, every custom call of the step summed)
+went with PR 31: the pair says more."""
 
 from benchmark import flops
 

@@ -156,11 +156,12 @@ class Spans:
         self.annotate = False
 
     def phases(self) -> dict:
-        """Seconds under each ``setup.*`` / ``check.*`` span: where a run's
-        time outside the window goes (stderr and the line's ``phases``)."""
+        """Seconds under each ``setup.*`` / ``trace.*`` / ``check.*`` span:
+        where a run's time outside the window goes (stderr and the line's
+        ``phases``)."""
         out = {}
         for name, t0, t1 in self.rows:
-            if name.startswith(("setup.", "check.")):
+            if name.startswith(("setup.", "trace.", "check.")):
                 out[name] = out.get(name, 0.0) + (t1 - t0)
         return out
 
@@ -189,8 +190,10 @@ class Tracer:
     @contextlib.contextmanager
     def window(self, devices, settle=None):
         """``settle`` runs uncounted steps between starting the profiler and
-        opening the window: the first programs run under it stall while the
-        device's tracing starts up (13 s for gpt2-xl's, PR 25)."""
+        opening the window: the first programs run under it may stall while
+        the device's tracing starts up (13 s for gpt2-xl's training step, PR
+        25). How many is the kind's to say, and few: the capture holds them
+        too, and the device's trace buffer is bounded (``trace.py``)."""
         self.opened_at = time.perf_counter()
         if not self.on:
             yield
@@ -209,9 +212,11 @@ class Tracer:
                 yield
         finally:
             self.spans.annotate = False
-            jax.profiler.stop_trace()
-        self.reduced = trace.reduce(trace.find(TRACE_DIR), len(devices),
-                                    Spans.PREFIX)
+            with self.spans("trace.stop"):
+                jax.profiler.stop_trace()
+        with self.spans("trace.reduce"):
+            self.reduced = trace.reduce(trace.find(TRACE_DIR), len(devices),
+                                        Spans.PREFIX)
         shutil.rmtree(TRACE_DIR, ignore_errors=True)
 
 

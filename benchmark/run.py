@@ -44,7 +44,8 @@ def measure(cell, seed, seconds, trace, clock0=None, limits=None) -> tuple:
     breakdown = None
     if trace:
         reduced = tracer.reduced
-        device.update(busy_s=reduced.busy_s, window_s=reduced.window_s)
+        device.update(busy_s=reduced.busy_s, window_s=reduced.window_s,
+                      trace_cut_s=reduced.cut_s)
         breakdown = reduced.breakdown()
         ctx = {"cell": cell, "records": out["records"], "trace": reduced,
                "spans": spans.rows, "device_kind": device["kind"]}

@@ -149,29 +149,30 @@ def test_a_span_reader_reads_nothing_from_an_empty_tracer(program, name):
 
 def _train_ctx(ops):
     cell = benchmark_tiny.cell("tiny-train")
-    trace = types.SimpleNamespace(
-        ops=ops, custom_call_s=sum(
-            s for n, s in ops.items() if n.startswith(("flash_", "attn"))))
+    trace = types.SimpleNamespace(ops=ops)
     return ctx(cell=cell, trace=trace, device_kind="TPU v5 lite",
                records={"global_batch": 8, "seq_len": 64,
                         "steps": [(0.0, 1.0)] * 5})
 
 
-def test_flash_forward_and_backward_bracket_the_whole():
+def test_flash_forward_and_backward_share_the_calls_least_time():
+    from benchmark import flops
     made = _train_ctx({"flash_fwd.1": 2e-4, "flash_fwd.7": 2e-4,
                        "flash_dq.3": 5e-4, "flash_dkv.2": 7e-4,
                        "fusion.9": 1.0})
-    fwd, bwd, whole = (harness.load_reader(n)(made) for n in
-                       FLASH_READERS + ["train_flash_roofline"])
-    assert min(fwd, bwd) < whole < max(fwd, bwd)
-    # one bound (memory, at the test size) sets all three, so the parts'
-    # least times add up to the whole's: 4 of 12 passes in 4e-4 s, 8 in 12e-4
-    assert whole * 16e-4 == pytest.approx(fwd * 4e-4 + bwd * 12e-4)
+    fwd, bwd = (harness.load_reader(n)(made) for n in FLASH_READERS)
+    # one bound (memory, at the test size) sets both, so the parts' least
+    # times add up to the whole call's: 4 of 12 passes in 4e-4 s, 8 in 12e-4
     assert fwd / bwd == pytest.approx((4 / 4e-4) / (8 / 12e-4))
+    cfg = made["cell"].config
+    call = flops.flash_causal_call(8, cfg["n_head"], 64,
+                                   cfg["n_embd"] // cfg["n_head"])
+    least = flops.roofline_seconds(call, flops.peaks("TPU v5 lite"))
+    assert least["seconds"] * cfg["n_layer"] * 5 == pytest.approx(
+        (fwd * 4e-4 + bwd * 12e-4) / 100.0)
 
 
 @pytest.mark.parametrize("name", FLASH_READERS)
 def test_a_flash_reader_reads_nothing_without_the_kernels_names(name):
     made = _train_ctx({"attn.143": 3e-4, "fusion.9": 1.0})
     assert harness.load_reader(name)(made) is None
-    assert harness.load_reader("train_flash_roofline")(made) is not None
