@@ -449,8 +449,8 @@ def _kernel_cases():
 
     def paged_decode():
         """The server's decode walk at gpt2-xl's row (25 heads in 1,664
-        lanes), ragged lengths: the kernel against the jnp loop, which on
-        the chip rounds nothing the kernel keeps."""
+        lanes), ragged lengths: the kernel against the jnp walk at one
+        query a slot, which on the chip rounds nothing the kernel keeps."""
         from deepspeed_tpu.serving import paged_attention as pa
         H, D, W, BS, N = 25, 64, 1664, 16, 513
         lens = np.array([0, 1, 16, 17, 150, 333, 640, 0], np.int32)
@@ -467,8 +467,9 @@ def _kernel_cases():
         args = (q, k_cur, v_cur, N, k_pool, v_pool, jnp.asarray(bt),
                 jnp.asarray(lens))
         return [("out", pa._decode_kernel_call(*args, D ** -0.5),
-                 jax.jit(lambda *a: pa._decode_loop(
-                     *a, None, None, D ** -0.5))(*args))]
+                 jax.jit(lambda q, k, v, *a: pa.paged_chunk_attention(
+                     q[:, :, None], k[:, :, None], v[:, :, None],
+                     *a)[:, :, 0])(*args))]
 
     def layer_norm():
         from deepspeed_tpu.ops.transformer.fused import fused_layer_norm

@@ -941,8 +941,6 @@ class DeepSpeedServingConfig(DeepSpeedConfigObject):
                                        C.SERVING_PREFILL_CHUNK_DEFAULT))
         self.max_model_len = int(s.get(C.SERVING_MAX_MODEL_LEN,
                                        C.SERVING_MAX_MODEL_LEN_DEFAULT))
-        self.attention_impl = s.get(C.SERVING_ATTENTION_IMPL,
-                                    C.SERVING_ATTENTION_IMPL_DEFAULT)
         self.decode_steps = int(s.get(C.SERVING_DECODE_STEPS,
                                       C.SERVING_DECODE_STEPS_DEFAULT))
         self.observability = DeepSpeedServingObservabilityConfig(s)
@@ -969,10 +967,11 @@ class DeepSpeedServingConfig(DeepSpeedConfigObject):
             raise DeepSpeedConfigError(
                 f"serving.num_blocks must be 0 (auto) or >= 2 (1 usable "
                 f"+ the reserved null block), got {self.num_blocks}")
-        if self.attention_impl not in ("paged", "gather"):
+        if "attention_impl" in s:
             raise DeepSpeedConfigError(
-                f"serving.attention_impl must be 'paged' or 'gather', "
-                f"got {self.attention_impl!r}")
+                "serving.attention_impl was removed: the server has one "
+                "attention path (a Pallas kernel for decode on a TPU over "
+                "bfloat16 pools, one jnp walk elsewhere); delete the key")
         if self.decode_steps < 1:
             raise DeepSpeedConfigError(
                 f"serving.decode_steps must be >= 1, got "

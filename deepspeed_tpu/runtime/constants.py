@@ -687,12 +687,6 @@ SERVING_PREFILL_CHUNK = "prefill_chunk"
 SERVING_PREFILL_CHUNK_DEFAULT = 32
 SERVING_MAX_MODEL_LEN = "max_model_len"
 SERVING_MAX_MODEL_LEN_DEFAULT = 0
-# "paged" streams attention over LIVE KV blocks (dynamic trip count, the
-# PagedAttention shape — per-step traffic scales with tokens that exist);
-# "gather" materialises the block table into the contiguous view the
-# Pallas decode kernel consumes (fixed window, tuned TPU GEMMs)
-SERVING_ATTENTION_IMPL = "attention_impl"
-SERVING_ATTENTION_IMPL_DEFAULT = "paged"
 # tokens decoded per dispatch (vLLM num_scheduler_steps-style multi-step
 # scheduling): >1 amortises host dispatch + the device sync over K
 # tokens at the cost of K-token admission/finish granularity (tokens a
@@ -737,7 +731,7 @@ SERVING_SPEC_ACCEPTANCE_FLOOR_DEFAULT = 0.35
 # serving.prefix_cache: block-level shared-prefix KV reuse
 # (serving/kv_cache.py PrefixCache). FULL prompt blocks are
 # content-addressed by a chain hash of (parent digest, token ids,
-# position base) salted with attention_impl|kv_dtype into a bounded LRU
+# position base) salted with the kv dtype into a bounded LRU
 # index; admission maps hits read-only into the slot's block table
 # (prefill starts at the first uncached token), the first divergent
 # write copy-on-write-forks the block, and refcount-1 (cache-only)

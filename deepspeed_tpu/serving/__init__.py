@@ -5,7 +5,7 @@ from deepspeed_tpu.serving.kv_cache import (BlockAllocator,  # noqa: F401
                                             BlockAllocatorError,
                                             PagedKVCache)
 from deepspeed_tpu.serving.paged_attention import (  # noqa: F401
-    paged_decode_attention, paged_prefill_attention)
+    paged_chunk_attention, paged_decode_attention)
 from deepspeed_tpu.serving.prefill import ChunkedPrefill  # noqa: F401
 from deepspeed_tpu.serving.router import (RouteDecision,  # noqa: F401
                                           ServingRouter)

@@ -25,25 +25,19 @@ Split of responsibilities:
   request's cache.
 * ``PrefixCache`` — content-addressed index over FULL blocks: each full
   block is keyed by a chain digest of ``(parent_digest, token_ids,
-  position_base)`` salted with the attention impl + KV dtype, in a
-  bounded LRU. Admission walks a prompt against it and maps every hit
+  position_base)`` salted with the KV dtype, in a bounded LRU.
+  Admission walks a prompt against it and maps every hit
   read-only (prefill then starts at the first uncached token); the
   index holds one reference per resident block, so a block whose last
   *request* finished stays reusable until LRU eviction or
   ``reclaim()`` — which the scheduler calls before any preemption
   fires.
 * ``PagedKVCache`` — owns the device pools (K and V as token rows, and
-  for the int8 KV layout the per-row fp32 scales) plus the
-  scatter/gather helpers the runner traces into the compiled step:
-  ``write_layers`` (one scatter per pool for a step's tokens, in one
-  layer or in all of them) and ``gather`` (block table -> contiguous
-  ``[B, H, T, D]`` view that composes with ``decode_attention``'s
-  per-sequence lengths).
-
-The gather materialises each slot's logical cache contiguously per step.
-Attention has to stream those bytes anyway — decode is KV-bandwidth
-bound — so paging costs one extra copy of the *live* window while buying
-the capacity sharing that makes continuous batching admissible.
+  for the int8 KV layout the per-row fp32 scales) plus the one write
+  the runner traces into a compiled step: ``write_layers`` (one scatter
+  per pool for a step's tokens in the layers that ran). The reads are
+  serving/paged_attention.py's: they walk the blocks that hold tokens
+  where they lie and never materialise a slot's window.
 """
 
 import hashlib
@@ -212,8 +206,8 @@ class PrefixCache:
 
     Every full block a request writes is registered under a *chain
     digest* — ``H(parent_digest, token_ids, position_base)`` with the
-    cache salt (attention impl, KV dtype, block size) folded into the
-    root — so a hit certifies the ENTIRE prefix up to and including the
+    cache salt (KV dtype, block size) folded into the root — so a hit
+    certifies the ENTIRE prefix up to and including the
     block, not just its own tokens (position_base makes the digest
     absolute-position-aware; learned position embeddings mean the same
     tokens at a different offset are different KV). Admission walks a
@@ -382,7 +376,7 @@ class PrefixCache:
 
 
 class PagedKVCache:
-    """Device block pools + the traced scatter/gather helpers.
+    """Device block pools + the traced write.
 
     Each pool is ONE array of token rows, blocks major, the layer folded
     into the block index (layer ``l``'s block ``b`` is row ``l*N + b``):
@@ -434,15 +428,15 @@ class PagedKVCache:
         # serving.prefix_cache config block.
         self.prefix_cache = None
 
-    def attach_prefix_cache(self, capacity_blocks=0, attention_impl=""):
+    def attach_prefix_cache(self, capacity_blocks=0):
         """Arm shared-prefix reuse: the salt folds in everything that
         makes two bit-identical token prefixes produce different block
-        BYTES (attention impl, KV dtype, block size), so a cache can
-        never serve a block written under a different layout."""
+        BYTES (KV dtype, block size), so a cache can never serve a block
+        written under a different layout."""
         self.prefix_cache = PrefixCache(
             self.allocator, self.block_size,
             capacity_blocks=capacity_blocks,
-            salt=f"{attention_impl}|{jnp.dtype(self.dtype).name}")
+            salt=jnp.dtype(self.dtype).name)
         return self.prefix_cache
 
     # -------------------------------------------------- pool construction
@@ -484,29 +478,26 @@ class PagedKVCache:
         return (layers * self.num_blocks).reshape(
             (-1,) + (1,) * jnp.ndim(block_ids)) + block_ids
 
-    # ------------------------------------------------------ traced writes
+    # ------------------------------------------------------- traced write
     @jax.named_scope("kv_write")
-    def write_layers(self, pools, k_new, v_new, block_ids, offsets,
-                     first_layer=0):
-        """Write one step's K/V for ``n`` consecutive layers, starting at
-        ``first_layer``, in ONE scatter per pool.
+    def write_layers(self, pools, k_new, v_new, block_ids, offsets):
+        """Write one step's K/V for the first ``n`` layers in ONE scatter
+        per pool.
 
         k_new/v_new: ``[n, B, H, D]`` (decode: a token per slot; prefill
         or verify: the ``B`` positions of a chunk); block_ids/offsets:
         ``[B]`` int32 (the scheduler routes inactive slots / pad
-        positions to the null block 0). The paged impl defers every
-        layer's write to one call at the end of the step (``n`` = the
-        layers it ran: all of them, or the self-draft's prefix, whose
-        K/V are bit-identical to the target's for those layers); the
-        gather impl, whose kernel needs the current token in the pool
-        before it reads, calls it per layer with ``n`` = 1.
+        positions to the null block 0). The runner defers every layer's
+        write to one call at the end of the step (``n`` = the layers it
+        ran: all of them, or the self-draft's prefix, whose K/V are
+        bit-identical to the target's for those layers).
 
         The scatter indexes the pool's two leading dimensions only (the
         folded row ``layer*N + block`` and the offset) and the update is
         ``[n*B, W]``, untransposed: that is what lets the TPU compiler
         update the donated pool in place (class docstring)."""
         n, B = k_new.shape[:2]
-        rows = self.layer_rows(block_ids, first_layer, n).reshape(-1)
+        rows = self.layer_rows(block_ids, n_layers=n).reshape(-1)
         offs = jnp.broadcast_to(offsets, (n, B)).reshape(-1)
 
         def token_rows(x, width):   # [n, B, ...] -> [n*B, width], 0-padded
@@ -522,43 +513,6 @@ class PagedKVCache:
             out[name] = pools[name].at[rows, offs].set(token_rows(
                 new.astype(pools[name].dtype), self.row_width))
         return out
-
-    # ------------------------------------------------------ traced gather
-    def gather(self, pools, layer, block_tables):
-        """Block table -> contiguous per-slot cache views.
-
-        block_tables: ``[B, MB]`` int32 (or ``[MB]`` for one slot).
-        Returns ``(k, v, k_scale, v_scale)`` with k/v shaped
-        ``[B, H, MB*block_size, D]`` (scales ``[B, H, MB*block_size]`` or
-        ``None``) — exactly what ``decode_attention`` /
-        ``decode_attention_quantized`` read, with per-sequence lengths
-        masking the tail.
-        """
-        squeeze = block_tables.ndim == 1
-        bt = block_tables[None] if squeeze else block_tables
-        B, MB = bt.shape
-        H, D = self.n_head, self.head_dim
-        T = MB * self.block_size
-        rows = self.layer_rows(bt, layer)[0]            # [B, MB]
-
-        def _g4(pool):   # [L*N,BS,W] -> [B,H,T,D]
-            g = pool[rows][..., :H * D]                 # [B, MB, BS, H*D]
-            return g.reshape(B, T, H, D).transpose(0, 2, 1, 3)
-
-        def _g3(pool):   # [L*N,BS,H] -> [B,H,T]
-            return pool[rows][..., :H].reshape(B, T, H).transpose(0, 2, 1)
-
-        k = _g4(pools["k"])
-        v = _g4(pools["v"])
-        ks = vs = None
-        if self.int8_kv:
-            ks = _g3(pools["k_scale"])
-            vs = _g3(pools["v_scale"])
-        if squeeze:
-            k, v = k[0], v[0]
-            if ks is not None:
-                ks, vs = ks[0], vs[0]
-        return k, v, ks, vs
 
     # ------------------------------------------------------- host helpers
     def blocks_for(self, n_tokens: int) -> int:

@@ -1,62 +1,53 @@
 """Streaming paged attention — work scales with LIVE tokens, not capacity.
 
-The runner's ``gather`` impl materialises each slot's block table into a
-contiguous ``[B, H, T_max, D]`` view and hands it to the
-ops/transformer/decode.py kernel. That composes with the Pallas TPU
-kernel, but it reads (and copies) the *allocated* window every step: a
-request 40 tokens into a 2048-token capacity still pays 2048 columns of
-gather+attention traffic — and decode is KV-bandwidth bound, so that tax
-is the whole step.
+The PagedAttention shape (SOSP '23): a flash-style online softmax over KV
+*blocks*, touching only blocks that hold tokens, never a materialised
+``[B, H, T_max, D]`` window (decode is KV-bandwidth bound, so reading the
+allocated window instead of the live one would be the whole step), one
+compiled program regardless of how lengths evolve. There are two
+implementations and one rule, :func:`decode_kernel_runs`, which chooses
+from the platform, the pool's dtype and the mesh's size (no option):
 
-This module is the PagedAttention-shaped alternative (SOSP '23): a
-flash-style online softmax over KV *blocks*, touching only blocks that
-hold tokens, never a full-window materialisation, one compiled program
-regardless of how lengths evolve. What runs where:
-
-* **Decode on a TPU, bfloat16 pools, no multi-device mesh**: one Pallas
-  kernel call a layer (``paged_decode`` on a profile's ``XLA Ops``
-  line). The pools stay in HBM; each slot walks its OWN
+* **Decode (one query a slot) on a TPU, bfloat16 pools, no multi-device
+  mesh**: one Pallas kernel call a layer (``paged_decode`` on a profile's
+  ``XLA Ops`` line). The pools stay in HBM; each slot walks its OWN
   ``ceil(past_len / block_size)`` blocks, each block one async copy of
   ``[block_size, W]`` rows into VMEM, ``_GROUP`` blocks a group with the
   next group's copies in flight while this one is reduced; rows stay
   ``W`` lanes wide from HBM to the accumulator (the heads are the rows
   of a block-diagonal query, :func:`_decode_kernel`). It replaced the
-  loop below there, which took 19.6 of a 20.5 ms decode program at
+  jnp walk there, which took 19.6 of a 20.5 ms decode program at
   gpt2-medium with 40 slots: a sixth of the HBM roofline, three fifths
   of the gathered blocks holding no token of their slot (PERF.md,
   PR 30).
-* **Decode everywhere else** (off the TPU, int8 pools, under a
-  multi-device mesh, where GSPMD refuses a bare ``pallas_call``), **and
-  prefill and verify everywhere**: a ``fori_loop`` with a DYNAMIC trip
-  count — ``ceil(max_past_len / block_size)`` is a traced scalar, so
-  XLA lowers it to a while loop; one block gather per iteration
-  (``[B, block_size, W]`` token rows, consumed immediately). The decode
-  loop is also the kernel's parity oracle
-  (tests/unit/test_paged_decode_kernel.py). :func:`decode_kernel_runs`
-  is the one place that chooses, from the platform, the pool's dtype and
-  the mesh's size: no option.
+* **Everything else** (prefill and verify chunks everywhere; decode off
+  the TPU, over int8 pools, and under a multi-device mesh, where GSPMD
+  refuses a bare ``pallas_call``): :func:`paged_chunk_attention`, ONE
+  jnp loop with a DYNAMIC trip count —
+  ``ceil(max_past_len / block_size)`` is a traced scalar, so XLA lowers
+  it to a while loop; one block gather per iteration
+  (``[B, block_size, W]`` token rows, consumed immediately). Decode is
+  that walk at ``C = 1`` (the kernel's parity oracle,
+  tests/unit/test_paged_decode_kernel.py), a prefill chunk at ``B = 1``,
+  a speculative verify at ``C = K+1``.
 
-The functions attend over the PAST pool only and fold the current
-token/chunk from registers (an extra online-softmax term / an intra-chunk
-causal piece merged in). That lets the runner defer every layer's KV
-write into ONE scatter per pool per step (kv_cache.write_layers). The
-pools are the row-shaped arrays of kv_cache.PagedKVCache, read as
-``pool[first_block + ids]``: a gather (or the kernel's copies) on the
-leading dimension alone, which the TPU compiler serves from the donated
-pool where it lies. (The former ``pool[layer, ids]`` on a
-``[L, N, H, BS, D]`` pool had every program convert the whole pool to
-row-major first, and the stacked write convert it twice more — PERF.md,
-PR 27.) The int8 KV layout dequantises per block from the per-row scale
-pools; the current token stays in registers at full precision (it is
-quantised only when written, exactly like the flax decode path, which
-attends to the quantised value from the NEXT step on).
+Both attend over the PAST pool only and fold the current token/chunk
+from registers (an intra-chunk causal piece merged in). That lets the
+runner defer every layer's KV write into ONE scatter per pool per step
+(kv_cache.write_layers). The pools are the row-shaped arrays of
+kv_cache.PagedKVCache, read as ``pool[first_block + ids]``: a gather (or
+the kernel's copies) on the leading dimension alone, which the TPU
+compiler serves from the donated pool where it lies. (The former
+``pool[layer, ids]`` on a ``[L, N, H, BS, D]`` pool had every program
+convert the whole pool to row-major first, and the stacked write convert
+it twice more — PERF.md, PR 27.) The int8 KV layout dequantises per block
+from the per-row scale pools; the current token stays in registers at
+full precision (it is quantised only when written, exactly like the flax
+decode path, which attends to the quantised value from the NEXT step on).
 
-Each function traces under ``jax.named_scope("paged_attention")`` (and the
-step's KV write under ``"kv_write"``): trace-time only, the name a profile's
+Both trace under ``jax.named_scope("paged_attention")`` (and the step's
+KV write under ``"kv_write"``): trace-time only, the name a profile's
 operations carry in their ``tf_op`` stat.
-
-Both impls are selectable per engine (``serving.attention_impl``) and
-pinned equal by tests/unit/test_serving.py.
 """
 
 import functools
@@ -86,18 +77,10 @@ def _read_blocks(pool, scale_pool, rows, H, D):
     return kb
 
 
-def _merge(m1, l1, a1, m2, l2, a2):
-    """Combine two online-softmax partials over disjoint key sets."""
-    m = jnp.maximum(m1, m2)
-    w1 = jnp.exp(m1 - m)
-    w2 = jnp.exp(m2 - m)
-    return m, l1 * w1 + l2 * w2, a1 * w1[..., None] + a2 * w2[..., None]
-
-
 def decode_kernel_runs(pool_dtype):
     """Whether :func:`paged_decode_attention` is the Pallas kernel here:
     on a TPU, over bfloat16 pools, under no multi-device mesh (GSPMD
-    refuses a bare ``pallas_call``). Everywhere else it is the jnp loop.
+    refuses a bare ``pallas_call``). Everywhere else it is the jnp walk.
     The server's block counter asks too, so that it states the walk that
     runs."""
     from deepspeed_tpu.utils import groups
@@ -106,72 +89,27 @@ def decode_kernel_runs(pool_dtype):
                      and groups.get_mesh().size > 1))
 
 
-@jax.named_scope("paged_attention")
 def paged_decode_attention(q, k_cur, v_cur, first_block, k_pool, v_pool,
                            block_tables, past_lens, *, k_scale_pool=None,
                            v_scale_pool=None, sm_scale=None):
-    """One decode token per slot over the paged pools.
+    """One decode token per slot over the paged pools: the kernel where
+    :func:`decode_kernel_runs`, else :func:`paged_chunk_attention` at
+    ``C = 1``.
 
     q/k_cur/v_cur: ``[B, H, D]`` (the current token's K/V stay in
-    registers — the pool write is deferred); pools: the
-    ``[L*N, BS, W]`` row arrays; first_block: the pool row of this
-    layer's block 0 (``layer * num_blocks``); block_tables: ``[B, MB]``
-    int32; past_lens: ``[B]`` int32 tokens ALREADY in the pool. Returns
-    ``[B, H, D]`` fp32.
+    registers — the pool write is deferred); the other arguments as
+    :func:`paged_chunk_attention`'s. Returns ``[B, H, D]`` fp32.
     """
-    if sm_scale is None:
-        sm_scale = q.shape[-1] ** -0.5
     if decode_kernel_runs(k_pool.dtype):
-        return _decode_kernel_call(q, k_cur, v_cur, first_block, k_pool,
-                                   v_pool, block_tables, past_lens,
-                                   sm_scale)
-    return _decode_loop(q, k_cur, v_cur, first_block, k_pool, v_pool,
-                        block_tables, past_lens, k_scale_pool,
-                        v_scale_pool, sm_scale)
-
-
-def _decode_loop(q, k_cur, v_cur, first_block, k_pool, v_pool,
-                 block_tables, past_lens, k_scale_pool, v_scale_pool,
-                 sm_scale):
-    """The batch-wide loop: every trip gathers one block for each of the
-    ``B`` slots, ``ceil(max(past_lens) / BS)`` trips. What runs off the
-    TPU, over int8 pools and under a multi-device mesh, and the kernel's
-    parity oracle."""
-    B, H, D = q.shape
-    BS = k_pool.shape[1]
-    qf = q.astype(jnp.float32)
-    n_blocks = ((jnp.max(past_lens) + BS - 1) // BS).astype(jnp.int32)
-
-    def body(i, carry):
-        m, l, acc = carry
-        rows = first_block + block_tables[:, i]        # [B]
-        kb = _read_blocks(k_pool, k_scale_pool, rows, H, D)  # [B,BS,H,D]
-        vb = _read_blocks(v_pool, v_scale_pool, rows, H, D)
-        s = jnp.einsum("bhd,bshd->bhs", qf, kb) * sm_scale
-        col = i * BS + jnp.arange(BS, dtype=jnp.int32)
-        s = jnp.where(col[None, None, :] < past_lens[:, None, None],
-                      s, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[..., None])
-        alpha = jnp.exp(m - m_new)
-        l_new = l * alpha + jnp.sum(p, axis=-1)
-        acc = acc * alpha[..., None] + jnp.einsum("bhs,bshd->bhd", p, vb)
-        return m_new, l_new, acc
-
-    m0 = jnp.full((B, H), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((B, H), jnp.float32)
-    a0 = jnp.zeros((B, H, D), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(0, n_blocks, body, (m0, l0, a0))
-    # fold the current token (always self-visible, so l can never be 0)
-    s_cur = jnp.einsum("bhd,bhd->bh", qf,
-                       k_cur.astype(jnp.float32)) * sm_scale
-    m_f = jnp.maximum(m, s_cur)
-    alpha = jnp.exp(m - m_f)
-    p_cur = jnp.exp(s_cur - m_f)
-    l = l * alpha + p_cur
-    acc = acc * alpha[..., None] \
-        + p_cur[..., None] * v_cur.astype(jnp.float32)
-    return acc / l[..., None]
+        with jax.named_scope("paged_attention"):
+            return _decode_kernel_call(
+                q, k_cur, v_cur, first_block, k_pool, v_pool, block_tables,
+                past_lens,
+                q.shape[-1] ** -0.5 if sm_scale is None else sm_scale)
+    return paged_chunk_attention(
+        q[:, :, None], k_cur[:, :, None], v_cur[:, :, None], first_block,
+        k_pool, v_pool, block_tables, past_lens, k_scale_pool=k_scale_pool,
+        v_scale_pool=v_scale_pool, sm_scale=sm_scale)[:, :, 0]
 
 
 def _dot_f32(a, b, dims):
@@ -344,114 +282,72 @@ def _decode_kernel_call(q, k_cur, v_cur, first_block, k_pool, v_pool,
 
 
 @jax.named_scope("paged_attention")
-def paged_verify_attention(q, k_chunk, v_chunk, first_block, k_pool, v_pool,
-                           block_tables, past_lens, *, k_scale_pool=None,
-                           v_scale_pool=None, sm_scale=None):
-    """Speculative verify: ``C = K+1`` queries PER SLOT over each slot's
-    PAST pages plus the candidate chunk itself (registers, causal).
+def paged_chunk_attention(q, k_chunk, v_chunk, first_block, k_pool, v_pool,
+                          block_tables, past_lens, *, k_scale_pool=None,
+                          v_scale_pool=None, sm_scale=None):
+    """The one jnp walk: ``C`` queries PER SLOT over each slot's PAST
+    pages plus the chunk itself (registers, causal).
 
-    The batched cross of the two functions above: decode's ``[B, MB]``
-    block tables and per-slot ``past_lens``, prefill's multi-position
-    chunk with the intra-chunk causal piece merged from registers. One
-    program verifies K drafted tokens for every slot in a single target
-    forward — the pool writes stay deferred, so a rejected suffix never
-    has to be undone on-device.
+    Every trip gathers one block for each of the ``B`` slots,
+    ``ceil(max(past_lens) / BS)`` trips, and a column at or past a slot's
+    ``past_len`` is masked; the chunk's own K/V never come from the pool
+    (its write is deferred, so a rejected speculative suffix never has to
+    be undone on-device). ``C = 1`` is a decode step, one slot a prefill
+    chunk (whose pad-tail queries produce rows that are discarded),
+    ``C = K+1`` a speculative verify.
 
     q/k_chunk/v_chunk: ``[B, H, C, D]`` (query c sits at absolute
-    position ``past_lens[b] + c``); block_tables: ``[B, MB]`` int32;
-    past_lens: ``[B]`` int32 tokens ALREADY in the pool. Returns
-    ``[B, H, C, D]`` fp32.
+    position ``past_lens[b] + c``); pools: the ``[L*N, BS, W]`` row
+    arrays; first_block: the pool row of this layer's block 0
+    (``layer * num_blocks``); block_tables: ``[B, MB]`` int32; past_lens:
+    ``[B]`` int32 tokens ALREADY in the pool. Returns ``[B, H, C, D]``
+    fp32. One slot may come without its batch dimension (``[H, C, D]``,
+    ``[MB]``, a scalar): the compiler does not drop a unit batch
+    dimension from these products by itself (runner.py says what it
+    cost).
     """
-    B, H, C, D = q.shape
+    H, C, D = q.shape[-3:]
     BS = k_pool.shape[1]
     if sm_scale is None:
         sm_scale = D ** -0.5
     qf = q.astype(jnp.float32)
+    lens = past_lens[..., None, None, None]
     n_blocks = ((jnp.max(past_lens) + BS - 1) // BS).astype(jnp.int32)
 
     def body(i, carry):
         m, l, acc = carry
-        rows = first_block + block_tables[:, i]        # [B]
+        rows = first_block + block_tables[..., i]
         kb = _read_blocks(k_pool, k_scale_pool, rows, H, D)  # [B,BS,H,D]
         vb = _read_blocks(v_pool, v_scale_pool, rows, H, D)
-        s = jnp.einsum("bhcd,bshd->bhcs", qf, kb) * sm_scale
+        s = jnp.einsum("...hcd,...shd->...hcs", qf, kb) * sm_scale
         col = i * BS + jnp.arange(BS, dtype=jnp.int32)
-        s = jnp.where(col[None, None, None, :]
-                      < past_lens[:, None, None, None], s, NEG_INF)
+        s = jnp.where(col < lens, s, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1))
         p = jnp.exp(s - m_new[..., None])
         alpha = jnp.exp(m - m_new)
         l_new = l * alpha + jnp.sum(p, axis=-1)
         acc = acc * alpha[..., None] \
-            + jnp.einsum("bhcs,bshd->bhcd", p, vb)
+            + jnp.einsum("...hcs,...shd->...hcd", p, vb)
         return m_new, l_new, acc
 
-    m0 = jnp.full((B, H, C), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((B, H, C), jnp.float32)
-    a0 = jnp.zeros((B, H, C, D), jnp.float32)
-    m_p, l_p, a_p = jax.lax.fori_loop(0, n_blocks, body, (m0, l0, a0))
-    # intra-chunk causal piece from registers: candidate e visible to
-    # query c iff e <= c; query 0 always sees itself, so l can never be 0
-    s_in = jnp.einsum("bhcd,bhed->bhce", qf,
-                      k_chunk.astype(jnp.float32)) * sm_scale
-    causal = (jnp.arange(C)[:, None] >= jnp.arange(C)[None, :])
-    s_in = jnp.where(causal[None, None], s_in, NEG_INF)
-    m_in = jnp.max(s_in, axis=-1)
-    p_in = jnp.exp(s_in - m_in[..., None])
-    l_in = jnp.sum(p_in, axis=-1)
-    a_in = jnp.einsum("bhce,bhed->bhcd", p_in,
-                      v_chunk.astype(jnp.float32))
-    _, l, acc = _merge(m_p, l_p, a_p, m_in, l_in, a_in)
-    return acc / l[..., None]
-
-
-@jax.named_scope("paged_attention")
-def paged_prefill_attention(q, k_chunk, v_chunk, first_block, k_pool, v_pool,
-                            bt_row, pos, start, *, k_scale_pool=None,
-                            v_scale_pool=None, sm_scale=None):
-    """Chunk attention for ONE slot: ``C`` queries at positions ``pos``
-    (= start + 0..C-1) over the slot's PAST pages plus the chunk itself
-    (registers, causal) — the chunk's pool write is deferred.
-
-    q/k_chunk/v_chunk: ``[H, C, D]``; bt_row: ``[MB]`` int32; start:
-    traced scalar, tokens already in the pool. Returns ``[H, C, D]``
-    fp32.
-    """
-    H, C, D = q.shape
-    BS = k_pool.shape[1]
-    if sm_scale is None:
-        sm_scale = D ** -0.5
-    qf = q.astype(jnp.float32)
-    n_blocks = ((start + BS - 1) // BS).astype(jnp.int32)
-
-    def body(i, carry):
-        m, l, acc = carry
-        row = first_block + bt_row[i]
-        kb = _read_blocks(k_pool, k_scale_pool, row, H, D)   # [BS, H, D]
-        vb = _read_blocks(v_pool, v_scale_pool, row, H, D)
-        s = jnp.einsum("hcd,shd->hcs", qf, kb) * sm_scale
-        col = i * BS + jnp.arange(BS, dtype=jnp.int32)
-        s = jnp.where(col[None, None, :] < start, s, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[..., None])
-        alpha = jnp.exp(m - m_new)
-        l_new = l * alpha + jnp.sum(p, axis=-1)
-        acc = acc * alpha[..., None] + jnp.einsum("hcs,shd->hcd", p, vb)
-        return m_new, l_new, acc
-
-    m0 = jnp.full((H, C), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((H, C), jnp.float32)
-    a0 = jnp.zeros((H, C, D), jnp.float32)
+    m0 = jnp.full(q.shape[:-1], NEG_INF, jnp.float32)
+    l0 = jnp.zeros(q.shape[:-1], jnp.float32)
+    a0 = jnp.zeros(q.shape, jnp.float32)
     m_p, l_p, a_p = jax.lax.fori_loop(0, n_blocks, body, (m0, l0, a0))
     # intra-chunk causal piece from registers: key e visible to query c
-    # iff e <= c (pad-tail queries produce garbage that is discarded)
-    s_in = jnp.einsum("hcd,hed->hce", qf,
+    # iff e <= c; every query sees itself, so l can never be 0
+    s_in = jnp.einsum("...hcd,...hed->...hce", qf,
                       k_chunk.astype(jnp.float32)) * sm_scale
-    causal = jnp.arange(C)[None, :, None] >= jnp.arange(C)[None, None, :]
+    causal = jnp.arange(C)[:, None] >= jnp.arange(C)[None, :]
     s_in = jnp.where(causal, s_in, NEG_INF)
     m_in = jnp.max(s_in, axis=-1)
     p_in = jnp.exp(s_in - m_in[..., None])
     l_in = jnp.sum(p_in, axis=-1)
-    a_in = jnp.einsum("hce,hed->hcd", p_in, v_chunk.astype(jnp.float32))
-    _, l, acc = _merge(m_p, l_p, a_p, m_in, l_in, a_in)
+    a_in = jnp.einsum("...hce,...hed->...hcd", p_in,
+                      v_chunk.astype(jnp.float32))
+    # the two online-softmax partials cover disjoint key sets
+    m = jnp.maximum(m_p, m_in)
+    w_p, w_in = jnp.exp(m_p - m), jnp.exp(m_in - m)
+    l = l_p * w_p + l_in * w_in
+    acc = a_p * w_p[..., None] + a_in * w_in[..., None]
     return acc / l[..., None]

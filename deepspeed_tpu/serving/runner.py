@@ -1,23 +1,34 @@
-"""Paged model runner — the two compiled programs behind the server.
+"""Paged model runner — the one forward pass behind the server's programs.
 
 The flax decode path (models/gpt2.py ``decode=True``) owns a per-batch
 contiguous cache with ONE shared ``cache_index`` — every sequence in the
 batch must sit at the same position, which is exactly what continuous
 batching breaks. This runner re-expresses the same GPT-2 math directly
 over the model's *params pytree* with per-slot positions and the paged
-pool from serving/kv_cache.py:
+pool from serving/kv_cache.py, ONCE: :meth:`PagedGPT2Runner._forward`
+embeds ``[B, C]`` tokens at their own positions, walks the blocks
+(:meth:`PagedGPT2Runner._block`, the only definition of a transformer
+block in the serving code), attends over each slot's past pages plus the
+chunk itself, writes the K/V of every layer that ran in one scatter per
+pool, and returns the logits. The four compiled programs are its callers:
 
 * ``decode_step`` — the one static-shaped program the server calls every
-  iteration: embeds each slot's last token at its own position, writes
-  its K/V through the slot's block table, gathers pages into the
-  contiguous view ``decode_attention`` reads (per-sequence lengths), and
-  samples the next token per request (serving/sampling.py). Compiled
-  once for the whole serving lifetime — request churn only changes
-  tensor *values*.
+  iteration: the forward at ``C = 1``, then a sampled token per request
+  (serving/sampling.py), ``decode_steps`` times in one dispatch.
+  Compiled once for the whole serving lifetime — request churn only
+  changes tensor *values*.
 * ``prefill_chunk`` — fills one slot's prompt KV ``chunk`` tokens at a
   time (serving/prefill.py plans the chunks) so a long prompt never
-  stalls the decode batch. Also compiled once: the final short chunk is
-  padded and its tail writes are routed to the null block.
+  stalls the decode batch: the forward at ``B = 1``, no head. Also
+  compiled once: the final short chunk is padded and its tail writes are
+  routed to the null block.
+* the speculative draft and verify programs (serving/speculative.py):
+  the forward at ``C = 1`` over a layer prefix, and at ``C = K+1``.
+
+Which attention runs is read off the static shape, not an option: one
+query a slot goes to ``paged_decode_attention`` (the Pallas kernel on a
+TPU over bfloat16 pools under one device, else the jnp walk), a chunk to
+the jnp walk (serving/paged_attention.py states the rule).
 
 Weight formats: float kernels and the engine's TRUE int8 weight storage
 (module_quantize ``quant_scales`` collection) both work — the dequant
@@ -29,16 +40,16 @@ trees, learned position embeddings, no MoE / pipeline / sequence
 parallelism, mp_size 1.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
 from deepspeed_tpu.ops.quantizer.int8_linear import int8_matmul
-from deepspeed_tpu.ops.transformer.decode import (decode_attention,
-                                                  decode_attention_quantized,
-                                                  quantize_kv)
-from deepspeed_tpu.serving.paged_attention import (paged_decode_attention,
-                                                   paged_prefill_attention)
-from deepspeed_tpu.serving.sampling import NEG_INF, sample_tokens
+from deepspeed_tpu.ops.transformer.decode import quantize_kv
+from deepspeed_tpu.serving.paged_attention import (paged_chunk_attention,
+                                                   paged_decode_attention)
+from deepspeed_tpu.serving.sampling import sample_tokens
 
 _LN_EPS = 1e-5
 
@@ -75,20 +86,8 @@ def _sub(scales, *path):
 
 
 class PagedGPT2Runner:
-    def __init__(self, model, cache, use_flash=None,
-                 attention_impl="paged", decode_steps=1):
-        """``attention_impl``: ``"paged"`` (default) streams attention
-        over LIVE KV blocks — per-step traffic scales with how many
-        tokens actually exist (serving/paged_attention.py: a Pallas
-        kernel over each slot's own blocks for decode on a TPU, a
-        dynamic-trip-count loop elsewhere). ``"gather"`` materialises each
-        slot's pages into the contiguous view the
-        ops/transformer/decode.py Pallas kernel reads — fixed
-        ``T_max``-window traffic, but the decode GEMMs run in the tuned
-        TPU kernel."""
-        assert attention_impl in ("paged", "gather"), attention_impl
+    def __init__(self, model, cache, decode_steps=1):
         assert decode_steps >= 1
-        self.attention_impl = attention_impl
         self.decode_steps = int(decode_steps)
         cfg = model.config
         for attr in ("n_layer", "n_head", "n_embd", "n_positions",
@@ -108,7 +107,6 @@ class PagedGPT2Runner:
             f"attention_mode={mode!r} models must serve with 'auto'")
         self.cfg = cfg
         self.cache = cache
-        self.use_flash = use_flash
         self.n_head = cfg.n_head
         self.head_dim = cfg.n_embd // cfg.n_head
         # the pools are donated and the server re-threads the returned
@@ -148,16 +146,7 @@ class PagedGPT2Runner:
         """Fork one block's bytes: the COW path's single device op."""
         return self._copy_block(pools, jnp.int32(src), jnp.int32(dst))
 
-    # ------------------------------------------------------------ layers
-    def _qkv(self, p, s, x):
-        B_or_C = x.shape[0]
-        H, D = self.n_head, self.head_dim
-        qkv = _dense(_ln(x, p["ln_1"]), p["attn"]["qkv"],
-                     _sub(s, "attn", "qkv"))
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        return (q.reshape(B_or_C, H, D), k.reshape(B_or_C, H, D),
-                v.reshape(B_or_C, H, D))
-
+    # ------------------------------------------------------- the forward
     def _requant(self, kv):
         """What the pool will hold for these rows: int8-round-tripped
         values, so the current token's self-attention matches what every
@@ -167,98 +156,67 @@ class PagedGPT2Runner:
         kq, ks = quantize_kv(kv)
         return kq.astype(jnp.float32) * ks[..., None]
 
-    def _attn_decode(self, p, s, layer, x, pools, bt, pos, active):
-        """Paged impl: attend over PAST pool + current token from
-        registers; returns the layer's (k, v) so the caller scatters all
-        layers at once. Gather impl: eager per-layer write, then the
-        ops/transformer/decode.py kernel over the contiguous view."""
-        B, E = x.shape
+    def _attend(self, layer, pools, bt, past_lens, C, q, k, v):
+        """Rows ``[B*C, H, D]`` of one layer's q/k/v over each slot's PAST
+        pages plus the chunk from registers; returns ``[B*C, H, D]``
+        fp32. The shape decides what runs: a single query a slot is a
+        decode step (the kernel where it can run), a chunk the jnp
+        walk."""
         int8 = self.cache.int8_kv
-        q, k, v = self._qkv(p, s, x)
-        if self.attention_impl == "paged":
-            out = paged_decode_attention(
-                q, self._requant(k), self._requant(v),
-                layer * self.cache.num_blocks, pools["k"], pools["v"],
-                bt, pos,
-                k_scale_pool=pools["k_scale"] if int8 else None,
-                v_scale_pool=pools["v_scale"] if int8 else None)
-            out = out.reshape(B, E).astype(x.dtype)
-            proj = _dense(out, p["attn"]["proj"], _sub(s, "attn", "proj"))
-            return pools, proj, (k, v)
-        bs = self.cache.block_size
-        row = jnp.take_along_axis(bt, (pos // bs)[:, None], axis=1)[:, 0]
-        blk = jnp.where(active, row, 0)
-        pools = self.cache.write_layers(pools, k[None], v[None], blk,
-                                        pos % bs, first_layer=layer)
-        lens = pos + 1
-        kg, vg, ksg, vsg = self.cache.gather(pools, layer, bt)
-        q4 = q[:, :, None, :]
-        if int8:
-            out = decode_attention_quantized(
-                q4, kg, ksg, vg, vsg, lens, use_flash=self.use_flash)
-        else:
-            out = decode_attention(q4, kg, vg, lens,
-                                   use_flash=self.use_flash)
-        out = out[:, :, 0, :].reshape(B, E).astype(x.dtype)
-        proj = _dense(out, p["attn"]["proj"], _sub(s, "attn", "proj"))
-        return pools, proj, None
+        first_block = layer * self.cache.num_blocks
+        scale_pools = dict(
+            k_scale_pool=pools["k_scale"] if int8 else None,
+            v_scale_pool=pools["v_scale"] if int8 else None)
+        k, v = self._requant(k), self._requant(v)
+        if C == 1:
+            return paged_decode_attention(q, k, v, first_block, pools["k"],
+                                          pools["v"], bt, past_lens,
+                                          **scale_pools)
+        N, H, D = q.shape
+        # a lone slot (a prefill chunk) goes without its batch dimension:
+        # with it gpt2-medium's prefill program took 2.122 ms, without
+        # it 2.050 (PERF.md, PR 32)
+        lead = () if N == C else (N // C,)
 
-    def _attn_prefill(self, p, s, layer, x, pools, bt_row, pos, start,
-                      n_valid):
-        """Chunk attention for one slot. Paged impl: past pages + the
-        chunk from registers (write deferred to one stacked scatter).
-        Gather impl: eager write, dense masked attention over the
-        contiguous view."""
-        C, E = x.shape
-        D = self.head_dim
-        int8 = self.cache.int8_kv
-        q, k, v = self._qkv(p, s, x)                    # [C, H, D]
-        qh = q.transpose(1, 0, 2)                       # [H, C, D]
-        if self.attention_impl == "paged":
-            out = paged_prefill_attention(
-                qh, self._requant(k).transpose(1, 0, 2),
-                self._requant(v).transpose(1, 0, 2),
-                layer * self.cache.num_blocks, pools["k"], pools["v"],
-                bt_row, pos, start,
-                k_scale_pool=pools["k_scale"] if int8 else None,
-                v_scale_pool=pools["v_scale"] if int8 else None)
-            out = out.transpose(1, 0, 2).reshape(C, E).astype(x.dtype)
-            proj = _dense(out, p["attn"]["proj"], _sub(s, "attn", "proj"))
-            return pools, proj, (k, v)
-        bs = self.cache.block_size
-        MB = bt_row.shape[0]
-        valid = jnp.arange(C) < n_valid
-        blk = jnp.where(valid,
-                        bt_row[jnp.minimum(pos // bs, MB - 1)], 0)
-        pools = self.cache.write_layers(pools, k[None], v[None], blk,
-                                        pos % bs, first_layer=layer)
-        kg, vg, ksg, vsg = self.cache.gather(pools, layer, bt_row)
-        if int8:
-            kg = (kg.astype(jnp.float32) * ksg[..., None]).astype(x.dtype)
-            vg = (vg.astype(jnp.float32) * vsg[..., None]).astype(x.dtype)
-        scores = jnp.einsum("hcd,htd->hct", qh, kg.astype(qh.dtype),
-                            preferred_element_type=jnp.float32)
-        scores = scores * (D ** -0.5)
-        T = kg.shape[1]
-        mask = jnp.arange(T)[None, :] <= pos[:, None]   # [C, T]
-        scores = jnp.where(mask[None], scores, NEG_INF)
-        probs = jax.nn.softmax(scores, axis=-1)
-        out = jnp.einsum("hct,htd->hcd", probs.astype(vg.dtype), vg)
-        out = out.transpose(1, 0, 2).reshape(C, E).astype(x.dtype)
-        proj = _dense(out, p["attn"]["proj"], _sub(s, "attn", "proj"))
-        return pools, proj, None
+        def heads(t):                                   # -> [.., H, C, D]
+            return jnp.moveaxis(t.reshape(lead + (C, H, D)), -3, -2)
 
-    def _mlp(self, p, s, x):
+        out = paged_chunk_attention(
+            heads(q), heads(k), heads(v), first_block, pools["k"],
+            pools["v"], bt.reshape(lead + bt.shape[1:]),
+            past_lens.reshape(lead), **scale_pools)
+        return jnp.moveaxis(out, -3, -2).reshape(N, H, D)
+
+    def _block(self, p, s, x, attend):
+        """One transformer block over rows ``x [N, E]``: pre-LN
+        attention (``attend(q, k, v)`` over ``[N, H, D]``) and the GELU
+        MLP, each added to the residual. Returns the rows and the
+        layer's ``k`` and ``v``, which the forward writes to the pools."""
+        N, E = x.shape
+        H, D = self.n_head, self.head_dim
+        qkv = _dense(_ln(x, p["ln_1"]), p["attn"]["qkv"],
+                     _sub(s, "attn", "qkv"))
+        q, k, v = (t.reshape(N, H, D) for t in jnp.split(qkv, 3, axis=-1))
+        out = attend(q, k, v).reshape(N, E).astype(x.dtype)
+        x = x + _dense(out, p["attn"]["proj"], _sub(s, "attn", "proj"))
         h = jax.nn.gelu(_dense(_ln(x, p["ln_2"]), p["mlp"]["fc"],
                                _sub(s, "mlp", "fc")), approximate=True)
-        return _dense(h, p["mlp"]["proj"], _sub(s, "mlp", "proj"))
+        return x + _dense(h, p["mlp"]["proj"], _sub(s, "mlp", "proj")), k, v
 
-    # ---------------------------------------------------------- programs
-    def _stack_decode(self, params, scales, pools, bt, pos, live, tok,
-                      n_layers=None):
-        """Embed each live slot's token at its own position, run the
-        first ``n_layers`` of the stack (default: all), write those
-        layers' K/V, and return ``(pools, logits)``.
+    def _forward(self, params, scales, pools, bt, past_lens, tok, pos, write,
+                 n_layers=None, want_logits=True):
+        """The serving forward pass: ``C`` tokens for each of ``B`` slots.
+
+        tok/pos ``[B, C]``: the tokens and their absolute positions
+        (``pos[b] = past_lens[b] + 0..C-1``); write ``[B, C]`` bool:
+        which of them are real (a frozen slot, a chunk's pad tail and a
+        candidate past a slot's budget are not: their K/V go to the null
+        block and their output rows are discarded by the caller); bt
+        ``[B, MB]``; past_lens ``[B]``: tokens ALREADY in the pool.
+
+        Embeds, runs the first ``n_layers`` blocks (default: all),
+        writes those layers' K/V in ONE scatter per pool, and returns
+        ``(pools, logits [B*C, V])``, the logits ``None`` unless wanted.
 
         ``n_layers < cfg.n_layer`` is the truncated-layer self-draft of
         serving/speculative.py: the SAME params pytree traced over a
@@ -267,47 +225,39 @@ class PagedGPT2Runner:
         target's, so draft writes land in the same pools."""
         cfg = self.cfg
         bs = self.cache.block_size
-        L = cfg.n_layer if n_layers is None else int(n_layers)
-        x = params["wte"][tok] + params["wpe"][pos].astype(
-            params["wte"].dtype)
-        kv_stack = []
-        for layer in range(L):
-            p = params[f"h_{layer}"]
-            s = _sub(scales, f"h_{layer}")
-            pools, a, kv = self._attn_decode(p, s, layer, x, pools, bt,
-                                             pos, live)
-            if kv is not None:
-                kv_stack.append(kv)
-            x = x + a
-            x = x + self._mlp(p, s, x)
-        if kv_stack:
-            # paged impl: ONE scatter per pool for the layers that ran;
-            # non-live slots land in the null block
-            row = jnp.take_along_axis(bt, (pos // bs)[:, None],
-                                      axis=1)[:, 0]
-            blk = jnp.where(live, row, 0)
-            pools = self.cache.write_layers(
-                pools, jnp.stack([k for k, _ in kv_stack]),
-                jnp.stack([v for _, v in kv_stack]), blk, pos % bs)
+        B, C = tok.shape
+        # a pad or over-budget position can step past n_positions (its
+        # row is discarded) and past the table: clamps keep both gathers
+        # legal
+        x = params["wte"][tok] + params["wpe"][
+            jnp.minimum(pos, cfg.n_positions - 1)].astype(
+                params["wte"].dtype)
+        x = x.reshape(B * C, cfg.n_embd)
+        ks, vs = [], []
+        for layer in range(cfg.n_layer if n_layers is None
+                           else int(n_layers)):
+            x, k, v = self._block(
+                params[f"h_{layer}"], _sub(scales, f"h_{layer}"), x,
+                functools.partial(self._attend, layer, pools, bt,
+                                  past_lens, C))
+            ks.append(k)
+            vs.append(v)
+        row = jnp.take_along_axis(
+            bt, jnp.minimum(pos // bs, bt.shape[1] - 1), axis=1)
+        pools = self.cache.write_layers(
+            pools, jnp.stack(ks), jnp.stack(vs),
+            jnp.where(write, row, 0).reshape(-1), (pos % bs).reshape(-1))
+        if not want_logits:
+            return pools, None
         x = _ln(x, params["ln_f"])
-        logits = jnp.einsum("be,ve->bv", x, params["wte"],
-                            preferred_element_type=jnp.float32)
-        return pools, logits
+        return pools, jnp.einsum("be,ve->bv", x, params["wte"],
+                                 preferred_element_type=jnp.float32)
 
-    def _decode_one(self, params, scales, pools, bt, pos, live, tok,
-                    temp, top_p, lanes):
-        """One decode iteration over the slot batch: embed each live
-        slot's token at its own position, run the stack, write all
-        layers' K/V, sample."""
-        pools, logits = self._stack_decode(params, scales, pools, bt,
-                                           pos, live, tok)
-        nxt = sample_tokens(logits, temp, top_p, lanes, pos,
-                            vocab_size=self.cfg.vocab_size)
-        return pools, nxt
-
+    # ---------------------------------------------------------- programs
     def _decode_impl(self, params, scales, pools, bt, pos, active, tok,
                      temp, top_p, lanes, budget):
-        """``decode_steps`` iterations in one dispatch (lax.scan).
+        """``decode_steps`` iterations in one dispatch (lax.scan), each
+        the forward at ``C = 1`` and a sampled token per slot.
 
         ``budget`` [B]: tokens this dispatch may produce per slot (the
         scheduler caps it by remaining generation / model length /
@@ -318,20 +268,22 @@ class PagedGPT2Runner:
         """
         K = self.decode_steps
 
+        def one(pools, step_pos, live, cur):
+            pools, logits = self._forward(
+                params, scales, pools, bt, step_pos, cur[:, None],
+                step_pos[:, None], live[:, None])
+            return pools, sample_tokens(logits, temp, top_p, lanes,
+                                        step_pos,
+                                        vocab_size=self.cfg.vocab_size)
+
         def body(carry, i):
             pools, cur = carry
-            step_pos = pos + jnp.minimum(i, budget)
             live = active & (i < budget)
-            pools, nxt = self._decode_one(params, scales, pools, bt,
-                                          step_pos, live, cur, temp,
-                                          top_p, lanes)
-            cur = jnp.where(live, nxt, cur)
-            return (pools, cur), nxt
+            pools, nxt = one(pools, pos + jnp.minimum(i, budget), live, cur)
+            return (pools, jnp.where(live, nxt, cur)), nxt
 
         if K == 1:
-            live = active & (budget > 0)
-            pools, nxt = self._decode_one(params, scales, pools, bt, pos,
-                                          live, tok, temp, top_p, lanes)
+            pools, nxt = one(pools, pos, active & (budget > 0), tok)
             return pools, nxt[None]
         (pools, _), toks = jax.lax.scan(
             body, (pools, tok), jnp.arange(K, dtype=jnp.int32))
@@ -339,33 +291,12 @@ class PagedGPT2Runner:
 
     def _prefill_impl(self, params, scales, pools, bt_row, tokens, start,
                       n_valid):
-        cfg = self.cfg
-        bs = self.cache.block_size
-        MB = bt_row.shape[0]
-        C = tokens.shape[0]
-        pos = start + jnp.arange(C, dtype=jnp.int32)
-        # the padded tail of the final chunk can step past n_positions;
-        # its embedding rows are discarded, clamp keeps the gather legal
-        pos_emb = jnp.minimum(pos, cfg.n_positions - 1)
-        x = params["wte"][tokens] + params["wpe"][pos_emb].astype(
-            params["wte"].dtype)
-        kv_stack = []
-        for layer in range(cfg.n_layer):
-            p = params[f"h_{layer}"]
-            s = _sub(scales, f"h_{layer}")
-            pools, a, kv = self._attn_prefill(p, s, layer, x, pools,
-                                              bt_row, pos, start, n_valid)
-            if kv is not None:
-                kv_stack.append(kv)
-            x = x + a
-            x = x + self._mlp(p, s, x)
-        if kv_stack:
-            valid = jnp.arange(C) < n_valid
-            blk = jnp.where(valid,
-                            bt_row[jnp.minimum(pos // bs, MB - 1)], 0)
-            pools = self.cache.write_layers(
-                pools, jnp.stack([k for k, _ in kv_stack]),
-                jnp.stack([v for _, v in kv_stack]), blk, pos % bs)
+        """One slot's chunk: the forward at ``B = 1``, no head; the
+        positions past ``n_valid`` are the final chunk's pad."""
+        idx = jnp.arange(tokens.shape[0], dtype=jnp.int32)
+        pools, _ = self._forward(
+            params, scales, pools, bt_row[None], start[None], tokens[None],
+            (start + idx)[None], (idx < n_valid)[None], want_logits=False)
         return pools
 
     # -------------------------------------------------------- public API

@@ -85,7 +85,7 @@ class RequestOutput:
 
 
 class ServingEngine:
-    def __init__(self, engine, config=None, registry=None, use_flash=None,
+    def __init__(self, engine, config=None, registry=None,
                  guardian=None, obs_server=None, slo=None,
                  draft_params=None, draft_scales=None):
         """``engine``: an ``InferenceEngine`` wrapping a GPT-2-family
@@ -133,9 +133,7 @@ class ServingEngine:
             block_size=config.block_size, num_blocks=num_blocks,
             dtype=engine.dtype, int8_kv=int8_kv)
         self.runner = PagedGPT2Runner(
-            model, self.cache, use_flash=use_flash,
-            attention_impl=config.attention_impl,
-            decode_steps=config.decode_steps)
+            model, self.cache, decode_steps=config.decode_steps)
         # speculative decoding (serving/speculative.py): replaces the
         # decode dispatch with a draft + verify program pair. The
         # scheduler's per-dispatch token budget (and the slot-step
@@ -221,8 +219,7 @@ class ServingEngine:
         pc_cfg = getattr(config, "prefix_cache", None)
         if pc_cfg is not None and pc_cfg.enabled:
             self.cache.attach_prefix_cache(
-                capacity_blocks=pc_cfg.capacity_blocks,
-                attention_impl=config.attention_impl)
+                capacity_blocks=pc_cfg.capacity_blocks)
         # HBM residency observatory (telemetry/memory_observatory.py):
         # shared with the train engine's manager — the serving tick adds
         # THIS server's paged-KV pool to the inventory, so the

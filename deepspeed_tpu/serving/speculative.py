@@ -20,9 +20,8 @@ Two compiled programs, both static-shaped for the serving lifetime:
   program; draft quality only moves the ACCEPTANCE RATE, never
   correctness — the verify pass decides every delivered token.
 * ``verify_step`` — the target forward over ``K+1`` positions per slot
-  (the slot's last accepted token + K drafted), the batched cross of the
-  decode and prefill-chunk programs: past pages stream through
-  ``paged_verify_attention`` while the candidate chunk stays in
+  (the slot's last accepted token + K drafted): the runner's one forward
+  at ``C = K+1``, past pages streamed while the candidate chunk stays in
   registers (causal), then ONE stacked scatter writes all layers at all
   candidate positions. Target tokens come from the SAME
   ``sample_tokens`` + position-fold the decode scan uses, so greedy
@@ -52,8 +51,6 @@ probability clears ``typical_threshold`` × the modal probability
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.serving.paged_attention import paged_verify_attention
-from deepspeed_tpu.serving.runner import NEG_INF, _dense, _ln, _sub
 from deepspeed_tpu.serving.sampling import sample_tokens
 
 
@@ -117,8 +114,8 @@ class SpeculativeDecoder:
     def _draft_impl(self, params, scales, pools, bt, pos, active, tok,
                     budget):
         """K greedy steps through the first ``draft_layers`` of
-        ``params`` (the scan body is the runner's own ``_stack_decode``
-        over a layer prefix). Writes ride ``write_layers`` at the
+        ``params`` (the scan body is the runner's own forward at
+        ``C = 1`` over a layer prefix). Writes ride ``write_layers`` at the
         speculative positions, budget-masked to the null block beyond
         each slot's allocation. Returns ``(pools, drafted [K, B])``."""
         r = self.runner
@@ -128,8 +125,9 @@ class SpeculativeDecoder:
             pools, cur = carry
             step_pos = pos + jnp.minimum(i, jnp.maximum(budget - 1, 0))
             live = active & (i < budget)
-            pools, logits = r._stack_decode(
-                params, scales, pools, bt, step_pos, live, cur,
+            pools, logits = r._forward(
+                params, scales, pools, bt, step_pos, cur[:, None],
+                step_pos[:, None], live[:, None],
                 n_layers=self.draft_layers)
             nxt = jnp.argmax(logits[:, :vocab], axis=-1).astype(jnp.int32)
             cur = jnp.where(live, nxt, cur)
@@ -140,55 +138,6 @@ class SpeculativeDecoder:
         return pools, drafted
 
     # ---------------------------------------------------------- verify
-    def _attn_verify(self, p, s, layer, x, pools, bt, pos, poss, live_w):
-        """One layer's attention for the K+1 candidate chunk of every
-        slot. Paged impl: past pages + the chunk from registers (write
-        deferred to the stacked scatter). Gather impl: eager write, then
-        dense per-query-masked attention over the contiguous view — the
-        batched form of the prefill chunk's gather branch."""
-        r = self.runner
-        cache = r.cache
-        B, C = poss.shape
-        H, D = r.n_head, r.head_dim
-        N, E = x.shape
-        int8 = cache.int8_kv
-        q, k, v = r._qkv(p, s, x)                       # [B*C, H, D]
-
-        def heads(t):                                   # -> [B, H, C, D]
-            return t.reshape(B, C, H, D).transpose(0, 2, 1, 3)
-
-        if r.attention_impl == "paged":
-            out = paged_verify_attention(
-                heads(q), heads(r._requant(k)), heads(r._requant(v)),
-                layer * cache.num_blocks, pools["k"], pools["v"], bt, pos,
-                k_scale_pool=pools["k_scale"] if int8 else None,
-                v_scale_pool=pools["v_scale"] if int8 else None)
-            out = out.transpose(0, 2, 1, 3).reshape(N, E).astype(x.dtype)
-            proj = _dense(out, p["attn"]["proj"], _sub(s, "attn", "proj"))
-            return pools, proj, (k, v)
-        bs = cache.block_size
-        MB = bt.shape[1]
-        row = jnp.take_along_axis(bt, jnp.minimum(poss // bs, MB - 1),
-                                  axis=1)                # [B, C]
-        blk = jnp.where(live_w, row, 0).reshape(-1)
-        pools = cache.write_layers(pools, k[None], v[None], blk,
-                                   (poss % bs).reshape(-1),
-                                   first_layer=layer)
-        kg, vg, ksg, vsg = cache.gather(pools, layer, bt)  # [B, H, T, D]
-        if int8:
-            kg = (kg.astype(jnp.float32) * ksg[..., None]).astype(x.dtype)
-            vg = (vg.astype(jnp.float32) * vsg[..., None]).astype(x.dtype)
-        T = kg.shape[2]
-        scores = jnp.einsum("bhcd,bhtd->bhct", heads(q).astype(jnp.float32),
-                            kg.astype(jnp.float32)) * (D ** -0.5)
-        mask = jnp.arange(T)[None, None, :] <= poss[:, :, None]  # [B, C, T]
-        scores = jnp.where(mask[:, None], scores, NEG_INF)
-        probs = jax.nn.softmax(scores, axis=-1)
-        out = jnp.einsum("bhct,bhtd->bhcd", probs.astype(vg.dtype), vg)
-        out = out.transpose(0, 2, 1, 3).reshape(N, E).astype(x.dtype)
-        proj = _dense(out, p["attn"]["proj"], _sub(s, "attn", "proj"))
-        return pools, proj, None
-
     def _verify_impl(self, params, scales, pools, bt, pos, active,
                      drafted, tok, temp, top_p, lanes, budget):
         """ONE target forward over K+1 positions per slot; returns
@@ -198,48 +147,20 @@ class SpeculativeDecoder:
         token). Only ``min(accepted+1, budget)`` rows are meaningful per
         slot; the host caps delivery."""
         r = self.runner
-        cache = r.cache
         cfg = r.cfg
-        bs = cache.block_size
         K = self.k
         C = K + 1
         B = tok.shape[0]
         toks_in = jnp.concatenate([tok[None], drafted], axis=0).T  # [B, C]
         poss = pos[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :]
+        # ONE stacked scatter for all layers x all K+1 positions:
+        # accepted positions become real target KV, rejected ones stale
+        # bytes the past_lens mask never reads; the tail candidates of a
+        # budget-capped slot are write-masked
         live_w = active[:, None] \
             & (jnp.arange(C, dtype=jnp.int32)[None, :] < budget[:, None])
-        # the tail candidates of a budget-capped slot can step past
-        # n_positions; their rows are write-masked, clamp keeps the
-        # embedding gather legal (same move as the prefill pad tail)
-        pos_emb = jnp.minimum(poss, cfg.n_positions - 1)
-        x = (params["wte"][toks_in]
-             + params["wpe"][pos_emb].astype(params["wte"].dtype))
-        x = x.reshape(B * C, cfg.n_embd)
-        kv_stack = []
-        for layer in range(cfg.n_layer):
-            p = params[f"h_{layer}"]
-            s = _sub(scales, f"h_{layer}")
-            pools, a, kv = self._attn_verify(p, s, layer, x, pools, bt,
-                                             pos, poss, live_w)
-            if kv is not None:
-                kv_stack.append(kv)
-            x = x + a
-            x = x + r._mlp(p, s, x)
-        if kv_stack:
-            # ONE stacked scatter for all layers × all K+1 positions —
-            # accepted positions become real target KV, rejected ones
-            # become stale bytes the past_lens mask never reads
-            MB = bt.shape[1]
-            row = jnp.take_along_axis(bt, jnp.minimum(poss // bs, MB - 1),
-                                      axis=1)
-            blk = jnp.where(live_w, row, 0).reshape(-1)
-            pools = cache.write_layers(
-                pools, jnp.stack([k for k, _ in kv_stack]),
-                jnp.stack([v for _, v in kv_stack]), blk,
-                (poss % bs).reshape(-1))
-        x = _ln(x, params["ln_f"])
-        logits = jnp.einsum("be,ve->bv", x, params["wte"],
-                            preferred_element_type=jnp.float32)
+        pools, logits = r._forward(params, scales, pools, bt, pos, toks_in,
+                                   poss, live_w)
         # the target's OWN token at every position: same sampler, same
         # position fold as the decode scan -> path-invariant draws
         flat_pos = poss.reshape(-1)

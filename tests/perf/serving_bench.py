@@ -67,8 +67,7 @@ Env:  SERVING_BENCH_OUT (default SERVING_BENCH.json at the repo root),
       SERVING_BENCH_MODEL ("bench-small" default; any PRESETS name),
       SERVING_BENCH_N (requests, default 96), SERVING_BENCH_BATCH
       (max batch, default 8), SERVING_BENCH_KV (auto|int8),
-      SERVING_BENCH_ATTN (gather|paged), SERVING_BENCH_DECODE_STEPS
-      (tokens per decode dispatch, default 8),
+      SERVING_BENCH_DECODE_STEPS (tokens per decode dispatch, default 8),
       SERVING_BENCH_PREFIX_N / _PREFIX_POOL / _PREFIX_LEN / _REUSE
       (shared-prefix trace: requests 64, pool 4, prefix length 96,
       reuse ratio 0.9), SERVING_BENCH_ROUTER_N (router trace size, 32),
@@ -355,7 +354,7 @@ def run_spec_arm(eng, max_batch, trace, k, draft_layers, spec, reps,
     from deepspeed_tpu.telemetry.metrics import MetricsRegistry
 
     cfg = {"max_batch": max_batch, "block_size": 32, "prefill_chunk": 64,
-           "max_model_len": 256, "attention_impl": "gather",
+           "max_model_len": 256,
            "decode_steps": 1 if spec else k + 1,
            "observability": {
                "enabled": True, "window": 32,
@@ -569,14 +568,9 @@ def main():
     base_s, base_ttfts, waste = run_baseline(eng, trace, max_batch)
 
     registry = MetricsRegistry()
-    # gather impl: at this scenario's small T_max/live ratio the
-    # contiguous-view read beats the streaming block loop's per-iteration
-    # overhead (the paged impl pays off when allocated windows are long
-    # relative to live lengths); decode_steps=8 amortises host dispatch
+    # decode_steps=8 amortises host dispatch
     serving_cfg = {"max_batch": max_batch, "block_size": 32,
                    "prefill_chunk": 64, "max_model_len": max_model_len,
-                   "attention_impl": os.environ.get(
-                       "SERVING_BENCH_ATTN", "gather"),
                    "decode_steps": int(os.environ.get(
                        "SERVING_BENCH_DECODE_STEPS", "8")),
                    # the slot-step ledger rides the timed run (pure host

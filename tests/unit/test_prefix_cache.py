@@ -195,7 +195,7 @@ def _host_cache(num_blocks=17, block_size=4, prefix=True):
     cache = PagedKVCache(n_layer=1, n_head=1, head_dim=4,
                          block_size=block_size, num_blocks=num_blocks)
     if prefix:
-        cache.attach_prefix_cache(attention_impl="paged")
+        cache.attach_prefix_cache()
     return cache
 
 

@@ -318,6 +318,15 @@ def test_serving_config_validation():
             DeepSpeedServingConfig({"serving": bad})
 
 
+@pytest.mark.parametrize("value", ["paged", "gather"])
+def test_removed_attention_impl_key_is_refused(value):
+    """The server has one attention path; a config from before says so
+    by being refused, whatever the key held, and is not read past."""
+    with pytest.raises(DeepSpeedConfigError, match="attention_impl"):
+        DeepSpeedServingConfig({"serving": {"attention_impl": value}})
+    assert not hasattr(DeepSpeedServingConfig({}), "attention_impl")
+
+
 # ------------------------------------------------------------- sampling
 def test_top_p_filter_keeps_nucleus():
     from deepspeed_tpu.serving.sampling import NEG_INF, top_p_filter
@@ -472,17 +481,13 @@ def test_e2e_mask_correct_when_requests_finish_mid_batch(tiny_serving):
     assert srv.cache.allocator.num_allocated == 0
 
 
-@pytest.mark.parametrize("variant", [
-    {"attention_impl": "gather"},
-    {"decode_steps": 4},
-    {"decode_steps": 4, "attention_impl": "gather"},
-])
+@pytest.mark.parametrize("variant", [{"decode_steps": 2},
+                                     {"decode_steps": 4}])
 def test_e2e_variant_parity(tiny_serving, variant):
-    """The gather attention impl and multi-step decode dispatches
-    (vLLM-style decode_steps>1) must produce byte-identical greedy
-    tokens — multi-step only changes how many tokens ride one dispatch,
-    and sampling folds the POSITION into the RNG lane so K is
-    semantics-free."""
+    """Multi-step decode dispatches (vLLM-style decode_steps>1) must
+    produce byte-identical greedy tokens — multi-step only changes how
+    many tokens ride one dispatch, and sampling folds the POSITION into
+    the RNG lane so K is semantics-free."""
     cfg, eng, srv, registry = tiny_serving
     from deepspeed_tpu.serving.server import ServingEngine
     v = ServingEngine(eng, config={"max_batch": 2, "block_size": 8,
@@ -531,13 +536,12 @@ def test_e2e_int8_kv_and_int8_weights_parity():
 
 
 @pytest.mark.parametrize("kv", ["auto", "int8"], ids=["kv-float", "kv-int8"])
-@pytest.mark.parametrize("impl", ["paged", "gather"])
-def test_e2e_lanes_that_do_not_divide(impl, kv):
+def test_e2e_lanes_that_do_not_divide(kv):
     """5 heads of 64: ``n_head*head_dim`` = 320 is 2.5 lanes of 128, so
     the pools' rows are padded to 384 (gpt2-xl's 1,600 -> 1,664 is the
-    deployed case). Both attention impls serve the flax decode path's
-    tokens one for one, and the pad lanes of every row written stay
-    zero: nothing past lane 320 ever reaches a product."""
+    deployed case). The server serves the flax decode path's tokens one
+    for one, and the pad lanes of every row written stay zero: nothing
+    past lane 320 ever reaches a product."""
     groups.destroy()
     groups.initialize()
     cfg = GPT2Config(vocab_size=256, n_positions=64, n_embd=320,
@@ -549,8 +553,7 @@ def test_e2e_lanes_that_do_not_divide(impl, kv):
                                        dtype=jnp.float32)
     from deepspeed_tpu.serving.server import ServingEngine
     srv = ServingEngine(eng, config={"max_batch": 2, "block_size": 8,
-                                     "prefill_chunk": 6,
-                                     "attention_impl": impl},
+                                     "prefill_chunk": 6},
                         registry=MetricsRegistry())
     cache = srv.cache
     assert cache.int8_kv == (kv == "int8")
@@ -566,7 +569,7 @@ def test_e2e_lanes_that_do_not_divide(impl, kv):
             for p, (_, g) in zip(prompts, cases)]
     outs = {o.req_id: o for o in srv.serve_forever()}
     for rid, p, (_, g) in zip(rids, prompts, cases):
-        assert outs[rid].tokens == _baseline(eng, p, g), (impl, kv, rid)
+        assert outs[rid].tokens == _baseline(eng, p, g), (kv, rid)
     for name, pool in srv.pools.items():
         used = cfg.n_head if name.endswith("_scale") else 320
         pool = np.asarray(pool)

@@ -157,17 +157,14 @@ def test_int8_weights_int8_kv_parity():
     assert serve(spec=True) == serve(spec=False)
 
 
-@pytest.mark.parametrize("impl", ["paged", "gather"])
 @pytest.mark.parametrize("kv", ["auto", "int8"], ids=["kv-float", "kv-int8"])
-def test_lanes_that_do_not_divide_parity(kv, impl):
+def test_lanes_that_do_not_divide_parity(kv):
     """5 heads of 64 (2.5 lanes of 128: the pools' rows are padded to
     384): the draft's write over a layer prefix, the verify read and its
     write of all layers at K+1 positions serve generate()'s greedy
-    tokens one for one, under both attention impls."""
+    tokens one for one."""
     cfg, eng = _make_engine(seed=4, kv=kv, n_embd=320, n_head=5)
-    srv = ServingEngine(eng,
-                        config=_spec_cfg(extra={"attention_impl": impl}),
-                        registry=MetricsRegistry())
+    srv = ServingEngine(eng, config=_spec_cfg(), registry=MetricsRegistry())
     assert srv.cache.row_width == 384
     rng = np.random.default_rng(31)
     cases = [(11, 6), (3, 9), (22, 4)]
@@ -177,7 +174,7 @@ def test_lanes_that_do_not_divide_parity(kv, impl):
             for p, (_, g) in zip(prompts, cases)]
     outs = {o.req_id: o for o in srv.serve_forever()}
     for rid, p, (_, g) in zip(rids, prompts, cases):
-        assert outs[rid].tokens == _baseline(eng, p, g), (kv, impl, rid)
+        assert outs[rid].tokens == _baseline(eng, p, g), (kv, rid)
     assert srv.compile_stats() == SPEC_COMPILE
     assert not np.asarray(srv.pools["k"])[..., 320:].any()
 
