@@ -255,9 +255,16 @@ class PagedGPT2Runner:
 
     # ---------------------------------------------------------- programs
     def _decode_impl(self, params, scales, pools, bt, pos, active, tok,
-                     temp, top_p, lanes, budget):
+                     temp, top_p, lanes, budget, prev, prev_row):
         """``decode_steps`` iterations in one dispatch (lax.scan), each
         the forward at ``C = 1`` and a sampled token per slot.
+
+        A slot's first input is ``tok`` (the host's) where ``prev_row`` is
+        negative, else row ``prev_row`` of ``prev [K, B]``: the tokens
+        the dispatch before this one returned, which the host has not
+        read yet (the server runs a step ahead of the device; the very
+        first dispatch passes zeros of the same shape, so there is one
+        program).
 
         ``budget`` [B]: tokens this dispatch may produce per slot (the
         scheduler caps it by remaining generation / model length /
@@ -267,6 +274,8 @@ class PagedGPT2Runner:
         per-token continuous batching. Returns (pools, tokens [K, B]).
         """
         K = self.decode_steps
+        tok = jnp.where(prev_row >= 0, jnp.take_along_axis(
+            prev, jnp.maximum(prev_row, 0)[None], axis=0)[0], tok)
 
         def one(pools, step_pos, live, cur):
             pools, logits = self._forward(
@@ -301,11 +310,13 @@ class PagedGPT2Runner:
 
     # -------------------------------------------------------- public API
     def decode_step(self, params, scales, pools, bt, pos, active, tok,
-                    temp, top_p, lanes, budget):
+                    temp, top_p, lanes, budget, prev, prev_row):
         """One decode DISPATCH (``decode_steps`` tokens per slot, budget-
-        capped); returns ``(pools, tokens [K, B] int32 device array)``."""
+        capped); returns ``(pools, tokens [K, B] int32 device array)``.
+        ``prev``/``prev_row``: the dispatch before's tokens and the row of
+        them that is each slot's input (negative: ``tok``)."""
         return self._decode(params, scales or {}, pools, bt, pos, active,
-                            tok, temp, top_p, lanes, budget)
+                            tok, temp, top_p, lanes, budget, prev, prev_row)
 
     def prefill_chunk(self, params, scales, pools, bt_row, tokens, start,
                       n_valid):

@@ -20,6 +20,7 @@ the sort permutation needed.
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 NEG_INF = -1e30
 
@@ -83,7 +84,17 @@ def sample_tokens(logits, temperature, top_p, rng_lanes, positions,
 
 
 def make_rng_lane(seed: int):
-    """Host-side: one request's key lane (uint32[2]) from its seed."""
-    import numpy as np
-    key = jax.random.PRNGKey(int(seed))
-    return np.asarray(jax.device_get(key), np.uint32)
+    """One request's key lane (uint32[2]) from its seed: what
+    ``jax.random.PRNGKey(seed)`` holds, computed on the host.
+
+    ``submit()`` calls this with the device's queue a whole step deep, so
+    a key built on the device and read back would wait for that queue.
+    Under the default ``threefry2x32`` implementation with x64 off the
+    seed is cut to 32 bits and the key is ``[0, seed mod 2**32]`` (pinned
+    against ``PRNGKey`` in tests/unit/test_serving.py); any other
+    setting, read off ``jax.config``, keeps the device form."""
+    if (jax.config.jax_default_prng_impl == "threefry2x32"
+            and not jax.config.jax_enable_x64):
+        return np.array([0, int(seed) & 0xFFFFFFFF], np.uint32)
+    return np.asarray(jax.device_get(jax.random.PRNGKey(int(seed))),
+                      np.uint32)

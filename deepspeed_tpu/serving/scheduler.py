@@ -36,6 +36,21 @@ hands the server an active mask. Policy:
   preemption-by-eviction path and its recompute accounting compose
   unchanged.
 
+* **Counts at dispatch, tokens at landing**: the server runs one step
+  ahead of the device, so a step is scheduled before the tokens of the
+  step before it have been read. Everything here follows what has been
+  DISPATCHED: ``cached_len`` advances when a chunk or a decode row is
+  sent, ``Request.dispatched`` counts the decode rows sent, and the
+  budget, the block growth and the decode set are computed from those
+  counts alone. ``output_tokens`` holds what has LANDED (been read back),
+  one step later. A request whose every token has been dispatched is not
+  decoded again; it keeps its slot, and its blocks, until the last of
+  them lands and the server calls ``finish`` (the prefix index needs the
+  tokens before the blocks go): one slot-step a request. Eviction
+  re-queues ``full_prompt``, which must hold every token whose KV was
+  written, so the scheduler asks the server to land what is in flight
+  (``land_first``) before it preempts.
+
 The scheduler is pure host-side bookkeeping: it never touches device
 state. The server (serving/server.py) turns its ``StepPlan`` into the
 static tensors the compiled programs consume.
@@ -67,8 +82,12 @@ class Request:
     # --- runtime state (scheduler/server owned) ---
     state: RequestState = RequestState.WAITING
     output_tokens: List[int] = dataclasses.field(default_factory=list)
+    # generated tokens that have LANDED (been read back from the device)
+    in_flight: int = 0          # decode rows dispatched whose tokens have
+    # not landed yet (the server runs one step ahead of the device)
     block_table: List[int] = dataclasses.field(default_factory=list)
-    cached_len: int = 0                  # KV positions written
+    cached_len: int = 0                  # KV positions written, or that
+    # a dispatched program will write: advances at DISPATCH
     max_cached_len: int = 0              # high-water mark across evictions:
     # re-prefilled positions below it are RECOMPUTE (their KV existed
     # before a preemption threw it away)
@@ -109,6 +128,12 @@ class Request:
         return self.spec_accepted / self.spec_drafted
 
     @property
+    def dispatched(self) -> int:
+        """Generated tokens dispatched so far, landed or in flight: what
+        the budget and the end of generation are counted from."""
+        return len(self.output_tokens) + self.in_flight
+
+    @property
     def full_prompt(self) -> List[int]:
         """Tokens whose KV must exist to continue decoding — the original
         prompt plus everything generated so far (what a preempted request
@@ -120,10 +145,13 @@ class Request:
 class StepPlan:
     """One scheduler iteration: one prefill chunk per still-prefilling
     slot (earliest-admitted first) + the decode slot set + the pending
-    copy-on-write forks the server must execute FIRST."""
+    copy-on-write forks the server must execute FIRST. ``awaiting``
+    counts the slots held by a request whose last token is in flight:
+    not decoded again, not yet vacated."""
     prefill: List[Request] = dataclasses.field(default_factory=list)
     decode_slots: List[int] = dataclasses.field(default_factory=list)
     cow_forks: List[Request] = dataclasses.field(default_factory=list)
+    awaiting: int = 0
 
     @property
     def has_work(self) -> bool:
@@ -142,6 +170,11 @@ class ContinuousBatchingScheduler:
         # synchronously on admit / preempt / admission-fail with the
         # request still carrying its pre-transition state
         self.observer = observer
+        # set by the server: ``land_first(reason) -> bool`` reads back the
+        # tokens in flight (True when there were any). Called before an
+        # eviction, whose re-queued ``full_prompt`` must hold every token
+        # whose KV was written
+        self.land_first = None
         self.waiting = deque()
         self.slots: List[Optional[Request]] = [None] * self.max_batch
         self._admit_counter = 0
@@ -193,6 +226,11 @@ class ContinuousBatchingScheduler:
         # PREFILL state), and the plan must only name requests that still
         # occupy a slot afterwards
         plan.decode_slots = self._ensure_decode_capacity()
+        # a running slot that is not decoded has had its every token
+        # dispatched
+        plan.awaiting = sum(
+            r is not None and r.state is RequestState.RUNNING
+            for r in self.slots) - len(plan.decode_slots)
         # one chunk per prefilling slot, earliest admission first: empty
         # decode slots are pure waste, so prefill runs at batch priority
         # (each chunk is still bounded, so decode interleaves at most
@@ -300,12 +338,19 @@ class ContinuousBatchingScheduler:
             if self.observer is not None:
                 self.observer.on_admit(req)
 
+    def _all_dispatched(self, req: Request) -> bool:
+        """Every token *req* may produce has been dispatched (its
+        generation length or the model length is reached by count): it
+        is not decoded again, and holds its slot until the last lands."""
+        return (req.dispatched >= req.max_new_tokens
+                or req.cached_len >= self.max_model_len)
+
     def _ensure_decode_capacity(self) -> List[int]:
         """Compute each running slot's dispatch budget (tokens the next
         decode dispatch may emit: capped by decode_steps, remaining
-        generation and the model-length cap), grow its block table to
-        cover the budget's KV writes, and preempt-by-eviction when the
-        pool runs dry.
+        generation and the model-length cap, all by DISPATCHED count),
+        grow its block table to cover the budget's KV writes, and
+        preempt-by-eviction when the pool runs dry.
 
         Two phases: capacity growth may preempt ANY slot — including one
         visited earlier — so the decode list is collected only after
@@ -313,10 +358,11 @@ class ContinuousBatchingScheduler:
         slot that a later slot's eviction emptied)."""
         for i in range(self.max_batch):
             req = self.slots[i]
-            if req is None or req.state is not RequestState.RUNNING:
+            if req is None or req.state is not RequestState.RUNNING \
+                    or self._all_dispatched(req):
                 continue
             budget = min(self.decode_steps,
-                         req.max_new_tokens - len(req.output_tokens),
+                         req.max_new_tokens - req.dispatched,
                          max(1, self.max_model_len - req.cached_len))
             req.step_budget = max(1, budget)
             while self.cache.blocks_for(
@@ -340,13 +386,23 @@ class ContinuousBatchingScheduler:
                 if owned >= 1:
                     req.step_budget = min(req.step_budget, owned)
                     break
+                if self.land_first is not None \
+                        and self.land_first("preemption"):
+                    # the tokens in flight have landed: every slot's
+                    # ``full_prompt`` now holds each token whose KV was
+                    # written, and a request they finished (this one,
+                    # maybe) has returned its blocks: try again
+                    if self.slots[i] is not req:
+                        break
+                    continue
                 victim = self._pick_victim()
                 self._preempt(victim, reason="capacity_growth")
                 if victim is req:
                     break
         return [i for i in range(self.max_batch)
                 if self.slots[i] is not None
-                and self.slots[i].state is RequestState.RUNNING]
+                and self.slots[i].state is RequestState.RUNNING
+                and not self._all_dispatched(self.slots[i])]
 
     def _pick_victim(self) -> Request:
         """Latest-admitted occupied slot — the request that has consumed
@@ -361,6 +417,9 @@ class ContinuousBatchingScheduler:
         today (a running slot needed one more KV block and the pool was
         dry); ``admission`` is reserved for a future evict-to-admit
         policy — strict FCFS never evicts at admission."""
+        assert req.in_flight == 0, (
+            f"req {req.req_id} evicted with {req.in_flight} token(s) in "
+            f"flight: full_prompt would miss KV that was written")
         # the high-water mark is what re-prefill will RE-compute: every
         # position below it had KV before this eviction threw it away
         req.max_cached_len = max(req.max_cached_len, req.cached_len)

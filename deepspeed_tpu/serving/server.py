@@ -11,6 +11,24 @@ prefill chunk per still-prefilling slot + one decode dispatch),
 ``serve_forever``) owns the loop and there is no hidden thread to
 reason about.
 
+The host runs ONE STEP AHEAD of the device. ``step()`` call k schedules
+step k from counts, dispatches its prefill chunks and its decode
+program, and only then reads back ("lands") the tokens of step k-1,
+which the device finished before it began step k's prefill: when a
+decode program ends, the next step is already queued, and the host's
+work of a step (scheduling, inputs, delivery, gauges, the caller's
+``submit()`` and ``collect()``) happens while the device runs. Step k's
+decode takes a slot's input from step k-1's output where it lies, on
+the device. Counts (``cached_len``, ``Request.dispatched``, budgets,
+block growth) advance at DISPATCH; ``output_tokens``, the EOS test,
+``finish``, the prefix index, TTFT and the latency histograms happen at
+LANDING, one step later. Where a step must know its tokens it lands
+first, read from its own state and never from an option: under
+speculation (``accepted`` decides the positions), before an eviction
+(``full_prompt`` must hold every token whose KV was written), and on
+the way out (``close``, the end of ``serve_forever``,
+``profile_window``).
+
 Observability rides the PR-1 registry (so the existing JSONL/Prometheus
 sinks carry serving without new plumbing): per-request TTFT and
 inter-token latency histograms, queue-depth / active-slot / KV-occupancy
@@ -24,6 +42,7 @@ import os
 import time
 from typing import List, Optional
 
+import jax
 import numpy as np
 
 from deepspeed_tpu.serving.kv_cache import PagedKVCache
@@ -71,6 +90,16 @@ class ServingAdmissionPausedError(RuntimeError):
             f"admission paused by the guardian (rule {rule!r}): the "
             f"server is shedding load; retry after recovery")
         self.rule = rule
+
+
+@dataclasses.dataclass
+class _Dispatch:
+    """One decode dispatch whose tokens the host has not read back."""
+    reqs: dict              # slot -> the Request it decoded for
+    budget: np.ndarray      # [B] rows each slot was given
+    toks: object            # [K, B] device array, its host copy under way
+    accepted: object        # [B] device array under speculation, else None
+    t0: int                 # perf_counter_ns when the dispatch began
 
 
 @dataclasses.dataclass
@@ -258,6 +287,17 @@ class ServingEngine:
         self._next_id = 0
         self._finished = []
         self._lanes = {}              # req_id -> uint32[2] rng lane
+        # the decode dispatch whose tokens have not landed (None: none),
+        # what a slot did this step (the slot-step ledger's input), and
+        # what the first dispatch reads in place of a dispatch before:
+        # zeros placed like the program's own output, so that there is
+        # one decode program
+        self._in_flight = None
+        self._acts = {}
+        self._no_prev = jax.device_put(
+            np.zeros((self.runner.decode_steps, self.max_batch), np.int32),
+            NamedSharding(engine.mesh, PartitionSpec()))
+        self.scheduler.land_first = self._land
         self.registry.gauge(
             "serving_kv_pool_bytes",
             "allocated paged-KV pool size").set(self.cache.pool_bytes())
@@ -281,7 +321,13 @@ class ServingEngine:
         greedy; otherwise temperature+top-p sampling on the request's own
         seeded RNG lane. Raises :class:`ServingAdmissionPausedError`
         while the guardian has admission paused — failing fast beats
-        joining a queue that cannot drain."""
+        joining a queue that cannot drain.
+
+        Host work only: nothing is sent to the device and nothing read
+        back (the RNG lane is computed on the host,
+        ``sampling.make_rng_lane``). The device's queue is a whole step
+        deep when a caller submits between steps, and a read-back here
+        would wait for all of it."""
         if self._admission_pause_rule is not None:
             self.registry.counter(
                 "serving_requests_rejected_total",
@@ -320,41 +366,72 @@ class ServingEngine:
     # -------------------------------------------------------------- step
     def step(self) -> bool:
         """One scheduler iteration: admission, one prefill chunk per
-        still-prefilling slot, one decode dispatch. Returns True when
-        any work was done."""
-        with trace_span("serving_step"):
+        still-prefilling slot, one decode dispatch, and the landing of
+        the decode dispatch of the step BEFORE. Returns True when any
+        work was done, so also while tokens are in flight.
+
+        The step is scheduled from counts and dispatched before the
+        tokens of the step before it are read back: those land last
+        (``_land``), while the device runs what this call queued. A
+        token therefore reaches ``output_tokens`` one ``step()`` after
+        its dispatch, and a request finishes (and vacates its slot)
+        when its last token lands: it is never in neither
+        ``scheduler.slots`` nor ``collect()``'s output. A request whose
+        every token has been dispatched is not decoded again while the
+        last is in flight (one slot-step a request). Under speculation
+        a step lands its own tokens, and before an eviction the
+        scheduler lands what is in flight (module docstring)."""
+        with trace_span("serving_step") as span:
+            # slot -> what it did this step (("prefill"|"recompute",
+            # n_valid), or ("decode", delivered) for the dispatch that
+            # LANDED in it): the slot-step ledger's input; collected
+            # DURING the step because finished requests vacate their
+            # slots before the step ends
+            self._acts = {}
             with trace_span("serving_schedule"):
                 plan = self.scheduler.schedule()
                 progress = self._drain_failed()
-            # acts: slot -> what it did this step (("prefill"|"recompute",
-            # n_valid) or ("decode", delivered)) — the slot-step ledger's
-            # input; collected DURING the step because finished requests
-            # vacate their slots before the step ends
-            acts = {}
             # COW forks first: a forked request may decode THIS step, and
             # its table already names the fork target — the copy must
             # land before any dispatch reads or writes it
             for req in plan.cow_forks:
                 progress |= self._run_cow_fork(req)
             for req in plan.prefill:
-                progress |= self._run_prefill(req, acts)
+                progress |= self._run_prefill(req)
+            ahead = bool(plan.decode_slots) and self._in_flight is not None
             if plan.decode_slots:
-                self._run_decode(plan.decode_slots, acts)
+                self._run_decode(plan.decode_slots)
                 progress = True
+            else:
+                progress |= self._land()
+            span.set(ahead=int(ahead))
+            if ahead:
+                self.registry.counter(
+                    "serving_steps_ahead_total",
+                    "steps whose decode was dispatched before the tokens "
+                    "of the step before had landed").inc()
+            if plan.awaiting:
+                self.registry.counter(
+                    "serving_slot_steps_awaiting_landing_total",
+                    "slot-steps held by a request whose last token was "
+                    "in flight: not decoded again, not yet "
+                    "vacated").inc(plan.awaiting)
             with trace_span("serving_publish"):
-                self._publish(acts, progress)
+                self._publish(progress)
         return progress
 
-    def _publish(self, acts, progress):
+    def _publish(self, progress):
         """The step's book-keeping once its dispatches are out: gauges,
         the observatory's slot-step ledger, the SLO and guardian ticks,
-        the memory tick (the ``serving_publish`` span)."""
+        the memory tick (the ``serving_publish`` span). Host numbers
+        only: a device array read here would wait for the step that was
+        just queued."""
         self._publish_gauges()
         if self.observatory is not None:
             occupied = {i for i, r in enumerate(self.scheduler.slots)
                         if r is not None}
             self.observatory.end_step(
-                acts, occupied,
+                self._acts, occupied,
                 queue_depth=self.scheduler.num_waiting,
                 active=self.scheduler.num_active,
                 kv_occupancy=self.cache.allocator.occupancy(),
@@ -410,6 +487,10 @@ class ServingEngine:
         if self.speculative is None or self._spec_disabled_rule is not None:
             return
         self._spec_disabled_rule = str(rule)
+        # the plain program emits ``decode_steps`` rows a dispatch, not
+        # the verify width: budgets, and the counts that now advance at
+        # dispatch, follow what it will run
+        self.scheduler.decode_steps = self.runner.decode_steps
         self.registry.gauge(
             "serving_speculation_disabled",
             "1 after the guardian disabled speculative decoding").set(1)
@@ -423,6 +504,7 @@ class ServingEngine:
         structured last rites instead of a silent livelock death. Slotted
         requests release their KV blocks through the normal finish path,
         so the pool is clean for a post-mortem restart."""
+        self._land("drain")
         count = 0
         waiting, self.scheduler.waiting = \
             list(self.scheduler.waiting), type(self.scheduler.waiting)()
@@ -494,10 +576,13 @@ class ServingEngine:
         if pc is None:
             return
         bs = self.cache.block_size
-        n_full = min(req.cached_len // bs, len(req.block_table))
+        full = req.full_prompt
+        # a block is registered by its tokens: of the positions written
+        # (or dispatched), those whose token has LANDED
+        n_full = min(min(req.cached_len, len(full) - 1) // bs,
+                     len(req.block_table))
         if req.indexed_blocks >= n_full:
             return
-        full = req.full_prompt
         while req.indexed_blocks < n_full:
             b = req.indexed_blocks
             req.prefix_digest = pc.insert(
@@ -505,7 +590,7 @@ class ServingEngine:
                 req.block_table[b])
             req.indexed_blocks += 1
 
-    def _run_prefill(self, req, acts=None) -> bool:
+    def _run_prefill(self, req) -> bool:
         slot, start = req.slot, req.cached_len
         t0 = time.perf_counter_ns()
         with trace_span("serving_prefill", req=req.req_id, start=start,
@@ -527,14 +612,13 @@ class ServingEngine:
                 "serving_recompute_tokens_total",
                 "tokens re-prefilled because a preemption evicted their "
                 "KV").inc(n_recompute)
-        if acts is not None:
-            # cached_prefill: this chunk exists because the cache DIDN'T
-            # cover the whole prompt — the tail of a prefix-hit
-            # admission. Still useful work (recompute outranks it: a
-            # re-prefilled position is waste whatever got it admitted)
-            acts[slot] = ("recompute" if n_recompute
-                          else ("cached_prefill" if req.prefix_hit_blocks
-                                else "prefill"), n_valid)
+        # cached_prefill: this chunk exists because the cache DIDN'T
+        # cover the whole prompt — the tail of a prefix-hit admission.
+        # Still useful work (recompute outranks it: a re-prefilled
+        # position is waste whatever got it admitted)
+        self._acts[slot] = ("recompute" if n_recompute
+                            else ("cached_prefill" if req.prefix_hit_blocks
+                                  else "prefill"), n_valid)
         self._index_blocks(req)
         if self.observatory is not None:
             self.observatory.record_prefill(req, slot, start, n_valid,
@@ -543,9 +627,14 @@ class ServingEngine:
             req.state = RequestState.RUNNING
         return True
 
-    def _decode_inputs(self, decode_slots):
+    def _decode_inputs(self, decode_slots, prev):
         """The decode program's host-built arguments: the block tables
-        and the per-slot vectors, zero (inactive) off ``decode_slots``."""
+        and the per-slot vectors, zero (inactive) off ``decode_slots``.
+        ``prev`` is the dispatch in flight (or None): a slot it also
+        decoded takes its input from that dispatch's tokens, still on
+        the device (``prev_row``: the row its budget ended on); a slot
+        that has just finished prefill or was re-admitted takes the
+        host's ``next_input`` (``prev_row`` -1)."""
         B = self.max_batch
         MB = self.max_blocks_per_seq
         slots = self.scheduler.slots
@@ -559,16 +648,20 @@ class ServingEngine:
         top_p = np.ones((B,), np.float32)
         lanes = np.zeros((B, 2), np.uint32)
         budget = np.zeros((B,), np.int32)
+        prev_row = np.full((B,), -1, np.int32)
         for i in decode_slots:
             r = slots[i]
             pos[i] = r.cached_len
             active[i] = True
-            tok[i] = r.next_input
+            if prev is not None and prev.reqs.get(i) is r:
+                prev_row[i] = prev.budget[i] - 1
+            else:
+                tok[i] = r.next_input
             temp[i] = r.temperature
             top_p[i] = r.top_p
             lanes[i] = self._lanes[r.req_id]
             budget[i] = r.step_budget
-        return bt, pos, active, tok, temp, top_p, lanes, budget
+        return bt, pos, active, tok, temp, top_p, lanes, budget, prev_row
 
     def _paged_block_counts(self, pos, active):
         """KV blocks the active slots hold tokens in, and blocks the
@@ -594,11 +687,18 @@ class ServingEngine:
             "over decode dispatches").inc(visited)
         return needed, visited
 
-    def _run_decode(self, decode_slots, acts=None):
+    def _run_decode(self, decode_slots):
+        """Dispatch this step's decode program, then land the tokens of
+        the step before, which the device finished before it began this
+        step's prefill chunks: the ``serving_decode_wait`` inside this
+        span waits for THAT dispatch, never for the one just sent.
+        Under speculation ``accepted`` decides the next positions, so
+        the step lands its own tokens too, as it always did."""
         with trace_span("serving_decode", batch=len(decode_slots)) as span:
+            prev = self._in_flight
             with trace_span("serving_decode_inputs"):
-                bt, pos, active, tok, temp, top_p, lanes, budget = \
-                    self._decode_inputs(decode_slots)
+                (bt, pos, active, tok, temp, top_p, lanes, budget,
+                 prev_row) = self._decode_inputs(decode_slots, prev)
             needed, visited = self._paged_block_counts(pos, active)
             span.set(blocks_needed=needed, blocks_visited=visited)
             spec = (self.speculative
@@ -628,52 +728,89 @@ class ServingEngine:
                     self.pools, toks = self._decode_fn(
                         self.engine.params, self.engine.quant_scales,
                         self.pools, bt, pos, active, tok, temp, top_p,
-                        lanes, budget)
-            with trace_span("serving_decode_wait"):
-                if spec is not None:
-                    accepted = np.asarray(accepted)    # [B]
-                toks = np.asarray(toks)        # [K, B]; the one host sync
-            t1 = time.perf_counter_ns()
-            with trace_span("serving_deliver"):
-                self._deliver_decoded(decode_slots, toks, budget, accepted,
-                                      t0, t1, acts)
+                        lanes, budget,
+                        self._no_prev if prev is None else prev.toks,
+                        prev_row)
+                # the copy to the host starts when the program ends, not
+                # when the landing asks for it a step later
+                jax.copy_to_host_async((toks, accepted))
+            # counts advance at dispatch: the next step is scheduled from
+            # them before these tokens are read
+            reqs = {i: self.scheduler.slots[i] for i in decode_slots}
+            for i, r in reqs.items():
+                rows = int(budget[i])
+                r.cached_len += rows
+                r.in_flight += rows
+            self._land()                    # the step before's tokens
+            self._in_flight = _Dispatch(reqs, budget, toks, accepted, t0)
+            if spec is not None:
+                self._land("speculation")
 
-    def _deliver_decoded(self, decode_slots, toks, budget, accepted, t0, t1,
-                         acts):
+    def _land(self, reason=None) -> bool:
+        """Read back the decode dispatch in flight and hand its tokens
+        to its requests: ``output_tokens``, ``next_input``, the prefix
+        index, the EOS test and ``finish``, TTFT and the latency
+        histograms, the observatory's records all happen here. False
+        when nothing was in flight. ``reason`` says why a dispatch
+        landed before the next step was scheduled (``speculation``,
+        ``preemption``: the scheduler's ``land_first``, ``drain``);
+        None is the run-ahead order, the landing that follows the next
+        step's dispatch. This is the step's one device-to-host read."""
+        flight, self._in_flight = self._in_flight, None
+        if flight is None:
+            return False
+        with trace_span("serving_decode_wait"):
+            accepted = (None if flight.accepted is None
+                        else np.asarray(flight.accepted))    # [B]
+            toks = np.asarray(flight.toks)     # [K, B]; the one host sync
+        t1 = time.perf_counter_ns()
+        with trace_span("serving_deliver"):
+            self._deliver_decoded(flight, toks, accepted, t1)
+        if reason is not None:
+            self.registry.counter(
+                "serving_steps_landed_first_total",
+                "decode dispatches landed before the next step was "
+                "scheduled, by what made the step need its tokens",
+                labels={"reason": reason}).inc()
+        return True
+
+    def _deliver_decoded(self, flight, toks, accepted, t1):
         """Hand one decode dispatch's tokens to its requests;
         ``accepted`` is the verify program's per-slot count under
         speculation, else None."""
-        slots = self.scheduler.slots
         spec = self.speculative if accepted is not None else None
         now = time.perf_counter()
         self.registry.counter("serving_decode_steps_total",
                               "compiled decode dispatches executed").inc()
+        # a request that the landing before this one finished on an EOS
+        # had been dispatched once more by then: those rows are dropped
+        # (their KV write lay in the request's own block)
+        live = {i: r for i, r in flight.reqs.items()
+                if r.state is not RequestState.FINISHED}
+        overrun = sum(int(flight.budget[i]) for i in flight.reqs
+                      if i not in live)
         if self.observatory is not None:
             # before delivery, so each timeline's decode_begin precedes
             # its first_token
             self.observatory.record_decode(
-                {i: (slots[i], int(budget[i])) for i in decode_slots},
-                t0, t1)
-        if spec is None:
-            for i in decode_slots:
-                delivered = self._deliver(slots[i],
-                                          toks[:budget[i], i].tolist(),
-                                          now)
-                if acts is not None:
-                    acts[i] = ("decode", delivered)
-            return
+                {i: (r, int(flight.budget[i])) for i, r in live.items()},
+                flight.t0, t1)
         # speculative delivery: per slot, min(accepted+1, budget) tokens
         # are real (accepted drafts + the target's bonus token); the
-        # rest ROLL BACK by simply not advancing cached_len — the stale
-        # pool bytes past the accepted point are masked by past_lens and
-        # overwritten by the next dispatch. drafted_rejected books the
-        # rejection cost into the slot-step ledger.
+        # rest ROLL BACK by not advancing cached_len past them — the
+        # stale pool bytes past the accepted point are masked by
+        # past_lens and overwritten by the next dispatch.
+        # drafted_rejected books the rejection cost into the slot-step
+        # ledger.
         drafted_t = accepted_t = rejected_t = 0
-        for i in decode_slots:
-            r = slots[i]
-            b = int(budget[i])
-            cap = min(int(accepted[i]) + 1, b)
-            delivered = self._deliver(r, toks[:cap, i].tolist(), now)
+        for i, r in live.items():
+            b = int(flight.budget[i])
+            cap = b if spec is None else min(int(accepted[i]) + 1, b)
+            delivered = self._deliver(r, toks[:cap, i].tolist(), b, now)
+            overrun += cap - delivered
+            if spec is None:
+                self._acts[i] = ("decode", delivered)
+                continue
             considered = min(spec.k, max(b - 1, 0))
             rejected = considered - (cap - 1)
             r.spec_drafted += considered
@@ -681,8 +818,12 @@ class ServingEngine:
             drafted_t += considered
             accepted_t += cap - 1
             rejected_t += rejected
-            if acts is not None:
-                acts[i] = ("decode", delivered, rejected)
+            self._acts[i] = ("decode", delivered, rejected)
+        if overrun:
+            self.registry.counter(
+                "serving_decode_overrun_tokens_total",
+                "decode rows spent past an EOS: sampled after the token "
+                "that ended their request, dropped at landing").inc(overrun)
         if drafted_t:
             self.registry.counter(
                 "serving_spec_drafted_total",
@@ -708,28 +849,35 @@ class ServingEngine:
                 "decoding").set(
                     accepted_c / drafted_c if drafted_c else 0.0)
 
-    def _deliver(self, req, tokens, now):
-        """Hand a dispatch's tokens to the request (one token in
+    def _deliver(self, req, tokens, budget, now):
+        """Land one dispatch's tokens on the request (one token in
         single-step mode, up to ``decode_steps`` otherwise; anything the
-        request samples past eos/max_tokens is discarded). Returns the
-        KEPT token count — the slot-step ledger's ``decode_useful``."""
+        request samples past eos/max_tokens is discarded). ``budget`` is
+        what the dispatch advanced ``cached_len`` and ``in_flight`` by;
+        the rows that do not land (rejected drafts, rows past an EOS)
+        roll back as a position edit. Returns the KEPT token count —
+        the slot-step ledger's ``decode_useful``."""
         slot = req.slot
         prev = req.last_token_t if req.first_token_t is not None else None
+        # KV positions whose tokens had landed before this dispatch (a
+        # later dispatch may be in flight already)
+        base = req.cached_len - req.in_flight
         delivered = 0
         reason = None
         for token in tokens:
             delivered += 1
             req.output_tokens.append(token)
-            req.cached_len += 1
             req.next_input = token
             if req.eos_token_id is not None and token == req.eos_token_id:
                 reason = "eos"
             elif len(req.output_tokens) >= req.max_new_tokens:
                 reason = "max_tokens"
-            elif req.cached_len >= self.max_model_len:
+            elif base + delivered >= self.max_model_len:
                 reason = "model_len"
             if reason is not None:
                 break
+        req.in_flight -= budget
+        req.cached_len -= budget - delivered
         if not delivered:
             return 0
         # register newly-full blocks BEFORE any finish releases the
@@ -829,7 +977,10 @@ class ServingEngine:
 
     # ----------------------------------------------------------- collect
     def collect(self) -> List[RequestOutput]:
-        """Drain finished requests (in finish order)."""
+        """Drain finished requests (in finish order). A request finishes
+        when its last token LANDS, one ``step()`` after the step that
+        dispatched it; until then it is in ``scheduler.slots``, so a
+        request is always in one of the two."""
         out = []
         for req in self._finished:
             self._lanes.pop(req.req_id, None)
@@ -848,7 +999,13 @@ class ServingEngine:
     def serve_forever(self, request_source=None, max_steps=None):
         """Drive the loop until drained: optionally pull submit-kwargs
         dicts from ``request_source`` (an iterable) to keep the queue
-        primed, step until no work remains, return collected outputs."""
+        primed, step until no work remains, return collected outputs.
+
+        A request holds its slot until its last token has landed, so
+        "no work remains" means nothing is in flight either. A loop cut
+        by ``max_steps`` lands what its last step left in flight before
+        it returns: the outputs and ``scheduler.slots`` then hold every
+        token that was dispatched."""
         source = iter(request_source) if request_source is not None else None
         outputs = []
         steps = 0
@@ -891,6 +1048,8 @@ class ServingEngine:
             steps += 1
             if max_steps is not None and steps >= max_steps:
                 break
+        if self._land("drain"):
+            outputs.extend(self.collect())
         return outputs
 
     # ------------------------------------------- HBM residency observatory
@@ -976,6 +1135,7 @@ class ServingEngine:
                 "state": r.state.value,
                 "prompt_len": len(r.prompt),
                 "generated": len(r.output_tokens),
+                "in_flight": r.in_flight,
                 "cached_len": r.cached_len,
                 "blocks": len(r.block_table),
                 "step_budget": r.step_budget,
@@ -1075,13 +1235,13 @@ class ServingEngine:
         iterations — the serving analog of ``engine.profile_step``.
 
         Runs a bounded ``jax.profiler`` capture around N annotated
-        ``step()`` calls (blocking on the KV pools inside each
-        annotation so device work lands in-window), post-processes the
+        ``step()`` calls (landing each step's tokens and blocking on the
+        KV pools inside its annotation, so that a step's device work and
+        its delivery lie in its own mark), post-processes the
         trace with the xplane parser and writes the schema-pinned
         report (default ``telemetry/STEP_ANATOMY.serving.json``).
         Inert (``{"enabled": False}``) when the profiler is
         unavailable or ``DS_TELEMETRY_ANATOMY=0``."""
-        import jax
         from deepspeed_tpu.telemetry import step_anatomy
         from deepspeed_tpu.telemetry.ledger import (
             profiler_available, _start_trace, _stop_trace)
@@ -1107,6 +1267,7 @@ class ServingEngine:
             for i in range(int(steps)):
                 with TraceAnnotation(step_anatomy.STEP_MARK, step=i):
                     self.step()
+                    self._land("drain")
                     jax.block_until_ready(self.pools)
         finally:
             try:
@@ -1134,7 +1295,9 @@ class ServingEngine:
         ``close()`` is what guarantees the last incident reaches
         ``SERVING_HEALTH.json``. The obs-server scrape route is
         unregistered first — its report provider points at this
-        object."""
+        object. Tokens still in flight land first, so the snapshot and
+        a last ``collect()`` hold them."""
+        self._land("drain")
         if self._obs_server is not None:
             self._obs_server.unregister("serving")
             self._obs_server = None

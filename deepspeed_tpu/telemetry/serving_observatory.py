@@ -165,7 +165,15 @@ class SlotStepLedger:
         ``("decode", delivered, drafted_rejected)``;
         ``occupied`` is the set of slots still holding a request (a slot
         neither acted nor occupied is idle; occupied-but-unscheduled is
-        frozen — an invariant breach worth seeing, not hiding)."""
+        frozen — an invariant breach worth seeing, not hiding).
+
+        The server runs one step ahead of the device, so a decode is
+        booked by the step in which its tokens LAND, the step after its
+        dispatch: a request's first decode step books ``frozen`` (it
+        dispatched, nothing landed) and the step that only lands its
+        last token books ``decode_useful``. The sums are those of the
+        order that lands every step at once, plus the one slot-step a
+        request holds while its last token is in flight."""
         K = self.K
         u = self.units
         for i in range(self.max_batch):

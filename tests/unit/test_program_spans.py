@@ -173,20 +173,24 @@ def test_serving_step_spans_nest_and_count(fresh_tracer, tmp_path,
 
     lengths = []            # per decode dispatch: the decoding slots' lengths
     run_decode = srv._run_decode
+    generated = srv.registry.counter("serving_tokens_generated_total")
 
-    def recording(decode_slots, acts=None):
+    def recording(decode_slots):
         lengths.append([srv.scheduler.slots[i].cached_len
                         for i in decode_slots])
-        return run_decode(decode_slots, acts)
+        return run_decode(decode_slots)
 
     srv._run_decode = recording
-    per_step = []           # (chunks, decode dispatches) each step added
+    # what each step added: chunks, decode dispatches, landings, tokens
+    per_step = []
     with profiler_session(tmp_path):
         for _ in range(200):
-            c0, d0 = chunks.value, decodes.value
+            c0, d0, g0, n0 = (chunks.value, decodes.value, generated.value,
+                              len(lengths))
             srv.step()
-            per_step.append((int(chunks.value - c0),
-                             int(decodes.value - d0)))
+            per_step.append((int(chunks.value - c0), len(lengths) - n0,
+                             int(decodes.value - d0),
+                             int(generated.value - g0)))
             if not srv.scheduler.num_active and not srv.scheduler.num_waiting:
                 break
     assert len(srv.collect()) == 4
@@ -197,23 +201,39 @@ def test_serving_step_spans_nest_and_count(fresh_tracer, tmp_path,
     assert len(steps) == len(per_step)
     recount = iter(lengths)
     total_needed = total_visited = 0
-    for step, (n_chunks, n_decodes) in zip(steps, per_step):
+    in_flight = 0           # slots of the dispatch that has not landed
+    for step, (n_chunks, n_dispatches, n_landings, n_tokens) in zip(
+            steps, per_step):
         inside = _children(events, step)
         names = [e["name"] for e in inside]
         assert names.count("serving_schedule") == 1
         assert names.count("serving_publish") == 1
         assert names.count("serving_prefill") == n_chunks
-        assert names.count("serving_decode") == n_decodes
-        assert names.count("serving_decode_wait") == n_decodes
+        assert names.count("serving_decode") == n_dispatches
+        assert names.count("serving_decode_wait") == n_landings
+        assert names.count("serving_deliver") == n_landings
         assert names[0] == "serving_schedule"
         assert names[-1] == "serving_publish"
+        # a step runs ahead where it dispatched a decode with the tokens of
+        # the step before still in flight; under speculation it never does
+        assert step["args"]["ahead"] == int(
+            bool(n_dispatches) and bool(in_flight) and not speculative)
         for e in inside:
             if e["name"] == "serving_prefill":
                 assert {"req", "start", "tokens"} <= set(e["args"])
         for decode in (e for e in inside if e["name"] == "serving_decode"):
-            assert [e["name"] for e in _children(events, decode)] == [
-                "serving_decode_inputs", "serving_decode_dispatch",
-                "serving_decode_wait", "serving_deliver"]
+            # the step's own dispatch closes BEFORE the wait opens, and
+            # that wait is for the dispatch of the step before (for this
+            # step's own under speculation: there the two are one)
+            inner = _children(events, decode)
+            landed_here = speculative or in_flight
+            assert [e["name"] for e in inner] == [
+                "serving_decode_inputs", "serving_decode_dispatch"] + (
+                ["serving_decode_wait", "serving_deliver"]
+                if landed_here else [])
+            if landed_here:
+                dispatch, wait = inner[1], inner[2]
+                assert dispatch["ts"] + dispatch["dur"] <= wait["ts"]
             lens = np.asarray(next(recount))
             args = decode["args"]
             assert args["batch"] == len(lens)
@@ -221,7 +241,20 @@ def test_serving_step_spans_nest_and_count(fresh_tracer, tmp_path,
             assert args["blocks_visited"] == B * math.ceil(lens.max() / BS)
             total_needed += args["blocks_needed"]
             total_visited += args["blocks_visited"]
-    assert sum(n for n, _ in per_step) > 4      # the prompts took chunks
+            if speculative:
+                continue
+            # the wait's tokens are step k-1's: one for each slot that
+            # step dispatched (greedy, no EOS), none of this step's own
+            assert n_tokens == in_flight
+            in_flight = args["batch"]
+        if not n_dispatches and not speculative:
+            # nothing to dispatch (every request's last token in flight):
+            # the step lands what is left, directly under itself
+            assert n_landings == int(bool(in_flight))
+            assert n_tokens == in_flight
+            in_flight = 0
+    assert not in_flight
+    assert sum(n for n, *_ in per_step) > 4     # the prompts took chunks
     assert (needed_c.value, visited_c.value) == (total_needed, total_visited)
     assert 0 < total_needed <= total_visited
 
