@@ -471,6 +471,50 @@ def _kernel_cases():
                      q[:, :, None], k[:, :, None], v[:, :, None],
                      *a)[:, :, 0])(*args))]
 
+    def latent_decode():
+        """The same kernel over one latent a token (128 heads' dense
+        queries, rows of 576 in 640 lanes whose first 512 are the
+        values), ragged lengths, against the jnp walk at one query a
+        slot; both hand the probabilities to the MXU in bfloat16."""
+        from deepspeed_tpu.serving import paged_attention as pa
+        H, Wq, V, W, BS, N = 128, 576, 512, 640, 16, 513
+        lens = np.array([0, 1, 16, 17, 150, 333, 2000, 0], np.int32)
+        bt = np.zeros((len(lens), 128), np.int32)
+        blocks = iter(np.random.default_rng(0).permutation(np.arange(1, N)))
+        for b, n in enumerate(lens):
+            for i in range(-(-int(n) // BS)):
+                bt[b, i] = next(blocks)
+        lanes = (jnp.arange(W) < Wq).astype(jnp.bfloat16)
+        pool = rnd((2 * N, BS, W), 0, jnp.bfloat16) * lanes
+        q = rnd((len(lens), H, Wq), 1, jnp.bfloat16, 0.2)
+        row = rnd((len(lens), Wq), 2, jnp.bfloat16)
+        args = (N, pool, None, jnp.asarray(bt), jnp.asarray(lens))
+        return [("out", pa._decode_kernel_call(q, row, None, *args,
+                                               Wq ** -0.5, v_width=V),
+                 jax.jit(lambda q, row, first, pool, bt, lens:
+                         pa.paged_chunk_attention(
+                             q[:, :, None], row[:, None], None, first, pool,
+                             None, bt, lens, v_width=V)[:, :, 0])(
+                                 q, row, N, pool, *args[3:]))]
+
+    def grouped_experts():
+        """The held experts' grouped product (megablox ``gmm`` at the
+        served tiles): 16 experts of the published width, a decode step's
+        handful of rows an expert and an empty one, against a loop over
+        the experts."""
+        from deepspeed_tpu.moe.held_experts import grouped_matmul
+        G, K, M = 16, 7168, 4096
+        sizes = np.array([4, 0, 9, 1, 3, 7, 2, 5, 4, 4, 130, 6, 3, 2, 8, 4],
+                         np.int32)
+        rows = rnd((1024, K), 0, jnp.bfloat16)
+        w = rnd((G, K, M), 1, jnp.bfloat16, 0.02)
+        got = grouped_matmul(rows, w, jnp.asarray(sizes), jnp.float32)
+        ends = np.cumsum(sizes)
+        want = jnp.concatenate([
+            jnp.dot(rows[e - n:e], w[g], preferred_element_type=jnp.float32)
+            for g, (e, n) in enumerate(zip(ends, sizes)) if n])
+        return [("out", got[:int(ends[-1])], want)]
+
     def layer_norm():
         from deepspeed_tpu.ops.transformer.fused import fused_layer_norm
         x, g, b = rnd((8192, 1024), 0), rnd((1024,), 1) + 1.0, rnd((1024,), 2)
@@ -571,6 +615,10 @@ def _kernel_cases():
         ("decode attention int8 cache", "bfloat16", decode(True)),
         ("paged decode walk, 25 heads in 1,664 lanes", "float32",
          paged_decode),
+        ("paged decode walk, one latent a token (128 heads, 640 lanes)",
+         "bfloat16", latent_decode),
+        ("grouped expert product, 16 experts of 7,168 x 4,096", "bfloat16",
+         grouped_experts),
         ("fused layer norm fwd+bwd", "float32", layer_norm),
         ("fused bias-gelu fwd+bwd", "float32", bias_gelu),
         ("fused softmax", "float32", softmax),

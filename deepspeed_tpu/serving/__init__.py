@@ -9,7 +9,8 @@ from deepspeed_tpu.serving.paged_attention import (  # noqa: F401
 from deepspeed_tpu.serving.prefill import ChunkedPrefill  # noqa: F401
 from deepspeed_tpu.serving.router import (RouteDecision,  # noqa: F401
                                           ServingRouter)
-from deepspeed_tpu.serving.runner import PagedGPT2Runner  # noqa: F401
+from deepspeed_tpu.serving.runner import (PagedGPT2Runner,  # noqa: F401
+                                          PagedRunner)
 from deepspeed_tpu.serving.sampling import (sample_tokens,  # noqa: F401
                                             top_p_filter)
 from deepspeed_tpu.serving.scheduler import (  # noqa: F401

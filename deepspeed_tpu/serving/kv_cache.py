@@ -33,7 +33,8 @@ Split of responsibilities:
   ``reclaim()`` — which the scheduler calls before any preemption
   fires.
 * ``PagedKVCache`` — owns the device pools (K and V as token rows, and
-  for the int8 KV layout the per-row fp32 scales) plus the one write
+  for the int8 KV layout the per-row fp32 scales; for latent attention
+  ONE pool ``kv`` whose row is the token's latent) plus the one write
   the runner traces into a compiled step: ``write_layers`` (one scatter
   per pool for a step's tokens in the layers that ran). The reads are
   serving/paged_attention.py's: they walk the blocks that hold tokens
@@ -388,7 +389,15 @@ class PagedKVCache:
       before any product;
     * ``k_scale``/``v_scale`` (int8 only): ``[n_layer*num_blocks,
       block_size, n_head rounded up to 128]`` fp32 per-row absmax
-      scales (``scale_width``), padded the same way.
+      scales (``scale_width``), padded the same way;
+    * with ``latent_width`` (latent attention, MLA): ONE pool ``kv`` of
+      the same shape and no ``v``: a token's row is its normed latent
+      and its rotated shared key (``latent_width`` values, 576 -> 640
+      lanes), which every head reads as its key and, in its first
+      lanes, as its value. ``n_head``/``head_dim`` then describe the
+      queries only. Everything that walks the pools (``pool_bytes``,
+      ``write_layers``, the block copy, the prefix cache's salt)
+      follows the SET of pools and assumes no name.
 
     Why this shape: on the TPU an array lives in tiles of 8 sublanes by
     128 lanes (16 rows of bf16), and with ``block_size`` rows of whole
@@ -412,11 +421,18 @@ class PagedKVCache:
     """
 
     def __init__(self, n_layer, n_head, head_dim, block_size, num_blocks,
-                 dtype=jnp.float32, int8_kv=False):
+                 dtype=jnp.float32, int8_kv=False, latent_width=0):
+        if latent_width and int8_kv:
+            raise NotImplementedError(
+                "int8 latent pools are not served: a latent row has no "
+                "heads to scale by (kv_cache_dtype 'int8' is the K/V "
+                "layout's)")
         self.n_layer = n_layer
         self.n_head = n_head
         self.head_dim = head_dim
-        self.row_width = -(-n_head * head_dim // _LANES) * _LANES
+        self.latent_width = int(latent_width)
+        self.row_width = -(-(self.latent_width or n_head * head_dim)
+                           // _LANES) * _LANES
         self.scale_width = -(-n_head // _LANES) * _LANES
         self.block_size = int(block_size)
         self.num_blocks = int(num_blocks)
@@ -431,17 +447,20 @@ class PagedKVCache:
     def attach_prefix_cache(self, capacity_blocks=0):
         """Arm shared-prefix reuse: the salt folds in everything that
         makes two bit-identical token prefixes produce different block
-        BYTES (KV dtype, block size), so a cache can never serve a block
-        written under a different layout."""
+        BYTES (KV dtype, the set of pools, block size), so a cache can
+        never serve a block written under a different layout."""
         self.prefix_cache = PrefixCache(
             self.allocator, self.block_size,
             capacity_blocks=capacity_blocks,
-            salt=jnp.dtype(self.dtype).name)
+            salt="/".join([jnp.dtype(self.dtype).name]
+                          + sorted(self._pool_shapes())))
         return self.prefix_cache
 
     # -------------------------------------------------- pool construction
     def _pool_shapes(self):
         rows = (self.n_layer * self.num_blocks, self.block_size)
+        if self.latent_width:
+            return {"kv": (rows + (self.row_width,), self.dtype)}
         shapes = {"k": (rows + (self.row_width,), self.dtype),
                   "v": (rows + (self.row_width,), self.dtype)}
         if self.int8_kv:
@@ -480,23 +499,24 @@ class PagedKVCache:
 
     # ------------------------------------------------------- traced write
     @jax.named_scope("kv_write")
-    def write_layers(self, pools, k_new, v_new, block_ids, offsets):
-        """Write one step's K/V for the first ``n`` layers in ONE scatter
+    def write_layers(self, pools, new, block_ids, offsets):
+        """Write one step's rows for the first ``n`` layers in ONE scatter
         per pool.
 
-        k_new/v_new: ``[n, B, H, D]`` (decode: a token per slot; prefill
-        or verify: the ``B`` positions of a chunk); block_ids/offsets:
-        ``[B]`` int32 (the scheduler routes inactive slots / pad
-        positions to the null block 0). The runner defers every layer's
-        write to one call at the end of the step (``n`` = the layers it
-        ran: all of them, or the self-draft's prefix, whose K/V are
-        bit-identical to the target's for those layers).
+        new: pool name -> ``[n, B, ...]`` (``k``/``v``: ``[n, B, H, D]``;
+        the latent pool ``kv``: ``[n, B, latent_width]``; decode: a token
+        per slot; prefill or verify: the ``B`` positions of a chunk);
+        block_ids/offsets: ``[B]`` int32 (the scheduler routes inactive
+        slots / pad positions to the null block 0). The runner defers
+        every layer's write to one call at the end of the step (``n`` =
+        the layers it ran: all of them, or the self-draft's prefix, whose
+        K/V are bit-identical to the target's for those layers).
 
         The scatter indexes the pool's two leading dimensions only (the
         folded row ``layer*N + block`` and the offset) and the update is
         ``[n*B, W]``, untransposed: that is what lets the TPU compiler
         update the donated pool in place (class docstring)."""
-        n, B = k_new.shape[:2]
+        n, B = next(iter(new.values())).shape[:2]
         rows = self.layer_rows(block_ids, n_layers=n).reshape(-1)
         offs = jnp.broadcast_to(offsets, (n, B)).reshape(-1)
 
@@ -505,13 +525,13 @@ class PagedKVCache:
             return jnp.pad(x, ((0, 0), (0, width - x.shape[1])))
 
         out = dict(pools)
-        for name, new in (("k", k_new), ("v", v_new)):
+        for name, rows_new in new.items():
             if self.int8_kv:
-                new, scale = quantize_kv(new)           # scales [n, B, H]
+                rows_new, scale = quantize_kv(rows_new)   # scales [n, B, H]
                 out[name + "_scale"] = pools[name + "_scale"].at[
                     rows, offs].set(token_rows(scale, self.scale_width))
             out[name] = pools[name].at[rows, offs].set(token_rows(
-                new.astype(pools[name].dtype), self.row_width))
+                rows_new.astype(pools[name].dtype), self.row_width))
         return out
 
     # ------------------------------------------------------- host helpers

@@ -31,6 +31,18 @@ from the platform, the pool's dtype and the mesh's size (no option):
   tests/unit/test_paged_decode_kernel.py), a prefill chunk at ``B = 1``,
   a speculative verify at ``C = K+1``.
 
+Both serve two row forms, told apart by the pools they are handed and by
+nothing else. **Heads in lanes** (``k`` and ``v`` pools): a row holds
+``H`` heads of ``D`` lanes, a query is one row, head ``h`` reads its own
+lanes. **One latent a token** (one pool, ``v_pool=None``; MLA with the
+key's up-projection absorbed into the query): a row is the token's
+latent and its rotated shared key, EVERY head's key, and in its first
+``v_width`` lanes every head's value; a slot's query is ``[H, W']`` dense
+rows. To the kernel both are the same product, a ``[Hp, W]`` query matrix
+against whole ``W``-lane rows: block-diagonal in the first form, dense in
+the second, which keeps the whole ``[H, W]`` result where the first reads
+each head's diagonal lanes.
+
 Both attend over the PAST pool only and fold the current token/chunk
 from registers (an intra-chunk causal piece merged in). That lets the
 runner defer every layer's KV write into ONE scatter per pool per step
@@ -91,21 +103,30 @@ def decode_kernel_runs(pool_dtype):
 
 def paged_decode_attention(q, k_cur, v_cur, first_block, k_pool, v_pool,
                            block_tables, past_lens, *, k_scale_pool=None,
-                           v_scale_pool=None, sm_scale=None):
+                           v_scale_pool=None, sm_scale=None, v_width=None):
     """One decode token per slot over the paged pools: the kernel where
     :func:`decode_kernel_runs`, else :func:`paged_chunk_attention` at
     ``C = 1``.
 
     q/k_cur/v_cur: ``[B, H, D]`` (the current token's K/V stay in
     registers — the pool write is deferred); the other arguments as
-    :func:`paged_chunk_attention`'s. Returns ``[B, H, D]`` fp32.
+    :func:`paged_chunk_attention`'s. Returns ``[B, H, D]`` fp32. With
+    ``v_pool=None`` (one latent a token): q ``[B, H, W']``, k_cur
+    ``[B, W']`` the token's own row, ``v_cur`` unused; returns
+    ``[B, H, v_width]``.
     """
     if decode_kernel_runs(k_pool.dtype):
         with jax.named_scope("paged_attention"):
             return _decode_kernel_call(
                 q, k_cur, v_cur, first_block, k_pool, v_pool, block_tables,
                 past_lens,
-                q.shape[-1] ** -0.5 if sm_scale is None else sm_scale)
+                q.shape[-1] ** -0.5 if sm_scale is None else sm_scale,
+                v_width=v_width)
+    if v_pool is None:
+        return paged_chunk_attention(
+            q[:, :, None], k_cur[:, None], None, first_block, k_pool, None,
+            block_tables, past_lens, sm_scale=sm_scale,
+            v_width=v_width)[:, :, 0]
     return paged_chunk_attention(
         q[:, :, None], k_cur[:, :, None], v_cur[:, :, None], first_block,
         k_pool, v_pool, block_tables, past_lens, k_scale_pool=k_scale_pool,
@@ -132,8 +153,7 @@ def _dot_f32(a, b, dims):
     return out[:n] + out[n:2 * n] + out[2 * n:]
 
 
-def _decode_kernel(first_ref, bt_ref, len_ref, q_ref, kc_ref, vc_ref,
-                   k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, parity_ref, *,
+def _decode_kernel(first_ref, bt_ref, len_ref, q_ref, kc_ref, *refs,
                    sm_scale, head_dim, group):
     """Grid program ``b`` is slot ``b``: it walks the slot's own
     ``ceil(past_len / BS)`` blocks in groups of ``group``, each block one
@@ -141,16 +161,30 @@ def _decode_kernel(first_ref, bt_ref, len_ref, q_ref, kc_ref, vc_ref,
     two VMEM buffers, the next group (or the next slot's first) in
     flight while this one is reduced.
 
-    Rows stay ``W`` lanes wide throughout. The ``H`` heads are the rows
-    of a block-diagonal query ``[Hp, W]`` (row ``h`` holds the query's
-    lanes ``h*D..(h+1)*D``, zero elsewhere), so scores are ``[Hp, T]``
-    from one matmul against the K rows, and ``P @ V`` is ``[Hp, W]``, of
-    which row ``h`` is read in head ``h``'s lanes alone. Pad lanes and
-    pad heads meet zeros of the query and are never read back."""
+    Rows stay ``W`` lanes wide throughout, and the queries are the rows
+    of a matrix ``[Hp, W]``, so scores are ``[Hp, T]`` from one matmul
+    against the K rows and ``P @ V`` is ``[Hp, W]``. With heads in lanes
+    (``head_dim`` given; refs: the current V row, the K and V pools, the
+    output, a buffer a pool) the matrix is block-diagonal (row ``h``
+    holds the query's lanes ``h*D..(h+1)*D``, zero elsewhere) and row
+    ``h`` of the result is read in head ``h``'s lanes alone; pad lanes
+    and pad heads meet zeros of the query and are never read back. With
+    one latent a token (``head_dim`` None; refs: the one pool, the
+    output, one buffer) the matrix is the slot's dense ``[Hp, W]``
+    query, the V rows ARE the K rows, and the whole result is kept (the
+    caller reads its first lanes)."""
+    latent = head_dim is None
+    if latent:
+        k_hbm, o_ref, k_buf, sems, parity_ref = refs
+        vc_ref, v_buf = kc_ref, k_buf
+        pools = ((k_hbm, k_buf),)
+    else:
+        vc_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, parity_ref = refs
+        pools = ((k_hbm, k_buf), (v_hbm, v_buf))
     b, n_slots = pl.program_id(0), pl.num_programs(0)
     BS, W = k_hbm.shape[1:]
     T = group * BS
-    Hp = -(-(W // head_dim) // 16) * 16
+    Hp = q_ref.shape[1] if latent else -(-(W // head_dim) // 16) * 16
 
     def n_blocks(slot):
         return (len_ref[slot] + BS - 1) // BS
@@ -165,8 +199,7 @@ def _decode_kernel(first_ref, bt_ref, len_ref, q_ref, kc_ref, vc_ref,
             @pl.when(g * group + i < n_blocks(slot))
             def _():
                 row = first_ref[0] + bt_ref[slot, g * group + i]
-                for s, (pool, dst) in enumerate(((k_hbm, k_buf),
-                                                 (v_hbm, v_buf))):
+                for s, (pool, dst) in enumerate(pools):
                     copy = pltpu.make_async_copy(
                         pool.at[row], dst.at[buf, pl.ds(i * BS, BS)],
                         sems.at[s, buf])
@@ -180,8 +213,8 @@ def _decode_kernel(first_ref, bt_ref, len_ref, q_ref, kc_ref, vc_ref,
         # a block the walk does not fetch keeps what its buffer held: a
         # masked score gives it weight 0, and 0 x NaN would still be NaN
         parity_ref[0] = 0
-        k_buf[...] = jnp.zeros_like(k_buf)
-        v_buf[...] = jnp.zeros_like(v_buf)
+        for _, buffer in pools:
+            buffer[...] = jnp.zeros_like(buffer)
 
     parity = parity_ref[0]
     # the slot before starts this slot's first group, unless it walked
@@ -191,11 +224,21 @@ def _decode_kernel(first_ref, bt_ref, len_ref, q_ref, kc_ref, vc_ref,
         copies(b, 0, parity, True)
 
     length, ng = len_ref[b], n_groups(b)
-    row = jax.lax.broadcasted_iota(jnp.int32, (Hp, W), 0)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (Hp, W), 1)
-    own = (lane >= row * head_dim) & (lane < (row + 1) * head_dim)
-    qf = q_ref[0].astype(jnp.float32)                       # [1, W]
-    q_heads = jnp.where(own, qf, 0.0).astype(q_ref.dtype)   # [Hp, W]
+    if latent:
+        q_heads = q_ref[0]                                  # [Hp, W]
+        qf = q_heads.astype(jnp.float32)
+
+        def own_lanes(x):
+            return x
+    else:
+        row = jax.lax.broadcasted_iota(jnp.int32, (Hp, W), 0)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (Hp, W), 1)
+        own = (lane >= row * head_dim) & (lane < (row + 1) * head_dim)
+        qf = q_ref[0].astype(jnp.float32)                       # [1, W]
+        q_heads = jnp.where(own, qf, 0.0).astype(q_ref.dtype)   # [Hp, W]
+
+        def own_lanes(x):
+            return jnp.where(own, x, 0.0)
 
     def body(g, carry):
         m, l, acc = carry
@@ -218,8 +261,14 @@ def _decode_kernel(first_ref, bt_ref, len_ref, q_ref, kc_ref, vc_ref,
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m - m_new)
         l_new = l * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc = acc * alpha + _dot_f32(p, v_buf[buf],
-                                     (((1,), (0,)), ((), ())))
+        # a slot's ``Hp`` dense query rows make the products the
+        # kernel's bound (at the chip's ridge, PERF.md): the
+        # probabilities go to the MXU in the pool's dtype, one pass,
+        # where a block-diagonal query waits on its copies either way
+        # and keeps them whole
+        acc = acc * alpha + _dot_f32(
+            p.astype(v_buf.dtype) if latent else p, v_buf[buf],
+            (((1,), (0,)), ((), ())))
         return m_new, l_new, acc
 
     m, l, acc = jax.lax.fori_loop(
@@ -228,63 +277,86 @@ def _decode_kernel(first_ref, bt_ref, len_ref, q_ref, kc_ref, vc_ref,
                       jnp.zeros((Hp, W), jnp.float32)))
     parity_ref[0] = (parity + ng) % 2
     # fold the current token (always self-visible, so l can never be 0)
-    s_cur = jnp.sum(jnp.where(own, qf * kc_ref[0].astype(jnp.float32), 0.0),
+    s_cur = jnp.sum(own_lanes(qf * kc_ref[0].astype(jnp.float32)),
                     axis=1, keepdims=True) * sm_scale       # [Hp, 1]
     m_f = jnp.maximum(m, s_cur)
     alpha = jnp.exp(m - m_f)
     p_cur = jnp.exp(s_cur - m_f)
     l = l * alpha + p_cur
     acc = acc * alpha + p_cur * vc_ref[0].astype(jnp.float32)
-    o_ref[0] = jnp.sum(jnp.where(own, acc / l, 0.0), axis=0, keepdims=True)
+    if latent:
+        o_ref[0] = acc / l
+    else:
+        o_ref[0] = jnp.sum(own_lanes(acc / l), axis=0, keepdims=True)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("sm_scale", "group", "interpret"))
+@functools.partial(jax.jit, static_argnames=("sm_scale", "group",
+                                             "interpret", "v_width"))
 def _decode_kernel_call(q, k_cur, v_cur, first_block, k_pool, v_pool,
                         block_tables, past_lens, sm_scale, group=_GROUP,
-                        interpret=False):
+                        interpret=False, v_width=None):
     """:func:`_decode_kernel` over ``B`` slots: the pools stay in HBM
     unblocked, the tables and lengths go in by scalar prefetch. So does
     the layer's first row, and the call is a jit of its own: every layer
     of a program is then one traced and lowered kernel, where 24 inlined
     ones added 12 s to each start of a server (PERF.md, PR 30)."""
-    B, H, D = q.shape
+    B, H = q.shape[:2]
     BS, W = k_pool.shape[1:]
+    latent = v_pool is None
 
-    def lane_rows(x):           # [B, H, D] -> [B, 1, W], zero pad lanes
-        return jnp.pad(x.reshape(B, 1, H * D),
-                       ((0, 0), (0, 0), (0, W - H * D)))
+    def lane_rows(x):           # [B, ...] -> [B, 1, W], zero pad lanes
+        x = x.reshape(B, 1, -1)
+        return jnp.pad(x, ((0, 0), (0, 0), (0, W - x.shape[-1])))
 
     row_spec = pl.BlockSpec((1, 1, W), lambda b, *_: (b, 0, 0))
     pool_spec = pl.BlockSpec(memory_space=pl.ANY)
+    prefetched = (jnp.asarray(first_block, jnp.int32).reshape(1),
+                  block_tables.astype(jnp.int32),
+                  past_lens.astype(jnp.int32))
+    if latent:
+        # the slot's queries as dense rows: whole sublane tiles of them
+        Hp = -(-H // 16) * 16
+        q_spec = pl.BlockSpec((1, Hp, W), lambda b, *_: (b, 0, 0))
+        rows = (jnp.pad(q, ((0, 0), (0, Hp - H), (0, W - q.shape[-1]))),
+                lane_rows(k_cur))
+        pool_args, in_specs = (k_pool,), [q_spec, row_spec, pool_spec]
+    else:
+        q_spec = row_spec
+        rows = (lane_rows(q), lane_rows(k_cur), lane_rows(v_cur))
+        pool_args = (k_pool, v_pool)
+        in_specs = [row_spec, row_spec, row_spec, pool_spec, pool_spec]
+    n = len(pool_args)
     out = pl.pallas_call(
-        functools.partial(_decode_kernel, sm_scale=sm_scale, head_dim=D,
+        functools.partial(_decode_kernel, sm_scale=sm_scale,
+                          head_dim=None if latent else q.shape[-1],
                           group=group),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(B,),
-            in_specs=[row_spec, row_spec, row_spec, pool_spec, pool_spec],
-            out_specs=row_spec,
-            scratch_shapes=[pltpu.VMEM((2, group * BS, W), k_pool.dtype),
-                            pltpu.VMEM((2, group * BS, W), v_pool.dtype),
-                            pltpu.SemaphoreType.DMA((2, 2)),
-                            pltpu.SMEM((1,), jnp.int32)]),
-        out_shape=jax.ShapeDtypeStruct((B, 1, W), jnp.float32),
+            in_specs=in_specs,
+            out_specs=q_spec,
+            scratch_shapes=[pltpu.VMEM((2, group * BS, W), pool.dtype)
+                            for pool in pool_args]
+            + [pltpu.SemaphoreType.DMA((n, 2)),
+               pltpu.SMEM((1,), jnp.int32)]),
+        out_shape=jax.ShapeDtypeStruct((B,) + q_spec.block_shape[1:],
+                                       jnp.float32),
         # slots in order on one core: each starts the next one's copies
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         name="paged_decode",
         interpret=interpret,
-    )(jnp.asarray(first_block, jnp.int32).reshape(1),
-      block_tables.astype(jnp.int32), past_lens.astype(jnp.int32),
-      lane_rows(q), lane_rows(k_cur), lane_rows(v_cur), k_pool, v_pool)
+    )(*prefetched, *rows, *pool_args)
+    if latent:
+        return out[:, :H, :v_width]
+    D = q.shape[-1]
     return out[:, 0, :H * D].reshape(B, H, D)
 
 
 @jax.named_scope("paged_attention")
 def paged_chunk_attention(q, k_chunk, v_chunk, first_block, k_pool, v_pool,
                           block_tables, past_lens, *, k_scale_pool=None,
-                          v_scale_pool=None, sm_scale=None):
+                          v_scale_pool=None, sm_scale=None, v_width=None):
     """The one jnp walk: ``C`` queries PER SLOT over each slot's PAST
     pages plus the chunk itself (registers, causal).
 
@@ -305,46 +377,108 @@ def paged_chunk_attention(q, k_chunk, v_chunk, first_block, k_pool, v_pool,
     ``[MB]``, a scalar): the compiler does not drop a unit batch
     dimension from these products by itself (runner.py says what it
     cost).
+
+    With ``v_pool=None`` the rows are one latent a token (module
+    docstring): q ``[B, H, C, W']``, k_chunk ``[B, C, W']`` the chunk's
+    own rows, every head's keys, and in their first ``v_width`` lanes its
+    values; returns ``[B, H, C, v_width]``. The products take the rows in
+    the pool's dtype and accumulate in float32, and a trip gathers
+    several blocks a slot, as many keys as the chunk has queries (``H``
+    times ``C`` query rows against 16 keys would leave seven eighths of
+    the MXU's columns empty, and every trip carries the accumulator
+    through HBM).
     """
     H, C, D = q.shape[-3:]
     BS = k_pool.shape[1]
+    latent = v_pool is None
     if sm_scale is None:
         sm_scale = D ** -0.5
-    qf = q.astype(jnp.float32)
+    qf = q if latent else q.astype(jnp.float32)
     lens = past_lens[..., None, None, None]
     n_blocks = ((jnp.max(past_lens) + BS - 1) // BS).astype(jnp.int32)
+    if latent:
+        acc_shape = q.shape[:-1] + (v_width,)
+        # as many keys a trip as the chunk has queries (a prefill chunk's
+        # past is whole chunks long, so no trip is part empty), and never
+        # under ``_GROUP`` blocks: every trip carries the accumulator
+        # ``[H, C, v_width]`` through HBM once, 134 MB at 128 heads and a
+        # chunk of 512, which at 8 blocks a trip was half of a prefill
+        # program (PERF.md, PR 35)
+        per_trip = max(_GROUP, C // BS)
+        n_trips = (n_blocks + per_trip - 1) // per_trip
+        block_tables = jnp.pad(
+            block_tables, [(0, 0)] * (block_tables.ndim - 1)
+            + [(0, -block_tables.shape[-1] % per_trip)])
+
+        def keys_values(i):
+            """The trip's rows ``[.., per_trip*BS, W']`` and their first
+            ``v_width`` lanes."""
+            ids = jax.lax.dynamic_slice_in_dim(
+                block_tables, i * per_trip, per_trip, axis=-1)
+            kb = k_pool[first_block + ids]          # [.., g, BS, W]
+            kb = kb.reshape(kb.shape[:-3] + (per_trip * BS, -1))[..., :D]
+            return kb, kb[..., :v_width]
+
+        def scores(kb):
+            return jnp.einsum("...hcd,...sd->...hcs", qf, kb,
+                              preferred_element_type=jnp.float32)
+
+        def weighted(p, vb):
+            return jnp.einsum("...hcs,...sd->...hcd", p.astype(vb.dtype),
+                              vb, preferred_element_type=jnp.float32)
+    else:
+        acc_shape, per_trip = q.shape, 1
+        n_trips = n_blocks
+
+        def keys_values(i):
+            rows = first_block + block_tables[..., i]
+            return (_read_blocks(k_pool, k_scale_pool, rows, H, D),
+                    _read_blocks(v_pool, v_scale_pool, rows, H, D))
+
+        def scores(kb):                                 # kb [B,BS,H,D]
+            return jnp.einsum("...hcd,...shd->...hcs", qf, kb)
+
+        def weighted(p, vb):
+            return jnp.einsum("...hcs,...shd->...hcd", p, vb)
 
     def body(i, carry):
         m, l, acc = carry
-        rows = first_block + block_tables[..., i]
-        kb = _read_blocks(k_pool, k_scale_pool, rows, H, D)  # [B,BS,H,D]
-        vb = _read_blocks(v_pool, v_scale_pool, rows, H, D)
-        s = jnp.einsum("...hcd,...shd->...hcs", qf, kb) * sm_scale
-        col = i * BS + jnp.arange(BS, dtype=jnp.int32)
+        kb, vb = keys_values(i)
+        s = scores(kb) * sm_scale
+        col = i * (per_trip * BS) + jnp.arange(per_trip * BS,
+                                               dtype=jnp.int32)
         s = jnp.where(col < lens, s, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1))
         p = jnp.exp(s - m_new[..., None])
         alpha = jnp.exp(m - m_new)
         l_new = l * alpha + jnp.sum(p, axis=-1)
-        acc = acc * alpha[..., None] \
-            + jnp.einsum("...hcs,...shd->...hcd", p, vb)
+        acc = acc * alpha[..., None] + weighted(p, vb)
         return m_new, l_new, acc
 
     m0 = jnp.full(q.shape[:-1], NEG_INF, jnp.float32)
     l0 = jnp.zeros(q.shape[:-1], jnp.float32)
-    a0 = jnp.zeros(q.shape, jnp.float32)
-    m_p, l_p, a_p = jax.lax.fori_loop(0, n_blocks, body, (m0, l0, a0))
+    a0 = jnp.zeros(acc_shape, jnp.float32)
+    m_p, l_p, a_p = jax.lax.fori_loop(0, n_trips, body, (m0, l0, a0))
     # intra-chunk causal piece from registers: key e visible to query c
     # iff e <= c; every query sees itself, so l can never be 0
-    s_in = jnp.einsum("...hcd,...hed->...hce", qf,
-                      k_chunk.astype(jnp.float32)) * sm_scale
+    if latent:
+        s_in = jnp.einsum("...hcd,...ed->...hce", qf, k_chunk,
+                          preferred_element_type=jnp.float32) * sm_scale
+    else:
+        s_in = jnp.einsum("...hcd,...hed->...hce", qf,
+                          k_chunk.astype(jnp.float32)) * sm_scale
     causal = jnp.arange(C)[:, None] >= jnp.arange(C)[None, :]
     s_in = jnp.where(causal, s_in, NEG_INF)
     m_in = jnp.max(s_in, axis=-1)
     p_in = jnp.exp(s_in - m_in[..., None])
     l_in = jnp.sum(p_in, axis=-1)
-    a_in = jnp.einsum("...hce,...hed->...hcd", p_in,
-                      v_chunk.astype(jnp.float32))
+    if latent:
+        a_in = jnp.einsum("...hce,...ed->...hcd", p_in.astype(k_chunk.dtype),
+                          k_chunk[..., :v_width],
+                          preferred_element_type=jnp.float32)
+    else:
+        a_in = jnp.einsum("...hce,...hed->...hcd", p_in,
+                          v_chunk.astype(jnp.float32))
     # the two online-softmax partials cover disjoint key sets
     m = jnp.maximum(m_p, m_in)
     w_p, w_in = jnp.exp(m_p - m), jnp.exp(m_in - m)

@@ -3,14 +3,26 @@
 The flax decode path (models/gpt2.py ``decode=True``) owns a per-batch
 contiguous cache with ONE shared ``cache_index`` — every sequence in the
 batch must sit at the same position, which is exactly what continuous
-batching breaks. This runner re-expresses the same GPT-2 math directly
-over the model's *params pytree* with per-slot positions and the paged
-pool from serving/kv_cache.py, ONCE: :meth:`PagedGPT2Runner._forward`
-embeds ``[B, C]`` tokens at their own positions, walks the blocks
-(:meth:`PagedGPT2Runner._block`, the only definition of a transformer
-block in the serving code), attends over each slot's past pages plus the
-chunk itself, writes the K/V of every layer that ran in one scatter per
-pool, and returns the logits. The four compiled programs are its callers:
+batching breaks. This runner re-expresses a model's math directly over
+its *params pytree* with per-slot positions and the paged pool from
+serving/kv_cache.py, ONCE: :meth:`PagedRunner._forward` embeds ``[B, C]``
+tokens at their own positions, walks the blocks, attends over each
+slot's past pages plus the chunk itself, writes the cache rows of every
+layer that ran in one scatter per pool, and returns the logits. The
+model's configuration says which of the two block definitions the
+forward walks (:func:`serving_family`; no option and no model's name):
+
+* GPT-2's (:class:`_GPT2Blocks`): LayerNorm, fused QKV with heads of
+  ``D`` lanes, learned positions, GELU MLP, tied head; K and V pools.
+* latent attention with experts (:class:`_MLAMoEBlocks`;
+  models/mla_moe.py): RMSNorm, low-rank queries, ONE latent row a token
+  (the cache holds nothing else, so every attention runs in the latent
+  space with the key's up-projection absorbed into the query), rotary
+  positions at each token's own offset (YaRN frequencies), SwiGLU, and
+  the expert layer of moe/held_experts.py over the experts this chip
+  holds; untied head; one ``kv`` pool.
+
+The four compiled programs are the forward's callers:
 
 * ``decode_step`` — the one static-shaped program the server calls every
   iteration: the forward at ``C = 1``, then a sampled token per request
@@ -35,9 +47,11 @@ Weight formats: float kernels and the engine's TRUE int8 weight storage
 folds into the matmul exactly like QuantDense. The int8 *KV* layout is
 the cache's concern and composes transparently.
 
-Scope guards (asserted at construction): GPT2LMHeadModel-family param
-trees, learned position embeddings, no MoE / pipeline / sequence
-parallelism, mp_size 1.
+What is not served is refused at construction with an error that names
+the mechanism (:class:`ServingNotSupported`), never mid-step: a GPT-2
+tree with rotary positions or experts in its blocks, pipeline stages,
+ring / Ulysses / block-sparse attention; over a latent cache, int8
+pools and int8 weights.
 """
 
 import functools
@@ -45,6 +59,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from deepspeed_tpu.moe.held_experts import held_expert_mlp, route
 from deepspeed_tpu.ops.quantizer.int8_linear import int8_matmul
 from deepspeed_tpu.ops.transformer.decode import quantize_kv
 from deepspeed_tpu.serving.paged_attention import (paged_chunk_attention,
@@ -85,68 +100,83 @@ def _sub(scales, *path):
     return node
 
 
-class PagedGPT2Runner:
-    def __init__(self, model, cache, decode_steps=1):
-        assert decode_steps >= 1
-        self.decode_steps = int(decode_steps)
-        cfg = model.config
-        for attr in ("n_layer", "n_head", "n_embd", "n_positions",
-                     "vocab_size"):
-            assert hasattr(cfg, attr), (
-                f"serving needs a GPT2Config-like model config (missing "
-                f"{attr!r}); got {type(cfg).__name__}")
-        assert getattr(cfg, "position_embedding", "learned") == "learned", \
-            "serving: rope per-slot offsets not wired yet; use 'learned'"
-        assert getattr(cfg, "moe_num_experts", 0) == 0, \
-            "serving: MoE decode not supported"
-        assert getattr(cfg, "pp_stages", 1) == 1, \
-            "serving: pipeline-parallel models not supported"
-        mode = getattr(cfg, "attention_mode", "auto")
-        assert not str(mode).startswith(("ring:", "ulysses:", "sparse")), (
-            f"serving decode is dense KV-cache attention; "
-            f"attention_mode={mode!r} models must serve with 'auto'")
+class ServingNotSupported(NotImplementedError):
+    """The model or the configuration asks for a mechanism that the
+    server does not run; raised where the server is built."""
+
+
+def serves_latent(cfg) -> bool:
+    """Whether ``cfg`` describes latent attention (one cached latent a
+    token: models/mla_moe.py), read from what it declares."""
+    return hasattr(cfg, "kv_lora_rank")
+
+
+def _require_gpt2_like(cfg):
+    for attr in ("n_layer", "n_head", "n_embd", "n_positions",
+                 "vocab_size"):
+        if not hasattr(cfg, attr):
+            raise ServingNotSupported(
+                f"serving needs a GPT2Config-like or a latent-attention "
+                f"model config (missing {attr!r}); got "
+                f"{type(cfg).__name__}")
+
+
+def cache_rows(cfg) -> dict:
+    """What ``PagedKVCache`` needs to know of the model's cache rows."""
+    if serves_latent(cfg):
+        return dict(n_head=cfg.num_attention_heads,
+                    head_dim=cfg.qk_nope_head_dim + cfg.qk_rope_head_dim,
+                    latent_width=cfg.latent_width)
+    _require_gpt2_like(cfg)
+    return dict(n_head=cfg.n_head, head_dim=cfg.n_embd // cfg.n_head)
+
+
+class _GPT2Blocks:
+    """GPT-2's embedding, block and head over the params pytree of
+    ``GPT2LMHeadModel``; K and V pools with the heads in lanes."""
+
+    def __init__(self, cfg, cache):
+        _require_gpt2_like(cfg)
+        if getattr(cfg, "position_embedding", "learned") != "learned":
+            raise ServingNotSupported(
+                "rotary positions in a GPT-2 block are not served (the "
+                "GPT-2 tree is served with learned positions; per-slot "
+                "rotary offsets run in the latent-attention block)")
+        if getattr(cfg, "moe_num_experts", 0):
+            raise ServingNotSupported(
+                "capacity-padded top-1/top-2 experts in a GPT-2 block are "
+                "not served (moe/sharded_moe.py is the training layer; the "
+                "served expert layer is moe/held_experts.py)")
+        if getattr(cfg, "pp_stages", 1) != 1:
+            raise ServingNotSupported(
+                "pipeline-parallel serving is not supported (pp_stages "
+                f"{cfg.pp_stages})")
+        mode = str(getattr(cfg, "attention_mode", "auto"))
+        if mode.startswith(("ring:", "ulysses:", "sparse")):
+            raise ServingNotSupported(
+                f"sequence-parallel and block-sparse attention are not "
+                f"served (attention_mode={mode!r}): decode is dense "
+                f"attention over the paged cache; serve with 'auto'")
         self.cfg = cfg
         self.cache = cache
         self.n_head = cfg.n_head
         self.head_dim = cfg.n_embd // cfg.n_head
-        # the pools are donated and the server re-threads the returned
-        # ones, so the stale buffers are never touched. Donation alone
-        # does not make the KV scatter an in-place update on the TPU: it
-        # gives the compiler the alias, and the pools' row shape
-        # (kv_cache.PagedKVCache) is what lets it write there without
-        # converting the pool — the decode program's compile memory at
-        # gpt2-medium, 40 slots: 4.74 GB of arguments + 0.04 GB of
-        # temporaries, where the ``[L, N, H, BS, D]`` pool took 4.9 +
-        # 8.1 GB (PERF.md, PR 27; tests/unit/test_serving_pool_layout.py
-        # keeps the check)
-        self._decode = jax.jit(self._decode_impl, donate_argnums=(2,))
-        self._prefill = jax.jit(self._prefill_impl, donate_argnums=(2,))
-        # copy-on-write block fork (prefix cache): ONE device block copy
-        # across every pool leaf (all layers in one update apiece, on
-        # the same folded rows the write scatters index). A third tiny
-        # program — deliberately NOT part of decode/prefill, whose
-        # signatures the one-program acceptance pins.
-        self._copy_block = jax.jit(self._copy_block_impl,
-                                   donate_argnums=(0,))
 
-    # -------------------------------------------------------- block copy
-    def _copy_block_impl(self, pools, src, dst):
-        """Block ``src`` -> block ``dst`` in every layer (rows
-        ``arange(L)*N + src`` -> ``+ dst``) of every leaf: K, V and the
-        int8 scales share the leading ``[L*N]`` block dim. src/dst are
-        traced int32 scalars, so every fork reuses one compiled
-        program."""
-        L = self.cache.n_layer
-        src_rows = self.cache.layer_rows(src, n_layers=L)
-        dst_rows = self.cache.layer_rows(dst, n_layers=L)
-        return {name: p.at[dst_rows].set(p[src_rows])
-                for name, p in pools.items()}
+    def embed(self, params, tok, pos):
+        cfg = self.cfg
+        # a pad or over-budget position can step past n_positions (its
+        # row is discarded) and past the table: clamps keep both gathers
+        # legal
+        x = params["wte"][tok] + params["wpe"][
+            jnp.minimum(pos, cfg.n_positions - 1)].astype(
+                params["wte"].dtype)
+        return x.reshape(-1, cfg.n_embd)
 
-    def copy_block(self, pools, src, dst):
-        """Fork one block's bytes: the COW path's single device op."""
-        return self._copy_block(pools, jnp.int32(src), jnp.int32(dst))
+    def head(self, params, x):
+        x = _ln(x, params["ln_f"])
+        return jnp.einsum("be,ve->bv", x, params["wte"],
+                          preferred_element_type=jnp.float32)
 
-    # ------------------------------------------------------- the forward
     def _requant(self, kv):
         """What the pool will hold for these rows: int8-round-tripped
         values, so the current token's self-attention matches what every
@@ -156,7 +186,7 @@ class PagedGPT2Runner:
         kq, ks = quantize_kv(kv)
         return kq.astype(jnp.float32) * ks[..., None]
 
-    def _attend(self, layer, pools, bt, past_lens, C, q, k, v):
+    def attend(self, layer, pools, bt, past_lens, C, q, k, v):
         """Rows ``[B*C, H, D]`` of one layer's q/k/v over each slot's PAST
         pages plus the chunk from registers; returns ``[B*C, H, D]``
         fp32. The shape decides what runs: a single query a slot is a
@@ -187,11 +217,13 @@ class PagedGPT2Runner:
             past_lens.reshape(lead), **scale_pools)
         return jnp.moveaxis(out, -3, -2).reshape(N, H, D)
 
-    def _block(self, p, s, x, attend):
+    def block(self, layer, params, scales, x, pos, real, attend):
         """One transformer block over rows ``x [N, E]``: pre-LN
         attention (``attend(q, k, v)`` over ``[N, H, D]``) and the GELU
-        MLP, each added to the residual. Returns the rows and the
-        layer's ``k`` and ``v``, which the forward writes to the pools."""
+        MLP, each added to the residual. Returns the rows, the layer's
+        ``k`` and ``v``, which the forward writes to the pools, and no
+        expert counts."""
+        p, s = params[f"h_{layer}"], _sub(scales, f"h_{layer}")
         N, E = x.shape
         H, D = self.n_head, self.head_dim
         qkv = _dense(_ln(x, p["ln_1"]), p["attn"]["qkv"],
@@ -201,8 +233,174 @@ class PagedGPT2Runner:
         x = x + _dense(out, p["attn"]["proj"], _sub(s, "attn", "proj"))
         h = jax.nn.gelu(_dense(_ln(x, p["ln_2"]), p["mlp"]["fc"],
                                _sub(s, "mlp", "fc")), approximate=True)
-        return x + _dense(h, p["mlp"]["proj"], _sub(s, "mlp", "proj")), k, v
+        x = x + _dense(h, p["mlp"]["proj"], _sub(s, "mlp", "proj"))
+        return x, {"k": k, "v": v}, None
 
+
+def _rms(x, gain, eps):
+    """RMSNorm in float32: ``x * rsqrt(mean(x^2) + eps) * gain``."""
+    xf = x.astype(jnp.float32)
+    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return (y * gain.astype(jnp.float32)).astype(x.dtype)
+
+
+def _swiglu(x, p):
+    return (jax.nn.silu(x @ p["gate"]) * (x @ p["up"])) @ p["down"]
+
+
+class _MLAMoEBlocks:
+    """The latent-attention block with experts (models/mla_moe.py) over
+    its params pytree; one ``kv`` pool whose row is the token's latent.
+
+    Every attention runs in the latent space, decode and chunk alike:
+    the cache holds a token's normed latent ``c_kv`` and its rotated
+    shared key ``k_pe`` and nothing else, so the key's up-projection is
+    absorbed into the query (``q_lat = q_nope @ W_UK^T``), the scores are
+    ``q_lat . c_kv + q_pe . k_pe``, the weighted sum is over ``c_kv`` and
+    the value's up-projection comes after it (``o = o_lat @ W_UV``)."""
+
+    def __init__(self, cfg, cache):
+        from deepspeed_tpu.models.mla_moe import yarn_inv_freq
+        self.cfg = cfg
+        self.cache = cache
+        self.inv_freq = yarn_inv_freq(cfg)
+
+    def embed(self, params, tok, pos):
+        return params["embed"][tok].reshape(-1, self.cfg.hidden_size)
+
+    def head(self, params, x):
+        x = _rms(x, params["norm_f"], self.cfg.rms_norm_eps)
+        return jnp.einsum("be,ve->bv", x, params["head"],
+                          preferred_element_type=jnp.float32)
+
+    def _rope(self, x, pos):
+        """Rotary positions at each row's own offset: ``x [N, ..., R]``,
+        ``pos [N]``; the interleaved pair ``(2i, 2i+1)`` turns by
+        ``pos * inv_freq[i]``, in float32."""
+        angle = pos.astype(jnp.float32)[:, None] * self.inv_freq
+        angle = angle.reshape((x.shape[0],) + (1,) * (x.ndim - 2) + (-1,))
+        scale = self.cfg.rope_cos_sin_scale
+        cos, sin = jnp.cos(angle) * scale, jnp.sin(angle) * scale
+        pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (-1, 2))
+        a, b = pairs[..., 0], pairs[..., 1]
+        return jnp.stack([a * cos - b * sin, a * sin + b * cos],
+                         axis=-1).reshape(x.shape).astype(x.dtype)
+
+    def attend(self, layer, pools, bt, past_lens, C, q, row):
+        """``q [B*C, H, W']`` (absorbed) and the tokens' own rows
+        ``[B*C, W']`` over each slot's past latents plus the chunk from
+        registers; returns ``[B*C, H, kv_lora]`` fp32. One query a slot
+        is a decode step (the kernel where it can run), a chunk the jnp
+        walk, as for every served model."""
+        kw = dict(sm_scale=self.cfg.softmax_scale,
+                  v_width=self.cfg.kv_lora_rank)
+        first_block = layer * self.cache.num_blocks
+        if C == 1:
+            return paged_decode_attention(q, row, None, first_block,
+                                          pools["kv"], None, bt, past_lens,
+                                          **kw)
+        N, H, W = q.shape
+        lead = () if N == C else (N // C,)      # a lone slot: no batch dim
+        out = paged_chunk_attention(
+            jnp.moveaxis(q.reshape(lead + (C, H, W)), -3, -2),
+            row.reshape(lead + (C, W)), None, first_block, pools["kv"],
+            None, bt.reshape(lead + bt.shape[1:]), past_lens.reshape(lead),
+            **kw)
+        return jnp.moveaxis(out, -3, -2).reshape(N, H, -1)
+
+    def block(self, layer, params, scales, x, pos, real, attend):
+        """One block over rows ``x [N, E]`` at positions ``pos`` (``N``
+        of them): pre-norm latent attention, then the dense SwiGLU or the
+        expert layer (shared expert + the held routed experts' part),
+        each added to the residual. Returns the rows, the row each token
+        caches and the expert layer's counts (None for a dense layer).
+        ``real`` marks the rows that are tokens (the rest are a chunk's
+        pad or a frozen slot): only they are routed."""
+        cfg = self.cfg
+        p = params[f"h_{layer}"]
+        a = p["attn"]
+        pos, real = pos.reshape(-1), real.reshape(-1)
+        N, H = x.shape[0], cfg.num_attention_heads
+        nope, lora = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+        eps = cfg.rms_norm_eps
+        h = _rms(x, p["norm_1"], eps)
+        q = (_rms(h @ a["q_a"], a["q_norm"], eps) @ a["q_b"]).reshape(
+            N, H, -1)
+        ckv = h @ a["kv_a"]
+        row = jnp.concatenate([_rms(ckv[:, :lora], a["kv_norm"], eps),
+                               self._rope(ckv[:, lora:], pos)], axis=-1)
+        q = jnp.concatenate(
+            [jnp.einsum("nhd,chd->nhc", q[..., :nope], a["kv_b_k"]),
+             self._rope(q[..., nope:], pos)], axis=-1)
+        o = jnp.einsum("nhc,chd->nhd", attend(q, row).astype(x.dtype),
+                       a["kv_b_v"]).reshape(N, -1)
+        x = x + o @ a["o"]
+        h = _rms(x, p["norm_2"], eps)
+        if "moe" not in p:
+            return x + _swiglu(h, p["mlp"]), {"kv": row}, None
+        m = p["moe"]
+        chosen, weights = route(
+            h, m["router"], m["router_bias"], k=cfg.num_experts_per_tok,
+            n_group=cfg.n_group, topk_group=cfg.topk_group,
+            scale=cfg.routed_scaling_factor)
+        routed, counts = held_expert_mlp(h, chosen, weights, m["experts"],
+                                         cfg.experts_held[0], real)
+        y = _swiglu(h, m["shared"]).astype(jnp.float32) + routed
+        return x + y.astype(x.dtype), {"kv": row}, counts
+
+
+class PagedRunner:
+    def __init__(self, model, cache, decode_steps=1):
+        assert decode_steps >= 1
+        self.decode_steps = int(decode_steps)
+        cfg = model.config
+        self.blocks = (_MLAMoEBlocks if serves_latent(cfg)
+                       else _GPT2Blocks)(cfg, cache)
+        self.cfg = cfg
+        self.cache = cache
+        # the expert layers' counts of the dispatches since they were
+        # last taken: int32 [3] device arrays (held_experts.py), which
+        # the server lands with the step's tokens; stays empty for a
+        # model without experts
+        self.expert_counts = []
+        # the pools are donated and the server re-threads the returned
+        # ones, so the stale buffers are never touched. Donation alone
+        # does not make the KV scatter an in-place update on the TPU: it
+        # gives the compiler the alias, and the pools' row shape
+        # (kv_cache.PagedKVCache) is what lets it write there without
+        # converting the pool — the decode program's compile memory at
+        # gpt2-medium, 40 slots: 4.74 GB of arguments + 0.04 GB of
+        # temporaries, where the ``[L, N, H, BS, D]`` pool took 4.9 +
+        # 8.1 GB (PERF.md, PR 27; tests/unit/test_serving_pool_layout.py
+        # keeps the check)
+        self._decode = jax.jit(self._decode_impl, donate_argnums=(2,))
+        self._prefill = jax.jit(self._prefill_impl, donate_argnums=(2,))
+        # copy-on-write block fork (prefix cache): ONE device block copy
+        # across every pool leaf (all layers in one update apiece, on
+        # the same folded rows the write scatters index). A third tiny
+        # program — deliberately NOT part of decode/prefill, whose
+        # signatures the one-program acceptance pins.
+        self._copy_block = jax.jit(self._copy_block_impl,
+                                   donate_argnums=(0,))
+
+    # -------------------------------------------------------- block copy
+    def _copy_block_impl(self, pools, src, dst):
+        """Block ``src`` -> block ``dst`` in every layer (rows
+        ``arange(L)*N + src`` -> ``+ dst``) of every leaf: every pool
+        (K, V and the int8 scales, or the latent pool) shares the
+        leading ``[L*N]`` block dim. src/dst are traced int32 scalars,
+        so every fork reuses one compiled program."""
+        L = self.cache.n_layer
+        src_rows = self.cache.layer_rows(src, n_layers=L)
+        dst_rows = self.cache.layer_rows(dst, n_layers=L)
+        return {name: p.at[dst_rows].set(p[src_rows])
+                for name, p in pools.items()}
+
+    def copy_block(self, pools, src, dst):
+        """Fork one block's bytes: the COW path's single device op."""
+        return self._copy_block(pools, jnp.int32(src), jnp.int32(dst))
+
+    # ------------------------------------------------------- the forward
     def _forward(self, params, scales, pools, bt, past_lens, tok, pos, write,
                  n_layers=None, want_logits=True):
         """The serving forward pass: ``C`` tokens for each of ``B`` slots.
@@ -210,48 +408,45 @@ class PagedGPT2Runner:
         tok/pos ``[B, C]``: the tokens and their absolute positions
         (``pos[b] = past_lens[b] + 0..C-1``); write ``[B, C]`` bool:
         which of them are real (a frozen slot, a chunk's pad tail and a
-        candidate past a slot's budget are not: their K/V go to the null
-        block and their output rows are discarded by the caller); bt
-        ``[B, MB]``; past_lens ``[B]``: tokens ALREADY in the pool.
+        candidate past a slot's budget are not: their cache rows go to
+        the null block and their output rows are discarded by the
+        caller); bt ``[B, MB]``; past_lens ``[B]``: tokens ALREADY in
+        the pool.
 
         Embeds, runs the first ``n_layers`` blocks (default: all),
-        writes those layers' K/V in ONE scatter per pool, and returns
-        ``(pools, logits [B*C, V])``, the logits ``None`` unless wanted.
+        writes those layers' cache rows in ONE scatter per pool, and
+        returns ``(pools, logits [B*C, V], counts)``, the logits ``None``
+        unless wanted, the counts the expert layers' summed (``None``
+        for a model without experts).
 
         ``n_layers < cfg.n_layer`` is the truncated-layer self-draft of
         serving/speculative.py: the SAME params pytree traced over a
         layer prefix (plus the shared ln_f and tied head) — zero extra
         weights, and the prefix layers' K/V are bit-identical to the
         target's, so draft writes land in the same pools."""
-        cfg = self.cfg
+        blocks = self.blocks
         bs = self.cache.block_size
         B, C = tok.shape
-        # a pad or over-budget position can step past n_positions (its
-        # row is discarded) and past the table: clamps keep both gathers
-        # legal
-        x = params["wte"][tok] + params["wpe"][
-            jnp.minimum(pos, cfg.n_positions - 1)].astype(
-                params["wte"].dtype)
-        x = x.reshape(B * C, cfg.n_embd)
-        ks, vs = [], []
-        for layer in range(cfg.n_layer if n_layers is None
+        x = blocks.embed(params, tok, pos)
+        new, counts = [], None
+        for layer in range(self.cfg.n_layer if n_layers is None
                            else int(n_layers)):
-            x, k, v = self._block(
-                params[f"h_{layer}"], _sub(scales, f"h_{layer}"), x,
-                functools.partial(self._attend, layer, pools, bt,
+            x, rows, n = blocks.block(
+                layer, params, scales, x, pos, write,
+                functools.partial(blocks.attend, layer, pools, bt,
                                   past_lens, C))
-            ks.append(k)
-            vs.append(v)
+            new.append(rows)
+            if n is not None:
+                counts = n if counts is None else counts + n
         row = jnp.take_along_axis(
             bt, jnp.minimum(pos // bs, bt.shape[1] - 1), axis=1)
         pools = self.cache.write_layers(
-            pools, jnp.stack(ks), jnp.stack(vs),
+            pools, {name: jnp.stack([rows[name] for rows in new])
+                    for name in new[0]},
             jnp.where(write, row, 0).reshape(-1), (pos % bs).reshape(-1))
         if not want_logits:
-            return pools, None
-        x = _ln(x, params["ln_f"])
-        return pools, jnp.einsum("be,ve->bv", x, params["wte"],
-                                 preferred_element_type=jnp.float32)
+            return pools, None, counts
+        return pools, blocks.head(params, x), counts
 
     # ---------------------------------------------------------- programs
     def _decode_impl(self, params, scales, pools, bt, pos, active, tok,
@@ -271,42 +466,45 @@ class PagedGPT2Runner:
         allocated blocks). A slot past its budget FREEZES — its writes
         route to the null block, its position stops advancing, and its
         sampled tokens are discarded host-side. K=1 reduces to classic
-        per-token continuous batching. Returns (pools, tokens [K, B]).
+        per-token continuous batching. Returns (pools, tokens [K, B],
+        the expert layers' counts or None).
         """
         K = self.decode_steps
         tok = jnp.where(prev_row >= 0, jnp.take_along_axis(
             prev, jnp.maximum(prev_row, 0)[None], axis=0)[0], tok)
 
         def one(pools, step_pos, live, cur):
-            pools, logits = self._forward(
+            pools, logits, counts = self._forward(
                 params, scales, pools, bt, step_pos, cur[:, None],
                 step_pos[:, None], live[:, None])
             return pools, sample_tokens(logits, temp, top_p, lanes,
                                         step_pos,
-                                        vocab_size=self.cfg.vocab_size)
+                                        vocab_size=self.cfg.vocab_size), \
+                counts
 
         def body(carry, i):
             pools, cur = carry
             live = active & (i < budget)
-            pools, nxt = one(pools, pos + jnp.minimum(i, budget), live, cur)
-            return (pools, jnp.where(live, nxt, cur)), nxt
+            pools, nxt, counts = one(pools, pos + jnp.minimum(i, budget),
+                                     live, cur)
+            return (pools, jnp.where(live, nxt, cur)), (nxt, counts)
 
         if K == 1:
-            pools, nxt = one(pools, pos, active & (budget > 0), tok)
-            return pools, nxt[None]
-        (pools, _), toks = jax.lax.scan(
+            pools, nxt, counts = one(pools, pos, active & (budget > 0), tok)
+            return pools, nxt[None], counts
+        (pools, _), (toks, counts) = jax.lax.scan(
             body, (pools, tok), jnp.arange(K, dtype=jnp.int32))
-        return pools, toks
+        return pools, toks, None if counts is None else counts.sum(0)
 
     def _prefill_impl(self, params, scales, pools, bt_row, tokens, start,
                       n_valid):
         """One slot's chunk: the forward at ``B = 1``, no head; the
         positions past ``n_valid`` are the final chunk's pad."""
         idx = jnp.arange(tokens.shape[0], dtype=jnp.int32)
-        pools, _ = self._forward(
+        pools, _, counts = self._forward(
             params, scales, pools, bt_row[None], start[None], tokens[None],
             (start + idx)[None], (idx < n_valid)[None], want_logits=False)
-        return pools
+        return pools, counts
 
     # -------------------------------------------------------- public API
     def decode_step(self, params, scales, pools, bt, pos, active, tok,
@@ -314,13 +512,32 @@ class PagedGPT2Runner:
         """One decode DISPATCH (``decode_steps`` tokens per slot, budget-
         capped); returns ``(pools, tokens [K, B] int32 device array)``.
         ``prev``/``prev_row``: the dispatch before's tokens and the row of
-        them that is each slot's input (negative: ``tok``)."""
-        return self._decode(params, scales or {}, pools, bt, pos, active,
-                            tok, temp, top_p, lanes, budget, prev, prev_row)
+        them that is each slot's input (negative: ``tok``). The expert
+        layers' counts, where the model has any, are kept for
+        :meth:`take_expert_counts`."""
+        pools, toks, counts = self._decode(
+            params, scales or {}, pools, bt, pos, active, tok, temp, top_p,
+            lanes, budget, prev, prev_row)
+        if counts is not None:
+            self.expert_counts.append(counts)
+        return pools, toks
 
     def prefill_chunk(self, params, scales, pools, bt_row, tokens, start,
                       n_valid):
         """Fill ``n_valid`` prompt tokens of one slot's KV; returns
         updated pools."""
-        return self._prefill(params, scales or {}, pools, bt_row, tokens,
-                             start, n_valid)
+        pools, counts = self._prefill(params, scales or {}, pools, bt_row,
+                                      tokens, start, n_valid)
+        if counts is not None:
+            self.expert_counts.append(counts)
+        return pools
+
+    def take_expert_counts(self) -> list:
+        """The counts of the dispatches made since the last call, still
+        on the device, each with its copy to the host under way."""
+        taken, self.expert_counts = self.expert_counts, []
+        return taken
+
+
+# the name the GPT-2-only server gave its runner, which callers import
+PagedGPT2Runner = PagedRunner

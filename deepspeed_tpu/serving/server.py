@@ -2,8 +2,9 @@
 
 ``ServingEngine`` glues the subsystem together on top of an
 ``InferenceEngine`` (which owns params, dtype/int8-weight handling and
-the mesh): a ``PagedKVCache`` block pool, the ``PagedGPT2Runner``'s two
-compiled programs, the FCFS continuous-batching scheduler, and chunked
+the mesh): a ``PagedKVCache`` block pool whose rows are what the model's
+configuration says it caches (K and V heads, or one latent a token), the
+``PagedRunner``'s two compiled programs, the FCFS continuous-batching scheduler, and chunked
 prefill. The API is deliberately synchronous — ``submit()`` enqueues,
 ``step()`` advances the world by one scheduler iteration (one bounded
 prefill chunk per still-prefilling slot + one decode dispatch),
@@ -48,7 +49,8 @@ import numpy as np
 from deepspeed_tpu.serving.kv_cache import PagedKVCache
 from deepspeed_tpu.serving.paged_attention import decode_kernel_runs
 from deepspeed_tpu.serving.prefill import ChunkedPrefill
-from deepspeed_tpu.serving.runner import PagedGPT2Runner
+from deepspeed_tpu.serving.runner import (PagedRunner, ServingNotSupported,
+                                          cache_rows, serves_latent)
 from deepspeed_tpu.serving.sampling import make_rng_lane
 from deepspeed_tpu.serving.scheduler import (ContinuousBatchingScheduler,
                                              Request, RequestState)
@@ -100,6 +102,9 @@ class _Dispatch:
     toks: object            # [K, B] device array, its host copy under way
     accepted: object        # [B] device array under speculation, else None
     t0: int                 # perf_counter_ns when the dispatch began
+    expert_counts: list     # int32 [3] device arrays: the expert layers'
+    #                         counts of this dispatch and of the prefill
+    #                         chunks queued before it (none without experts)
 
 
 @dataclasses.dataclass
@@ -117,8 +122,10 @@ class ServingEngine:
     def __init__(self, engine, config=None, registry=None,
                  guardian=None, obs_server=None, slo=None,
                  draft_params=None, draft_scales=None):
-        """``engine``: an ``InferenceEngine`` wrapping a GPT-2-family
-        model; ``config``: ``DeepSpeedServingConfig``, a ds-config dict
+        """``engine``: an ``InferenceEngine`` wrapping a model the runner
+        has a block for (serving/runner.py: the GPT-2 family, latent
+        attention with experts); what is not served is refused here,
+        by the name of the mechanism, and never mid-step; ``config``: ``DeepSpeedServingConfig``, a ds-config dict
         (with or without the outer ``{"serving": ...}``), or ``None`` for
         defaults; ``guardian``: a :class:`runtime.guardian.Guardian` to
         wire the overload-degradation policy into (falls back to the
@@ -142,26 +149,41 @@ class ServingEngine:
             config = DeepSpeedServingConfig(pd)
         self.config = config
         self.engine = engine
-        assert engine.mp_world_size == 1, (
-            "serving currently drives single-chip decode (mp=1); "
-            "tensor-parallel serving is a roadmap item")
+        if engine.mp_world_size != 1:
+            raise ServingNotSupported(
+                "tensor-parallel serving is not supported: the server "
+                "drives one chip's decode (mp_size 1; replicas behind "
+                f"serving/router.py scale out), got mp_size "
+                f"{engine.mp_world_size}")
         model = engine.module
         cfg = model.config
+        spec_cfg = getattr(config, "speculative", None)
+        if serves_latent(cfg):
+            if engine.quant_scales is not None:
+                raise ServingNotSupported(
+                    "int8 weights are not served for a latent-attention "
+                    "model (dtype=int8 folds its scales into the GPT-2 "
+                    "block's matmuls only)")
+            if spec_cfg is not None and spec_cfg.enabled:
+                raise ServingNotSupported(
+                    "speculative decoding over a latent cache is not "
+                    "served: the draft's layer prefix has no head of its "
+                    "own here and a draft module is ROADMAP M7")
+        rows = cache_rows(cfg)      # refuses a model with no served block
         n_pos = int(getattr(cfg, "n_positions"))
         self.max_model_len = (min(int(config.max_model_len), n_pos)
                               if config.max_model_len else n_pos)
         self.max_batch = int(config.max_batch)
-        head_dim = cfg.n_embd // cfg.n_head
         int8_kv = getattr(cfg, "kv_cache_dtype", "auto") == "int8"
         self.max_blocks_per_seq = -(-self.max_model_len
                                     // int(config.block_size))
         num_blocks = int(config.num_blocks) or (
             1 + self.max_batch * self.max_blocks_per_seq)
         self.cache = PagedKVCache(
-            n_layer=cfg.n_layer, n_head=cfg.n_head, head_dim=head_dim,
-            block_size=config.block_size, num_blocks=num_blocks,
-            dtype=engine.dtype, int8_kv=int8_kv)
-        self.runner = PagedGPT2Runner(
+            n_layer=cfg.n_layer, block_size=config.block_size,
+            num_blocks=num_blocks, dtype=engine.dtype, int8_kv=int8_kv,
+            **rows)
+        self.runner = PagedRunner(
             model, self.cache, decode_steps=config.decode_steps)
         # speculative decoding (serving/speculative.py): replaces the
         # decode dispatch with a draft + verify program pair. The
@@ -169,7 +191,6 @@ class ServingEngine:
         # ledger's K basis) becomes k+1 — the verify width — so block
         # growth covers every candidate position and the ledger's
         # sums-exact invariant holds on both engines of an A/B.
-        spec_cfg = getattr(config, "speculative", None)
         self.speculative = None
         self._spec_disabled_rule = None       # None = speculation live
         if spec_cfg is not None and spec_cfg.enabled:
@@ -293,6 +314,7 @@ class ServingEngine:
         # zeros placed like the program's own output, so that there is
         # one decode program
         self._in_flight = None
+        self._landed_pairs = None     # what the last landing counted
         self._acts = {}
         self._no_prev = jax.device_put(
             np.zeros((self.runner.decode_steps, self.max_batch), np.int32),
@@ -731,9 +753,12 @@ class ServingEngine:
                         lanes, budget,
                         self._no_prev if prev is None else prev.toks,
                         prev_row)
+                # the expert layers' counts of this dispatch and of the
+                # chunks queued before it land with its tokens
+                counts = self.runner.take_expert_counts()
                 # the copy to the host starts when the program ends, not
                 # when the landing asks for it a step later
-                jax.copy_to_host_async((toks, accepted))
+                jax.copy_to_host_async((toks, accepted, counts))
             # counts advance at dispatch: the next step is scheduled from
             # them before these tokens are read
             reqs = {i: self.scheduler.slots[i] for i in decode_slots}
@@ -742,9 +767,14 @@ class ServingEngine:
                 r.cached_len += rows
                 r.in_flight += rows
             self._land()                    # the step before's tokens
-            self._in_flight = _Dispatch(reqs, budget, toks, accepted, t0)
+            self._in_flight = _Dispatch(reqs, budget, toks, accepted, t0,
+                                        counts)
             if spec is not None:
                 self._land("speculation")
+            if self._landed_pairs is not None:
+                held, absent, most = self._landed_pairs
+                span.set(pairs_held=held, pairs_absent=absent,
+                         pairs_max=most)
 
     def _land(self, reason=None) -> bool:
         """Read back the decode dispatch in flight and hand its tokens
@@ -757,13 +787,17 @@ class ServingEngine:
         None is the run-ahead order, the landing that follows the next
         step's dispatch. This is the step's one device-to-host read."""
         flight, self._in_flight = self._in_flight, None
+        self._landed_pairs = None
         if flight is None:
             return False
         with trace_span("serving_decode_wait"):
             accepted = (None if flight.accepted is None
                         else np.asarray(flight.accepted))    # [B]
             toks = np.asarray(flight.toks)     # [K, B]; the one host sync
+            counts = [np.asarray(c) for c in flight.expert_counts]
         t1 = time.perf_counter_ns()
+        if counts:
+            self._count_expert_pairs(np.sum(counts, axis=0))
         with trace_span("serving_deliver"):
             self._deliver_decoded(flight, toks, accepted, t1)
         if reason is not None:
@@ -773,6 +807,24 @@ class ServingEngine:
                 "scheduled, by what made the step need its tokens",
                 labels={"reason": reason}).inc()
         return True
+
+    def _count_expert_pairs(self, counts):
+        """Book what the expert layers of the landed dispatches counted
+        (moe/held_experts.py): token-expert choices whose expert is held
+        here and not, and the most pairs that one held expert got in a
+        dispatch and layer, summed."""
+        held, absent, most = (int(n) for n in counts)
+        self._landed_pairs = (held, absent, most)
+        for name, n, what in (
+                ("serving_moe_pairs_held_total", held,
+                 "token-expert choices whose expert this chip holds"),
+                ("serving_moe_pairs_absent_total", absent,
+                 "token-expert choices whose expert another chip holds: "
+                 "left out of the partial sum"),
+                ("serving_moe_expert_load_max_total", most,
+                 "the most pairs any one held expert got, summed over "
+                 "dispatches and expert layers")):
+            self.registry.counter(name, what).inc(n)
 
     def _deliver_decoded(self, flight, toks, accepted, t1):
         """Hand one decode dispatch's tokens to its requests;

@@ -125,7 +125,7 @@ class SpeculativeDecoder:
             pools, cur = carry
             step_pos = pos + jnp.minimum(i, jnp.maximum(budget - 1, 0))
             live = active & (i < budget)
-            pools, logits = r._forward(
+            pools, logits, _ = r._forward(
                 params, scales, pools, bt, step_pos, cur[:, None],
                 step_pos[:, None], live[:, None],
                 n_layers=self.draft_layers)
@@ -159,7 +159,7 @@ class SpeculativeDecoder:
         # budget-capped slot are write-masked
         live_w = active[:, None] \
             & (jnp.arange(C, dtype=jnp.int32)[None, :] < budget[:, None])
-        pools, logits = r._forward(params, scales, pools, bt, pos, toks_in,
+        pools, logits, _ = r._forward(params, scales, pools, bt, pos, toks_in,
                                    poss, live_w)
         # the target's OWN token at every position: same sampler, same
         # position fold as the decode scan -> path-invariant draws

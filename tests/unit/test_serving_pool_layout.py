@@ -192,3 +192,76 @@ def test_detector_sees_the_stacked_write_convert_the_pool(one_chip):
     assert _pool_copies(compiled.as_text(), [pool])
     pool_bytes = L * N * H * BS * D * 2
     assert compiled.memory_analysis().temp_size_in_bytes >= pool_bytes
+
+
+# ----------------------------------------------- the latent pool (PR 35)
+def _latent_programs(one_chip, slots=16, num_blocks=257, chunk=128):
+    """The decode, prefill and block-copy programs of the latent-attention
+    model at its published widths (benchmark/configs/dots.vlm1.inst.json),
+    one dense and two expert layers, 128 rows of vocabulary."""
+    from deepspeed_tpu.models.mla_moe import (MLAMoEConfig,
+                                              MLAMoEForCausalLM, init_params)
+    from deepspeed_tpu.serving.runner import PagedRunner, cache_rows
+    spec = _spec(one_chip)
+    cfg = MLAMoEConfig(
+        vocab_size=128, hidden_size=7168, num_hidden_layers=3,
+        first_k_dense_replace=1, intermediate_size=18432,
+        moe_intermediate_size=2048, n_routed_experts=256,
+        experts_held=(0, 16), num_experts_per_tok=8, n_group=8, topk_group=4,
+        routed_scaling_factor=2.5, num_attention_heads=128, q_lora_rank=1536,
+        kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+        v_head_dim=128, max_position_embeddings=163840, rope_factor=40.0,
+        rope_mscale=1.0, rope_mscale_all_dim=1.0)
+    params = jax.eval_shape(
+        lambda: init_params(cfg, jax.random.PRNGKey(0), jnp.bfloat16))
+    params = jax.tree.map(lambda a: spec(a.shape, jnp.bfloat16), params)
+    cache = PagedKVCache(n_layer=3, block_size=BLOCK_SIZE,
+                         num_blocks=num_blocks, dtype=jnp.bfloat16,
+                         **cache_rows(cfg))
+    runner = PagedRunner(MLAMoEForCausalLM(cfg), cache)
+    pools = {name: spec(shape, dtype)
+             for name, (shape, dtype) in cache._pool_shapes().items()}
+    B, i32, f32 = slots, jnp.int32, jnp.float32
+    return cache, pools, {
+        "decode": (runner._decode,
+                   [params, {}, pools, spec((B, MAX_BLOCKS), i32),
+                    spec((B,), i32), spec((B,), jnp.bool_), spec((B,), i32),
+                    spec((B,), f32), spec((B,), f32),
+                    spec((B, 2), jnp.uint32), spec((B,), i32),
+                    spec((1, B), i32), spec((B,), i32)]),
+        "prefill": (runner._prefill,
+                    [params, {}, pools, spec((MAX_BLOCKS,), i32),
+                     spec((chunk,), i32), spec((), i32), spec((), i32)]),
+        "copy_block": (runner._copy_block,
+                       [pools, spec((), i32), spec((), i32)]),
+    }
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill", "copy_block"])
+def test_latent_program_leaves_its_one_pool_in_place(one_chip, monkeypatch,
+                                                     program):
+    """The latent model's programs compile for the v5e at the published
+    widths: one ``kv`` pool of 640-lane rows, aliased to its output and
+    never copied whole; the decode walk is the ``paged_decode`` kernel (one
+    call a layer, the queries 128 dense rows a slot), and an expert layer
+    two grouped products (Mosaic as well). A prefill chunk needs no head,
+    so the compiler drops the last layer's MLP and its two products."""
+    from deepspeed_tpu.moe import held_experts
+    monkeypatch.setattr(paged_attention, "_interpret", lambda: False)
+    monkeypatch.setattr(held_experts, "_interpret", lambda: False)
+    monkeypatch.setattr(groups, "_MESH", None)
+    cache, pools, programs = _latent_programs(one_chip)
+    assert {n: tuple(s.shape) for n, s in pools.items()} == {
+        "kv": (3 * 257, 16, 640)}
+    fn, args = programs[program]
+    compiled = fn.lower(*args).compile()
+    text = compiled.as_text()
+    kernels = text.count('custom_call_target="tpu_custom_call"')
+    assert kernels == {"decode": 3 + 4, "prefill": 2, "copy_block": 0}[
+        program], f"{kernels} Mosaic calls in the {program} program"
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= cache.pool_bytes()
+    copies = _pool_copies(text, pools.values())
+    assert not copies, "whole-pool copies:\n" + "\n".join(copies)
+    if program == "decode":
+        assert mem.temp_size_in_bytes < 64e6
