@@ -12,14 +12,15 @@ from the platform, the pool's dtype and the mesh's size (no option):
   mesh**: one Pallas kernel call a layer (``paged_decode`` on a profile's
   ``XLA Ops`` line). The pools stay in HBM; each slot walks its OWN
   ``ceil(past_len / block_size)`` blocks, each block one async copy of
-  ``[block_size, W]`` rows into VMEM, ``_GROUP`` blocks a group with the
-  next group's copies in flight while this one is reduced; rows stay
-  ``W`` lanes wide from HBM to the accumulator (the heads are the rows
-  of a block-diagonal query, :func:`_decode_kernel`). It replaced the
-  jnp walk there, which took 19.6 of a 20.5 ms decode program at
-  gpt2-medium with 40 slots: a sixth of the HBM roofline, three fifths
-  of the gathered blocks holding no token of their slot (PERF.md,
-  PR 30).
+  ``[block_size, W]`` rows into VMEM, ``_GROUP`` blocks a group; the
+  groups of all slots are one stream, of which ``_AHEAD`` are fetched
+  or in flight beside the one being reduced, across slot boundaries;
+  rows stay ``W`` lanes wide from HBM to the accumulator (the heads are
+  the rows of a block-diagonal query, :func:`_decode_kernel`). It
+  replaced the jnp walk there, which took 19.6 of a 20.5 ms decode
+  program at gpt2-medium with 40 slots: a sixth of the HBM roofline,
+  three fifths of the gathered blocks holding no token of their slot
+  (PERF.md, PR 30).
 * **Everything else** (prefill and verify chunks everywhere; decode off
   the TPU, over int8 pools, and under a multi-device mesh, where GSPMD
   refuses a bare ``pallas_call``): :func:`paged_chunk_attention`, ONE
@@ -72,10 +73,17 @@ from jax.experimental.pallas import tpu as pltpu
 from deepspeed_tpu.ops._platform import interpret as _interpret
 
 NEG_INF = -1e30
-# KV blocks the decode kernel fetches as one group, the next group's
-# copies in flight while this one is reduced; chosen on the chip at both
-# benchmark shapes (PERF.md, PR 30)
+# KV blocks the decode kernel fetches and reduces as one group; chosen on
+# the chip at both benchmark shapes (PERF.md, PR 30; 16 read again under
+# PR 36's schedule: no faster where groups are full, slower where a
+# slot's last group is part empty)
 _GROUP = 8
+# groups of the one stream over all slots that the decode kernel keeps
+# fetched or in flight beside the one it reduces (a ring of ``_AHEAD + 1``
+# buffers a pool: 2 MB of VMEM at gpt2-medium's row, 3.4 MB at gpt2-xl's);
+# chosen on the chip: 2 is 16% slower at medium's mix, 4 no faster
+# (PERF.md, PR 36)
+_AHEAD = 3
 
 
 def _read_blocks(pool, scale_pool, rows, H, D):
@@ -153,77 +161,115 @@ def _dot_f32(a, b, dims):
     return out[:n] + out[n:2 * n] + out[2 * n:]
 
 
-def _decode_kernel(first_ref, bt_ref, len_ref, q_ref, kc_ref, *refs,
-                   sm_scale, head_dim, group):
-    """Grid program ``b`` is slot ``b``: it walks the slot's own
-    ``ceil(past_len / BS)`` blocks in groups of ``group``, each block one
-    copy of a ``[BS, W]`` row block out of the pool in HBM into one of
-    two VMEM buffers, the next group (or the next slot's first) in
-    flight while this one is reduced.
+def _decode_kernel(first_ref, bt_ref, len_ref, next_ref, q_ref, kc_ref,
+                   *refs, sm_scale, head_dim, group):
+    """Grid program ``b`` reduces slot ``b``; the copies follow ONE
+    stream of groups over all slots (slot 0's groups, then slot 1's, ...,
+    a slot that holds nothing skipped), and the kernel keeps the next
+    ``n_buf - 1`` groups of that stream fetched or in flight beside the
+    one being reduced, whatever slot they belong to, in a ring of
+    ``n_buf`` VMEM buffers a pool: a slot's last group, its fold of the
+    current token and the next slot's start all run with copies queued
+    behind them. A group is ``group`` blocks, each block one copy of a
+    ``[BS, W]`` row block out of the pool in HBM, and only blocks that
+    hold a token are copied. The ring's next buffer to reduce and the
+    stream's next group to fetch (slot, group) live in SMEM across grid
+    programs; ``next_ref[s]`` is the first slot from ``s`` on that holds
+    a token (``n_slots`` past the last).
+
+    A group's turn is ONE basic block (:func:`turn`, one copy of it a
+    buffer, chosen by a switch): await the group's copies, reduce it, and
+    start the copies of the group ``n_buf - 1`` further down the stream
+    into the buffer reduced a turn ago. Each copy is predicated by itself
+    (its block holds a token, the stream has not ended) and the cursor
+    moves by selects, so no branch stands between the descriptors'
+    scalar work (an address out of the table and a bounds check, some 14
+    bundles a copy) and the products, and the scheduler runs the one
+    under the other; each buffer is an allocation of its own, so that a
+    copy into one is seen not to touch the rows read from another. With
+    a branch around the starts and one ``[n_buf, T, W]`` allocation the
+    vector units sat idle through every descriptor (PERF.md, PR 36).
 
     Rows stay ``W`` lanes wide throughout, and the queries are the rows
     of a matrix ``[Hp, W]``, so scores are ``[Hp, T]`` from one matmul
     against the K rows and ``P @ V`` is ``[Hp, W]``. With heads in lanes
     (``head_dim`` given; refs: the current V row, the K and V pools, the
-    output, a buffer a pool) the matrix is block-diagonal (row ``h``
+    output, a ring a pool) the matrix is block-diagonal (row ``h``
     holds the query's lanes ``h*D..(h+1)*D``, zero elsewhere) and row
     ``h`` of the result is read in head ``h``'s lanes alone; pad lanes
     and pad heads meet zeros of the query and are never read back. With
     one latent a token (``head_dim`` None; refs: the one pool, the
-    output, one buffer) the matrix is the slot's dense ``[Hp, W]``
+    output, one ring) the matrix is the slot's dense ``[Hp, W]``
     query, the V rows ARE the K rows, and the whole result is kept (the
-    caller reads its first lanes)."""
+    caller reads its first lanes). The last three refs of either form:
+    the slot's float32 accumulator ``[Hp, W]`` in VMEM, the copies'
+    semaphores (one a pool and buffer), the three cursor words in SMEM."""
     latent = head_dim is None
+    *refs, acc_ref, sems, state = refs
+    n_buf = sems.shape[1]
     if latent:
-        k_hbm, o_ref, k_buf, sems, parity_ref = refs
-        vc_ref, v_buf = kc_ref, k_buf
-        pools = ((k_hbm, k_buf),)
+        k_hbm, o_ref, *k_bufs = refs
+        vc_ref, v_bufs = kc_ref, k_bufs
+        pools = ((k_hbm, k_bufs),)
     else:
-        vc_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, parity_ref = refs
-        pools = ((k_hbm, k_buf), (v_hbm, v_buf))
+        vc_ref, k_hbm, v_hbm, o_ref, *bufs = refs
+        k_bufs, v_bufs = bufs[:n_buf], bufs[n_buf:]
+        pools = ((k_hbm, k_bufs), (v_hbm, v_bufs))
     b, n_slots = pl.program_id(0), pl.num_programs(0)
     BS, W = k_hbm.shape[1:]
     T = group * BS
-    Hp = q_ref.shape[1] if latent else -(-(W // head_dim) // 16) * 16
+    Hp = acc_ref.shape[0]
 
     def n_blocks(slot):
         return (len_ref[slot] + BS - 1) // BS
 
-    def n_groups(slot):
-        return (n_blocks(slot) + group - 1) // group
-
-    def copies(slot, g, buf, go):
-        """Start (``go``) or await the copies of ``slot``'s group ``g``
-        into buffer ``buf``: only blocks that hold a token."""
-        for i in range(group):
-            @pl.when(g * group + i < n_blocks(slot))
+    def copies(slot, g, held, buf, go):
+        """Start (``go``) or await the copies of ``slot``'s group ``g``,
+        the first ``held`` of its blocks, into buffer ``buf``. One traced
+        body, unrolled where it is lowered (``i`` is a constant there):
+        written out here, a kernel's eleven calls traced 88 conditionals
+        and every engine start paid 2 s for them (PERF.md, PR 36)."""
+        def one(i, _):
+            @pl.when(i < held)
             def _():
-                row = first_ref[0] + bt_ref[slot, g * group + i]
-                for s, (pool, dst) in enumerate(pools):
+                src = first_ref[0] + bt_ref[slot, g * group + i]
+                rows = pl.ds(pl.multiple_of(i * BS, BS), BS)
+                for s, (pool, ring) in enumerate(pools):
                     copy = pltpu.make_async_copy(
-                        pool.at[row], dst.at[buf, pl.ds(i * BS, BS)],
-                        sems.at[s, buf])
+                        pool.at[src], ring[buf].at[rows], sems.at[s, buf])
                     if go:
                         copy.start()
                     else:
                         copy.wait()
 
+        jax.lax.fori_loop(0, group, one, None, unroll=True)
+
+    def fetch_next(buf):
+        """Start the copies of the stream's next group, if it has one,
+        into buffer ``buf``, and move the cursor on; no branch."""
+        at, g = state[1], state[2]
+        slot = jnp.minimum(at, n_slots - 1)
+        held = jnp.where(at < n_slots, n_blocks(slot) - g * group, 0)
+        copies(slot, g, held, buf, True)
+        more = held > group
+        state[1] = jnp.where(more, at,
+                             next_ref[jnp.minimum(at + 1, n_slots)])
+        state[2] = jnp.where(more, g + 1, 0)
+
     @pl.when(b == 0)
     def _():
         # a block the walk does not fetch keeps what its buffer held: a
         # masked score gives it weight 0, and 0 x NaN would still be NaN
-        parity_ref[0] = 0
-        for _, buffer in pools:
-            buffer[...] = jnp.zeros_like(buffer)
+        for _, ring in pools:
+            for buffer in ring:
+                buffer[...] = jnp.zeros_like(buffer)
+        state[0], state[1], state[2] = 0, next_ref[0], 0
+        for buf in range(n_buf - 1):
+            fetch_next(buf)
 
-    parity = parity_ref[0]
-    # the slot before starts this slot's first group, unless it walked
-    # nothing itself
-    @pl.when((b == 0) | (n_groups(jnp.maximum(b - 1, 0)) == 0))
-    def _():
-        copies(b, 0, parity, True)
-
-    length, ng = len_ref[b], n_groups(b)
+    first = state[0]
+    length, held = len_ref[b], n_blocks(b)
+    ng = (held + group - 1) // group
     if latent:
         q_heads = q_ref[0]                                  # [Hp, W]
         qf = q_heads.astype(jnp.float32)
@@ -240,20 +286,15 @@ def _decode_kernel(first_ref, bt_ref, len_ref, q_ref, kc_ref, *refs,
         def own_lanes(x):
             return jnp.where(own, x, 0.0)
 
-    def body(g, carry):
-        m, l, acc = carry
-        buf = (parity + g) % 2
-
-        @pl.when(g + 1 < ng)
-        def _():
-            copies(b, g + 1, 1 - buf, True)
-
-        @pl.when((g + 1 == ng) & (b + 1 < n_slots))
-        def _():
-            copies(b + 1, 0, 1 - buf, True)
-
-        copies(b, g, buf, False)
-        s = _dot_f32(q_heads, k_buf[buf],
+    def turn(buf, g, m, l):
+        """The slot's group ``g``, which lies in buffer ``buf``. The
+        running max and sum go through the switch; the accumulator stays
+        in VMEM, updated where it lies: as an operand, a latent slot's
+        ``[128, W]`` (80 vector registers) was copied in and out of every
+        arm, a third of the turn, while max and sum read back from VMEM
+        put a load at the head of every turn's chain (PERF.md, PR 36)."""
+        copies(b, g, held - g * group, buf, False)
+        s = _dot_f32(q_heads, k_bufs[buf][...],
                      (((1,), (1,)), ((), ()))) * sm_scale   # [Hp, T]
         col = g * T + jax.lax.broadcasted_iota(jnp.int32, (Hp, T), 1)
         s = jnp.where(col < length, s, NEG_INF)
@@ -266,16 +307,24 @@ def _decode_kernel(first_ref, bt_ref, len_ref, q_ref, kc_ref, *refs,
         # probabilities go to the MXU in the pool's dtype, one pass,
         # where a block-diagonal query waits on its copies either way
         # and keeps them whole
-        acc = acc * alpha + _dot_f32(
-            p.astype(v_buf.dtype) if latent else p, v_buf[buf],
+        acc_ref[...] = acc_ref[...] * alpha + _dot_f32(
+            p.astype(v_bufs[buf].dtype) if latent else p, v_bufs[buf][...],
             (((1,), (0,)), ((), ())))
-        return m_new, l_new, acc
+        fetch_next((buf - 1) % n_buf)
+        return m_new, l_new
 
-    m, l, acc = jax.lax.fori_loop(
+    def body(g, carry):
+        return tuple(jax.lax.switch(
+            (first + g) % n_buf,
+            [functools.partial(turn, buf) for buf in range(n_buf)],
+            g, *carry))
+
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m, l = jax.lax.fori_loop(
         0, ng, body, (jnp.full((Hp, 1), NEG_INF, jnp.float32),
-                      jnp.zeros((Hp, 1), jnp.float32),
-                      jnp.zeros((Hp, W), jnp.float32)))
-    parity_ref[0] = (parity + ng) % 2
+                      jnp.zeros((Hp, 1), jnp.float32)))
+    acc = acc_ref[...]
+    state[0] = (first + ng) % n_buf
     # fold the current token (always self-visible, so l can never be 0)
     s_cur = jnp.sum(own_lanes(qf * kc_ref[0].astype(jnp.float32)),
                     axis=1, keepdims=True) * sm_scale       # [Hp, 1]
@@ -290,11 +339,11 @@ def _decode_kernel(first_ref, bt_ref, len_ref, q_ref, kc_ref, *refs,
         o_ref[0] = jnp.sum(own_lanes(acc / l), axis=0, keepdims=True)
 
 
-@functools.partial(jax.jit, static_argnames=("sm_scale", "group",
+@functools.partial(jax.jit, static_argnames=("sm_scale", "group", "ahead",
                                              "interpret", "v_width"))
 def _decode_kernel_call(q, k_cur, v_cur, first_block, k_pool, v_pool,
                         block_tables, past_lens, sm_scale, group=_GROUP,
-                        interpret=False, v_width=None):
+                        ahead=_AHEAD, interpret=False, v_width=None):
     """:func:`_decode_kernel` over ``B`` slots: the pools stay in HBM
     unblocked, the tables and lengths go in by scalar prefetch. So does
     the layer's first row, and the call is a jit of its own: every layer
@@ -310,12 +359,18 @@ def _decode_kernel_call(q, k_cur, v_cur, first_block, k_pool, v_pool,
 
     row_spec = pl.BlockSpec((1, 1, W), lambda b, *_: (b, 0, 0))
     pool_spec = pl.BlockSpec(memory_space=pl.ANY)
+    past_lens = past_lens.astype(jnp.int32)
+    # the first slot from each on that holds a token, ``B`` past the last
+    walked = jnp.where(past_lens > 0, jnp.arange(B, dtype=jnp.int32), B)
     prefetched = (jnp.asarray(first_block, jnp.int32).reshape(1),
-                  block_tables.astype(jnp.int32),
-                  past_lens.astype(jnp.int32))
+                  block_tables.astype(jnp.int32), past_lens,
+                  jnp.append(jax.lax.cummin(walked, reverse=True),
+                             jnp.int32(B)))
+    # the query matrix's rows: whole sublane tiles of heads (of the lanes'
+    # heads, pad lanes included, where a row holds them)
+    Hp = -(-(H if latent else W // q.shape[-1]) // 16) * 16
     if latent:
-        # the slot's queries as dense rows: whole sublane tiles of them
-        Hp = -(-H // 16) * 16
+        # the slot's queries as dense rows
         q_spec = pl.BlockSpec((1, Hp, W), lambda b, *_: (b, 0, 0))
         rows = (jnp.pad(q, ((0, 0), (0, Hp - H), (0, W - q.shape[-1]))),
                 lane_rows(k_cur))
@@ -325,23 +380,27 @@ def _decode_kernel_call(q, k_cur, v_cur, first_block, k_pool, v_pool,
         rows = (lane_rows(q), lane_rows(k_cur), lane_rows(v_cur))
         pool_args = (k_pool, v_pool)
         in_specs = [row_spec, row_spec, row_spec, pool_spec, pool_spec]
-    n = len(pool_args)
     out = pl.pallas_call(
         functools.partial(_decode_kernel, sm_scale=sm_scale,
                           head_dim=None if latent else q.shape[-1],
                           group=group),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=4,
             grid=(B,),
             in_specs=in_specs,
             out_specs=q_spec,
-            scratch_shapes=[pltpu.VMEM((2, group * BS, W), pool.dtype)
-                            for pool in pool_args]
-            + [pltpu.SemaphoreType.DMA((n, 2)),
-               pltpu.SMEM((1,), jnp.int32)]),
+            # a ring of ``ahead + 1`` buffers a pool, each an allocation
+            # of its own; one DMA semaphore a buffer
+            scratch_shapes=[pltpu.VMEM((group * BS, W), pool.dtype)
+                            for pool in pool_args
+                            for _ in range(ahead + 1)]
+            # the slot's accumulator
+            + [pltpu.VMEM((Hp, W), jnp.float32),
+               pltpu.SemaphoreType.DMA((len(pool_args), ahead + 1)),
+               pltpu.SMEM((3,), jnp.int32)]),
         out_shape=jax.ShapeDtypeStruct((B,) + q_spec.block_shape[1:],
                                        jnp.float32),
-        # slots in order on one core: each starts the next one's copies
+        # slots in order on one core: the copies run ahead across them
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         name="paged_decode",

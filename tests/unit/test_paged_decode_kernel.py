@@ -3,8 +3,13 @@ the TPU (serving/paged_attention.py), in interpret mode: bfloat16 pools
 and queries as the chip holds them, the comparison at float32 rounding.
 
 The batches are chosen for the kernel's own control flow: a slot walks
-its own ``ceil(len / 16)`` blocks in groups of ``_GROUP``, and the slot
-before it starts its first group unless that one walked nothing.
+its own ``ceil(len / 16)`` blocks in groups of ``_GROUP``, and a slot
+that holds nothing walks none.
+
+The copies follow one stream of groups over all slots, ``_AHEAD`` groups
+fetched or in flight beside the one reduced in a ring of ``_AHEAD + 1``
+buffers (PR 36): the ``STREAMS`` batches are chosen for what that
+schedule can get wrong, at both row forms.
 
 The jnp walk itself, the one loop behind decode, prefill and verify, is
 held to a dense masked softmax over a contiguous view of the pool, at
@@ -72,6 +77,85 @@ def test_kernel_matches_the_jnp_loop(shape, batch, first_layer):
     got = pa._decode_kernel_call(*args, D ** -0.5, interpret=True)
     assert got.shape == want.shape == (B, H, D) and got.dtype == jnp.float32
     # values are O(1); bfloat16 rounding of a probability would show as 4e-3
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=0, atol=4e-6)
+
+
+RING = 6        # the deepest ring tried below
+# the stream against the ring: lengths in BLOCKS, up to RING + 1 groups
+STREAMS = {
+    # more empty slots in a row than the ring is deep, first and last:
+    # the cursor skips them at the start, and past the last there is
+    # nothing to fetch
+    "empty-runs-longer-than-the-ring": (
+        [0] * (RING + 1) + [G + 1, 1, 2 * G] + [0] * (RING + 1)),
+    # a slot with more groups than the ring is deep, then one-block
+    # slots: the ring wraps inside the slot and then spans several slots
+    "long-slot-then-one-block-slots": (
+        [(RING + 1) * G] + [1] * (RING + 2)),
+    # every slot one block: every look-ahead crosses RING - 1 slots
+    "every-slot-one-block": [1] * (2 * RING + 1),
+    # the last slot's last group is the stream's end, the ring still full
+    "stream-ends-in-a-long-slot": [1, 0, G, (RING + 1) * G - 3],
+    # a stream shorter than the ring: the start fetches all there is
+    "stream-shorter-than-the-ring": [0, 2, 0, 0],
+}
+FORMS = {"heads-w1024-h16": (1024, 16), "heads-w1664-h25": (1600, 25),
+         "latent-w256-h8": (160, 8)}
+# the module's ring at every form; a ring of two and one of ``RING``
+# (the turn's switch then has other arms) at one
+RINGS = [(form, pa._AHEAD) for form in FORMS] + [
+    ("heads-w1024-h16", 1), ("latent-w256-h8", 5)]
+
+
+@pytest.mark.parametrize("stream", list(STREAMS))
+@pytest.mark.parametrize("form,ahead", RINGS,
+                         ids=[f"{f}-ahead{a}" for f, a in RINGS])
+def test_the_stream_of_groups_across_slots(form, ahead, stream):
+    """Both row forms through the ring: one stream of groups, whatever
+    slot they belong to."""
+    E, H = FORMS[form]
+    latent = form.startswith("latent")
+    W = -(-E // 128) * 128
+    blocks = STREAMS[stream]
+    B, MB = len(blocks), max(blocks) + 1
+    n = 1 + sum(blocks)                         # blocks a layer
+    rng = np.random.default_rng(len(stream) + E)
+    # a last block is part full, but for the first slot's
+    lens = [max(0, nb * BS - (3 * b) % BS) for b, nb in enumerate(blocks)]
+
+    def pool():     # two layers' rows, the pad lanes zero as written
+        rows = rng.standard_normal((2 * n, BS, E))
+        return jnp.asarray(np.pad(rows, ((0, 0), (0, 0), (0, W - E))),
+                           jnp.bfloat16)
+
+    bt = np.zeros((B, MB), np.int32)
+    free = iter(rng.permutation(np.arange(1, n)))
+    for b, nb in enumerate(blocks):
+        for i in range(nb):
+            bt[b, i] = next(free)
+    tables = (jnp.asarray(bt), jnp.asarray(lens, jnp.int32))
+    if latent:
+        V, scale = 128, E ** -0.5
+        q = jnp.asarray(rng.standard_normal((B, H, E)), jnp.bfloat16)
+        row = jnp.asarray(rng.standard_normal((B, E)), jnp.bfloat16)
+        args = (q, row, None, n, pool(), None, *tables)
+        want = pa.paged_chunk_attention(
+            q[:, :, None], row[:, None], None, *args[3:], sm_scale=scale,
+            v_width=V)[:, :, 0]
+        got = pa._decode_kernel_call(*args, scale, ahead=ahead,
+                                     interpret=True, v_width=V)
+        assert got.shape == want.shape == (B, H, V)
+    else:
+        D = E // H
+        q, k_cur, v_cur = (jnp.asarray(rng.standard_normal((B, H, D)),
+                                       jnp.bfloat16) for _ in range(3))
+        args = (q, k_cur, v_cur, n, pool(), pool(), *tables)
+        want = _walk_one_token(*args)
+        got = pa._decode_kernel_call(*args, D ** -0.5, ahead=ahead,
+                                     interpret=True)
+        assert got.shape == want.shape == (B, H, D)
+    assert got.dtype == jnp.float32
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=0, atol=4e-6)
 

@@ -265,3 +265,43 @@ def test_latent_program_leaves_its_one_pool_in_place(one_chip, monkeypatch,
     assert not copies, "whole-pool copies:\n" + "\n".join(copies)
     if program == "decode":
         assert mem.temp_size_in_bytes < 64e6
+
+
+# ------------------- the decode kernel at the serve cells' slots (PR 36)
+# slots, table width and blocks a layer as benchmark/configs/*.json run
+# them; the ring of VMEM buffers has to fit the default scoped VMEM
+# limit (16 MiB) beside a slot's pipelined rows
+KERNEL_CELLS = {
+    "gpt2-medium-384-slots": dict(B=384, H=16, W=1024, MB=64, N=7305),
+    "gpt2-xl-96-slots": dict(B=96, H=25, W=1664, MB=64, N=1949),
+    "dots-latent-96-slots": dict(B=96, H=128, W=640, MB=288, N=10724),
+}
+
+
+@pytest.mark.parametrize("cell", list(KERNEL_CELLS))
+def test_decode_kernel_fits_the_chip_at_the_cells_slots(one_chip, cell):
+    """One layer's ``paged_decode`` call alone, compiled for the v5e at
+    the slot counts the serve cells run (the programs above keep the 40
+    and 8 slots their temporaries were recorded at): Mosaic accepts the
+    ring under the limit it has, no limit is raised, and the call is
+    one kernel."""
+    c = KERNEL_CELLS[cell]
+    spec = _spec(one_chip)
+    B, H, W = c["B"], c["H"], c["W"]
+    tables = (spec((B, c["MB"]), jnp.int32), spec((B,), jnp.int32))
+    pool = spec((2 * c["N"], BLOCK_SIZE, W), jnp.bfloat16)
+    if cell.startswith("dots"):
+        args = (spec((B, H, 576), jnp.bfloat16), spec((B, 576), jnp.bfloat16),
+                None, spec((), jnp.int32), pool, None, *tables, 576 ** -0.5)
+        kw = dict(v_width=512)
+    else:
+        row = spec((B, H, 64), jnp.bfloat16)
+        args = (row, row, row, spec((), jnp.int32), pool, pool, *tables,
+                0.125)
+        kw = {}
+    compiled = paged_attention._decode_kernel_call.lower(
+        *args, **kw).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "vmem_limit_bytes" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 8e6
