@@ -5,7 +5,7 @@ machinery, which top-level ``import deepspeed_tpu`` should not pay for."""
 
 import importlib
 
-_SUBMODULES = ("adam", "adagrad", "lamb", "aio", "quantizer",
+_SUBMODULES = ("adam", "adagrad", "lamb", "aio", "quantizer", "ssm",
                "sparse_attention", "transformer", "op_builder")
 
 

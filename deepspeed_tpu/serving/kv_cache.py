@@ -34,7 +34,8 @@ Split of responsibilities:
   fires.
 * ``PagedKVCache`` — owns the device pools (K and V as token rows, and
   for the int8 KV layout the per-row fp32 scales; for latent attention
-  ONE pool ``kv`` whose row is the token's latent) plus the one write
+  ONE pool ``kv`` whose row is the token's latent; for layers that carry
+  a state, pools of one row a slot beside them) plus the one write
   the runner traces into a compiled step: ``write_layers`` (one scatter
   per pool for a step's tokens in the layers that ran). The reads are
   serving/paged_attention.py's: they walk the blocks that hold tokens
@@ -399,6 +400,14 @@ class PagedKVCache:
       ``write_layers``, the block copy, the prefix cache's salt)
       follows the SET of pools and assumes no name.
 
+    Those are the ``paged`` pools. With ``slot_state`` (name -> the shape
+    of one slot's state a layer and its dtype, None for the activation
+    dtype) the cache also holds ``per_slot`` pools ``[state_layers,
+    slots, ...]``: a row a slot and layer, never paged, never shared, and
+    updated in place by the layer that owns it (serving/runner.py). The
+    block copy and the prefix cache's salt take the paged pools alone;
+    ``pool_bytes`` and ``init_pools`` take both kinds (:meth:`pool_kinds`).
+
     Why this shape: on the TPU an array lives in tiles of 8 sublanes by
     128 lanes (16 rows of bf16), and with ``block_size`` rows of whole
     lanes per block the pool's device layout is plain row-major. The
@@ -421,7 +430,8 @@ class PagedKVCache:
     """
 
     def __init__(self, n_layer, n_head, head_dim, block_size, num_blocks,
-                 dtype=jnp.float32, int8_kv=False, latent_width=0):
+                 dtype=jnp.float32, int8_kv=False, latent_width=0,
+                 slot_state=None, state_layers=0, slots=0):
         if latent_width and int8_kv:
             raise NotImplementedError(
                 "int8 latent pools are not served: a latent row has no "
@@ -438,6 +448,10 @@ class PagedKVCache:
         self.num_blocks = int(num_blocks)
         self.int8_kv = bool(int8_kv)
         self.dtype = jnp.int8 if int8_kv else dtype
+        self.slot_state = {
+            name: ((int(state_layers), int(slots)) + tuple(shape),
+                   dtype if state_dtype is None else state_dtype)
+            for name, (shape, state_dtype) in (slot_state or {}).items()}
         self.allocator = BlockAllocator(num_blocks)
         # shared-prefix index (None = prefix caching off). The scheduler
         # reads this attribute; the server attaches it from the
@@ -453,7 +467,9 @@ class PagedKVCache:
             self.allocator, self.block_size,
             capacity_blocks=capacity_blocks,
             salt="/".join([jnp.dtype(self.dtype).name]
-                          + sorted(self._pool_shapes())))
+                          + sorted(name for name, kind
+                                   in self.pool_kinds().items()
+                                   if kind == "paged")))
         return self.prefix_cache
 
     # -------------------------------------------------- pool construction
@@ -466,13 +482,19 @@ class PagedKVCache:
         if self.int8_kv:
             shapes["k_scale"] = (rows + (self.scale_width,), jnp.float32)
             shapes["v_scale"] = (rows + (self.scale_width,), jnp.float32)
-        return shapes
+        return {**shapes, **self.slot_state}
+
+    def pool_kinds(self) -> dict:
+        """Pool name -> ``paged`` (token rows in blocks) or ``per_slot``
+        (one row a slot and layer)."""
+        return {name: "per_slot" if name in self.slot_state else "paged"
+                for name in self._pool_shapes()}
 
     def init_pools(self, sharding=None):
         """Zeroed device pools; pass through the jitted step and thread
         the returned (donated) pools back in. Two leaves (four with int8
-        KV), whatever the depth: the dispatch call does not grow with
-        the layer count."""
+        KV; two more with per-slot state), whatever the depth: the
+        dispatch call does not grow with the layer count."""
         pools = {name: jnp.zeros(shape, dtype)
                  for name, (shape, dtype) in self._pool_shapes().items()}
         # COMMIT the arrays (to the caller's sharding — the server passes
@@ -484,11 +506,13 @@ class PagedKVCache:
             pools, sharding if sharding is not None
             else jax.local_devices()[0])
 
-    def pool_bytes(self) -> int:
-        """Total HBM the pools occupy (for the serving metrics), the pad
-        lanes of ``row_width`` included."""
+    def pool_bytes(self, kind=None) -> int:
+        """HBM the pools occupy (for the serving metrics), the pad lanes
+        of ``row_width`` included: all of them, or those of one kind."""
+        kinds = self.pool_kinds()
         return sum(int(np.prod(shape)) * jnp.dtype(dtype).itemsize
-                   for shape, dtype in self._pool_shapes().values())
+                   for name, (shape, dtype) in self._pool_shapes().items()
+                   if kind in (None, kinds[name]))
 
     def layer_rows(self, block_ids, first_layer=0, n_layers=1):
         """Pool rows of ``block_ids`` in ``n_layers`` consecutive layers:

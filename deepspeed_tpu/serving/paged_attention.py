@@ -34,15 +34,17 @@ from the platform, the pool's dtype and the mesh's size (no option):
 
 Both serve two row forms, told apart by the pools they are handed and by
 nothing else. **Heads in lanes** (``k`` and ``v`` pools): a row holds
-``H`` heads of ``D`` lanes, a query is one row, head ``h`` reads its own
-lanes. **One latent a token** (one pool, ``v_pool=None``; MLA with the
-key's up-projection absorbed into the query): a row is the token's
-latent and its rotated shared key, EVERY head's key, and in its first
-``v_width`` lanes every head's value; a slot's query is ``[H, W']`` dense
-rows. To the kernel both are the same product, a ``[Hp, W]`` query matrix
-against whole ``W``-lane rows: block-diagonal in the first form, dense in
-the second, which keeps the whole ``[H, W]`` result where the first reads
-each head's diagonal lanes.
+``K`` heads of ``D`` lanes, and query head ``h`` reads the lanes of KV
+head ``h // G`` (grouped-query attention, ``G = H / K`` queries a KV
+head; multi-head attention is the group of 1). **One latent a token**
+(one pool, ``v_pool=None``; MLA with the key's up-projection absorbed
+into the query): a row is the token's latent and its rotated shared key,
+EVERY head's key, and in its first ``v_width`` lanes every head's value;
+a slot's query is ``[H, W']`` dense rows. To the kernel both are the
+same product, a ``[Hp, W]`` query matrix against whole ``W``-lane rows:
+block-diagonal in the first form, dense in the second, which keeps the
+whole ``[H, W]`` result where the first reads each head's diagonal
+lanes.
 
 Both attend over the PAST pool only and fold the current token/chunk
 from registers (an intra-chunk causal piece merged in). That lets the
@@ -116,8 +118,9 @@ def paged_decode_attention(q, k_cur, v_cur, first_block, k_pool, v_pool,
     :func:`decode_kernel_runs`, else :func:`paged_chunk_attention` at
     ``C = 1``.
 
-    q/k_cur/v_cur: ``[B, H, D]`` (the current token's K/V stay in
-    registers — the pool write is deferred); the other arguments as
+    q ``[B, H, D]``, k_cur/v_cur ``[B, K, D]`` (``K`` divides ``H``; the
+    current token's K/V stay in registers — the pool write is deferred);
+    the other arguments as
     :func:`paged_chunk_attention`'s. Returns ``[B, H, D]`` fp32. With
     ``v_pool=None`` (one latent a token): q ``[B, H, W']``, k_cur
     ``[B, W']`` the token's own row, ``v_cur`` unused; returns
@@ -194,10 +197,13 @@ def _decode_kernel(first_ref, bt_ref, len_ref, next_ref, q_ref, kc_ref,
     of a matrix ``[Hp, W]``, so scores are ``[Hp, T]`` from one matmul
     against the K rows and ``P @ V`` is ``[Hp, W]``. With heads in lanes
     (``head_dim`` given; refs: the current V row, the K and V pools, the
-    output, a ring a pool) the matrix is block-diagonal (row ``h``
-    holds the query's lanes ``h*D..(h+1)*D``, zero elsewhere) and row
-    ``h`` of the result is read in head ``h``'s lanes alone; pad lanes
-    and pad heads meet zeros of the query and are never read back. With
+    output, a ring a pool) the matrix is block-diagonal: with ``Kr = W //
+    D`` lane heads and ``G`` queries a KV head (the query block's rows),
+    row ``j*Kr + k`` holds query ``j`` of KV head ``k`` in that head's
+    lanes ``k*D..(k+1)*D``, zero elsewhere, and is read back in those
+    lanes alone, so that output row ``j`` is the sum of rows ``j*Kr ..
+    (j+1)*Kr``; pad lanes and pad heads meet zeros of the query and are
+    never read back. With
     one latent a token (``head_dim`` None; refs: the one pool, the
     output, one ring) the matrix is the slot's dense ``[Hp, W]``
     query, the V rows ARE the K rows, and the whole result is kept (the
@@ -277,10 +283,19 @@ def _decode_kernel(first_ref, bt_ref, len_ref, next_ref, q_ref, kc_ref,
         def own_lanes(x):
             return x
     else:
+        per_kv, kv_rows = q_ref.shape[1], W // head_dim
         row = jax.lax.broadcasted_iota(jnp.int32, (Hp, W), 0)
         lane = jax.lax.broadcasted_iota(jnp.int32, (Hp, W), 1)
-        own = (lane >= row * head_dim) & (lane < (row + 1) * head_dim)
-        qf = q_ref[0].astype(jnp.float32)                       # [1, W]
+        # row j*Kr + k: query j of KV head k; pad rows own no lane
+        head = row if per_kv == 1 else jnp.where(
+            row < per_kv * kv_rows, row % kv_rows, kv_rows)
+        own = (lane >= head * head_dim) & (lane < (head + 1) * head_dim)
+        qf = q_ref[0].astype(jnp.float32)                       # [G, W]
+        if per_kv > 1:
+            pad = [jnp.zeros((Hp - per_kv * kv_rows, W), jnp.float32)]
+            qf = jnp.concatenate(
+                [jnp.broadcast_to(qf[j:j + 1], (kv_rows, W))
+                 for j in range(per_kv)] + pad[:Hp > per_kv * kv_rows])
         q_heads = jnp.where(own, qf, 0.0).astype(q_ref.dtype)   # [Hp, W]
 
         def own_lanes(x):
@@ -335,8 +350,13 @@ def _decode_kernel(first_ref, bt_ref, len_ref, next_ref, q_ref, kc_ref,
     acc = acc * alpha + p_cur * vc_ref[0].astype(jnp.float32)
     if latent:
         o_ref[0] = acc / l
-    else:
+    elif per_kv == 1:
         o_ref[0] = jnp.sum(own_lanes(acc / l), axis=0, keepdims=True)
+    else:
+        out = own_lanes(acc / l)
+        o_ref[0] = jnp.concatenate(
+            [jnp.sum(out[j * kv_rows:(j + 1) * kv_rows], axis=0,
+                     keepdims=True) for j in range(per_kv)])
 
 
 @functools.partial(jax.jit, static_argnames=("sm_scale", "group", "ahead",
@@ -353,8 +373,8 @@ def _decode_kernel_call(q, k_cur, v_cur, first_block, k_pool, v_pool,
     BS, W = k_pool.shape[1:]
     latent = v_pool is None
 
-    def lane_rows(x):           # [B, ...] -> [B, 1, W], zero pad lanes
-        x = x.reshape(B, 1, -1)
+    def lane_rows(x, n=1):      # [B, ...] -> [B, n, W], zero pad lanes
+        x = x.reshape(B, n, -1)
         return jnp.pad(x, ((0, 0), (0, 0), (0, W - x.shape[-1])))
 
     row_spec = pl.BlockSpec((1, 1, W), lambda b, *_: (b, 0, 0))
@@ -366,9 +386,11 @@ def _decode_kernel_call(q, k_cur, v_cur, first_block, k_pool, v_pool,
                   block_tables.astype(jnp.int32), past_lens,
                   jnp.append(jax.lax.cummin(walked, reverse=True),
                              jnp.int32(B)))
+    # queries a KV head: the query block's rows (heads in lanes)
+    per_kv = 1 if latent else H // k_cur.shape[1]
     # the query matrix's rows: whole sublane tiles of heads (of the lanes'
-    # heads, pad lanes included, where a row holds them)
-    Hp = -(-(H if latent else W // q.shape[-1]) // 16) * 16
+    # heads, pad lanes included, where a row holds them, times the group)
+    Hp = -(-(H if latent else W // q.shape[-1] * per_kv) // 16) * 16
     if latent:
         # the slot's queries as dense rows
         q_spec = pl.BlockSpec((1, Hp, W), lambda b, *_: (b, 0, 0))
@@ -376,10 +398,13 @@ def _decode_kernel_call(q, k_cur, v_cur, first_block, k_pool, v_pool,
                 lane_rows(k_cur))
         pool_args, in_specs = (k_pool,), [q_spec, row_spec, pool_spec]
     else:
-        q_spec = row_spec
-        rows = (lane_rows(q), lane_rows(k_cur), lane_rows(v_cur))
+        D = q.shape[-1]
+        q_spec = pl.BlockSpec((1, per_kv, W), lambda b, *_: (b, 0, 0))
+        if per_kv > 1:      # row j: query j of every KV head, in its lanes
+            q = jnp.swapaxes(q.reshape(B, H // per_kv, per_kv, D), 1, 2)
+        rows = (lane_rows(q, per_kv), lane_rows(k_cur), lane_rows(v_cur))
         pool_args = (k_pool, v_pool)
-        in_specs = [row_spec, row_spec, row_spec, pool_spec, pool_spec]
+        in_specs = [q_spec, row_spec, row_spec, pool_spec, pool_spec]
     out = pl.pallas_call(
         functools.partial(_decode_kernel, sm_scale=sm_scale,
                           head_dim=None if latent else q.shape[-1],
@@ -408,8 +433,10 @@ def _decode_kernel_call(q, k_cur, v_cur, first_block, k_pool, v_pool,
     )(*prefetched, *rows, *pool_args)
     if latent:
         return out[:, :H, :v_width]
-    D = q.shape[-1]
-    return out[:, 0, :H * D].reshape(B, H, D)
+    if per_kv == 1:
+        return out[:, 0, :H * D].reshape(B, H, D)
+    out = out.reshape(B, per_kv, W // D, D)[:, :, :H // per_kv]
+    return jnp.swapaxes(out, 1, 2).reshape(B, H, D)
 
 
 @jax.named_scope("paged_attention")
@@ -427,8 +454,9 @@ def paged_chunk_attention(q, k_chunk, v_chunk, first_block, k_pool, v_pool,
     chunk (whose pad-tail queries produce rows that are discarded),
     ``C = K+1`` a speculative verify.
 
-    q/k_chunk/v_chunk: ``[B, H, C, D]`` (query c sits at absolute
-    position ``past_lens[b] + c``); pools: the ``[L*N, BS, W]`` row
+    q ``[B, H, C, D]`` (query c sits at absolute position ``past_lens[b]
+    + c``), k_chunk/v_chunk ``[B, K, C, D]`` (query head ``h`` meets KV
+    head ``h // (H / K)``); pools: the ``[L*N, BS, W]`` row
     arrays; first_block: the pool row of this layer's block 0
     (``layer * num_blocks``); block_tables: ``[B, MB]`` int32; past_lens:
     ``[B]`` int32 tokens ALREADY in the pool. Returns ``[B, H, C, D]``
@@ -488,11 +516,20 @@ def paged_chunk_attention(q, k_chunk, v_chunk, first_block, k_pool, v_pool,
     else:
         acc_shape, per_trip = q.shape, 1
         n_trips = n_blocks
+        kv_heads = k_chunk.shape[-3]
+        group = H // kv_heads
+
+        def repeat(kv, axis):       # each KV head once for each of its queries
+            return kv if group == 1 else jnp.repeat(kv, group, axis=axis)
+
+        k_chunk, v_chunk = repeat(k_chunk, -3), repeat(v_chunk, -3)
 
         def keys_values(i):
             rows = first_block + block_tables[..., i]
-            return (_read_blocks(k_pool, k_scale_pool, rows, H, D),
-                    _read_blocks(v_pool, v_scale_pool, rows, H, D))
+            return (repeat(_read_blocks(k_pool, k_scale_pool, rows,
+                                        kv_heads, D), -2),
+                    repeat(_read_blocks(v_pool, v_scale_pool, rows,
+                                        kv_heads, D), -2))
 
         def scores(kb):                                 # kb [B,BS,H,D]
             return jnp.einsum("...hcd,...shd->...hcs", qf, kb)

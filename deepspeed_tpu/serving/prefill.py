@@ -63,7 +63,7 @@ class ChunkedPrefill:
         bt_row[:len(req.block_table)] = req.block_table
         pools = self.prefill_fn(
             params, scales, pools, bt_row, tokens,
-            np.int32(start), np.int32(n_valid))
+            np.int32(start), np.int32(n_valid), np.int32(req.slot))
         req.cached_len += n_valid
         n_recompute = max(0, min(start + n_valid,
                                  getattr(req, "max_cached_len", 0)) - start)

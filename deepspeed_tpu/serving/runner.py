@@ -10,7 +10,7 @@ tokens at their own positions, walks the blocks, attends over each
 slot's past pages plus the chunk itself, writes the cache rows of every
 layer that ran in one scatter per pool, and returns the logits. The
 model's configuration says which of the two block definitions the
-forward walks (:func:`serving_family`; no option and no model's name):
+forward walks (no option and no model's name):
 
 * GPT-2's (:class:`_GPT2Blocks`): LayerNorm, fused QKV with heads of
   ``D`` lanes, learned positions, GELU MLP, tied head; K and V pools.
@@ -21,6 +21,11 @@ forward walks (:func:`serving_family`; no option and no model's name):
   positions at each token's own offset (YaRN frequencies), SwiGLU, and
   the expert layer of moe/held_experts.py over the experts this chip
   holds; untied head; one ``kv`` pool.
+* the state-space hybrid (:class:`_SSMHybridBlocks`; models/ssm_hybrid.py):
+  RMSNorm, SwiGLU, and as ``layer_types`` says a Mamba-2 mixer, whose
+  per-slot state (ops/ssm) a layer reads and writes back where it lies
+  inside the layer loop, or grouped-query attention with no positions
+  over K and V pools that hold the attention layers alone; tied head.
 
 The four compiled programs are the forward's callers:
 
@@ -40,7 +45,9 @@ The four compiled programs are the forward's callers:
 Which attention runs is read off the static shape, not an option: one
 query a slot goes to ``paged_decode_attention`` (the Pallas kernel on a
 TPU over bfloat16 pools under one device, else the jnp walk), a chunk to
-the jnp walk (serving/paged_attention.py states the rule).
+the jnp walk (serving/paged_attention.py states the rule). So for the
+state: one token a slot is ``ssm_decode`` (or its jnp form), a chunk the
+chunked scan.
 
 Weight formats: float kernels and the engine's TRUE int8 weight storage
 (module_quantize ``quant_scales`` collection) both work — the dequant
@@ -51,7 +58,8 @@ What is not served is refused at construction with an error that names
 the mechanism (:class:`ServingNotSupported`), never mid-step: a GPT-2
 tree with rotary positions or experts in its blocks, pipeline stages,
 ring / Ulysses / block-sparse attention; over a latent cache, int8
-pools and int8 weights.
+pools and int8 weights; for a model with per-slot state, grouped ``B`` and
+``C`` (``mamba_n_groups`` > 1); the server refuses what it composes with.
 """
 
 import functools
@@ -60,6 +68,7 @@ import jax
 import jax.numpy as jnp
 
 from deepspeed_tpu.moe.held_experts import held_expert_mlp, route
+from deepspeed_tpu.ops import ssm
 from deepspeed_tpu.ops.quantizer.int8_linear import int8_matmul
 from deepspeed_tpu.ops.transformer.decode import quantize_kv
 from deepspeed_tpu.serving.paged_attention import (paged_chunk_attention,
@@ -111,24 +120,48 @@ def serves_latent(cfg) -> bool:
     return hasattr(cfg, "kv_lora_rank")
 
 
+def serves_state(cfg) -> bool:
+    """Whether ``cfg`` describes layers that carry a per-slot state
+    (Mamba-2: models/ssm_hybrid.py), read from what it declares."""
+    return hasattr(cfg, "mamba_layers")
+
+
 def _require_gpt2_like(cfg):
     for attr in ("n_layer", "n_head", "n_embd", "n_positions",
                  "vocab_size"):
         if not hasattr(cfg, attr):
             raise ServingNotSupported(
                 f"serving needs a GPT2Config-like or a latent-attention "
-                f"model config (missing {attr!r}); got "
-                f"{type(cfg).__name__}")
+                f"model config, or a state-space hybrid (missing "
+                f"{attr!r}); got {type(cfg).__name__}")
 
 
 def cache_rows(cfg) -> dict:
-    """What ``PagedKVCache`` needs to know of the model's cache rows."""
+    """What ``PagedKVCache`` needs to know of the model's cache rows (and,
+    with per-slot state, of each slot's state a layer)."""
     if serves_latent(cfg):
         return dict(n_head=cfg.num_attention_heads,
                     head_dim=cfg.qk_nope_head_dim + cfg.qk_rope_head_dim,
                     latent_width=cfg.latent_width)
+    if serves_state(cfg):
+        rows = ssm.packed_rows(cfg.mamba_n_heads, cfg.mamba_d_head)
+        taps = cfg.mamba_d_conv - 1
+        return dict(n_head=cfg.num_key_value_heads, head_dim=cfg.head_dim,
+                    slot_state={
+                        "ssm": ((rows, cfg.mamba_d_state, ssm.scan.LANES),
+                                jnp.float32),
+                        "conv": ((taps * cfg.conv_channels,), None)})
     _require_gpt2_like(cfg)
     return dict(n_head=cfg.n_head, head_dim=cfg.n_embd // cfg.n_head)
+
+
+def cache_layers(cfg) -> dict:
+    """Layers whose tokens the paged pools hold, and layers that carry a
+    per-slot state: ``{"paged": n, "per_slot": m}``."""
+    if serves_state(cfg):
+        return {"paged": len(cfg.attention_layers),
+                "per_slot": len(cfg.mamba_layers)}
+    return {"paged": cfg.n_layer, "per_slot": 0}
 
 
 class _GPT2Blocks:
@@ -189,40 +222,21 @@ class _GPT2Blocks:
     def attend(self, layer, pools, bt, past_lens, C, q, k, v):
         """Rows ``[B*C, H, D]`` of one layer's q/k/v over each slot's PAST
         pages plus the chunk from registers; returns ``[B*C, H, D]``
-        fp32. The shape decides what runs: a single query a slot is a
-        decode step (the kernel where it can run), a chunk the jnp
-        walk."""
+        fp32 (:func:`_attend_in_lanes`)."""
         int8 = self.cache.int8_kv
-        first_block = layer * self.cache.num_blocks
-        scale_pools = dict(
+        return _attend_in_lanes(
+            layer * self.cache.num_blocks, pools, bt, past_lens, C, q,
+            self._requant(k), self._requant(v),
             k_scale_pool=pools["k_scale"] if int8 else None,
             v_scale_pool=pools["v_scale"] if int8 else None)
-        k, v = self._requant(k), self._requant(v)
-        if C == 1:
-            return paged_decode_attention(q, k, v, first_block, pools["k"],
-                                          pools["v"], bt, past_lens,
-                                          **scale_pools)
-        N, H, D = q.shape
-        # a lone slot (a prefill chunk) goes without its batch dimension:
-        # with it gpt2-medium's prefill program took 2.122 ms, without
-        # it 2.050 (PERF.md, PR 32)
-        lead = () if N == C else (N // C,)
 
-        def heads(t):                                   # -> [.., H, C, D]
-            return jnp.moveaxis(t.reshape(lead + (C, H, D)), -3, -2)
-
-        out = paged_chunk_attention(
-            heads(q), heads(k), heads(v), first_block, pools["k"],
-            pools["v"], bt.reshape(lead + bt.shape[1:]),
-            past_lens.reshape(lead), **scale_pools)
-        return jnp.moveaxis(out, -3, -2).reshape(N, H, D)
-
-    def block(self, layer, params, scales, x, pos, real, attend):
+    def block(self, layer, params, scales, x, pos, real, attend, pools,
+              slot):
         """One transformer block over rows ``x [N, E]``: pre-LN
         attention (``attend(q, k, v)`` over ``[N, H, D]``) and the GELU
         MLP, each added to the residual. Returns the rows, the layer's
-        ``k`` and ``v``, which the forward writes to the pools, and no
-        expert counts."""
+        ``k`` and ``v``, which the forward writes to the pools, no
+        expert counts and the pools as they were."""
         p, s = params[f"h_{layer}"], _sub(scales, f"h_{layer}")
         N, E = x.shape
         H, D = self.n_head, self.head_dim
@@ -234,7 +248,33 @@ class _GPT2Blocks:
         h = jax.nn.gelu(_dense(_ln(x, p["ln_2"]), p["mlp"]["fc"],
                                _sub(s, "mlp", "fc")), approximate=True)
         x = x + _dense(h, p["mlp"]["proj"], _sub(s, "mlp", "proj"))
-        return x, {"k": k, "v": v}, None
+        return x, {"k": k, "v": v}, None, pools
+
+
+def _attend_in_lanes(first_block, pools, bt, past_lens, C, q, k, v,
+                     **kw):
+    """Heads in lanes over the ``k`` and ``v`` pools: q ``[B*C, H, D]``
+    and k/v ``[B*C, K, D]`` (``K`` divides ``H``: grouped queries) of one
+    layer over each slot's PAST pages plus the chunk from registers;
+    returns ``[B*C, H, D]`` fp32. The shape decides what runs: a single
+    query a slot is a decode step (the kernel where it can run), a chunk
+    the jnp walk."""
+    if C == 1:
+        return paged_decode_attention(q, k, v, first_block, pools["k"],
+                                      pools["v"], bt, past_lens, **kw)
+    N, H, D = q.shape
+    # a lone slot (a prefill chunk) goes without its batch dimension:
+    # with it gpt2-medium's prefill program took 2.122 ms, without
+    # it 2.050 (PERF.md, Findings)
+    lead = () if N == C else (N // C,)
+
+    def heads(t):                                       # -> [.., h, C, D]
+        return jnp.moveaxis(t.reshape(lead + (C, t.shape[1], D)), -3, -2)
+
+    out = paged_chunk_attention(
+        heads(q), heads(k), heads(v), first_block, pools["k"], pools["v"],
+        bt.reshape(lead + bt.shape[1:]), past_lens.reshape(lead), **kw)
+    return jnp.moveaxis(out, -3, -2).reshape(N, H, D)
 
 
 def _rms(x, gain, eps):
@@ -308,12 +348,14 @@ class _MLAMoEBlocks:
             **kw)
         return jnp.moveaxis(out, -3, -2).reshape(N, H, -1)
 
-    def block(self, layer, params, scales, x, pos, real, attend):
+    def block(self, layer, params, scales, x, pos, real, attend, pools,
+              slot):
         """One block over rows ``x [N, E]`` at positions ``pos`` (``N``
         of them): pre-norm latent attention, then the dense SwiGLU or the
         expert layer (shared expert + the held routed experts' part),
         each added to the residual. Returns the rows, the row each token
-        caches and the expert layer's counts (None for a dense layer).
+        caches, the expert layer's counts (None for a dense layer) and the
+        pools as they were.
         ``real`` marks the rows that are tokens (the rest are a chunk's
         pad or a frozen slot): only they are routed."""
         cfg = self.cfg
@@ -337,7 +379,7 @@ class _MLAMoEBlocks:
         x = x + o @ a["o"]
         h = _rms(x, p["norm_2"], eps)
         if "moe" not in p:
-            return x + _swiglu(h, p["mlp"]), {"kv": row}, None
+            return x + _swiglu(h, p["mlp"]), {"kv": row}, None, pools
         m = p["moe"]
         chosen, weights = route(
             h, m["router"], m["router_bias"], k=cfg.num_experts_per_tok,
@@ -346,7 +388,147 @@ class _MLAMoEBlocks:
         routed, counts = held_expert_mlp(h, chosen, weights, m["experts"],
                                          cfg.experts_held[0], real)
         y = _swiglu(h, m["shared"]).astype(jnp.float32) + routed
-        return x + y.astype(x.dtype), {"kv": row}, counts
+        return x + y.astype(x.dtype), {"kv": row}, counts, pools
+
+
+class _SSMHybridBlocks:
+    """The state-space hybrid (models/ssm_hybrid.py) over its params
+    pytree: K and V pools over the attention layers alone, and two pools
+    a slot (the Mamba layers' float32 ``ssm`` state, packed as ops/ssm
+    says, and ``conv``: the last ``d_conv - 1`` inputs of the convolution,
+    in the activation dtype, which is theirs: exact).
+
+    A Mamba layer reads its slots' state and writes it back where it lies,
+    inside the layer loop: deferred like the K/V rows, every layer's new
+    state would sit in temporaries at once (4.8 GB at 64 slots of
+    granite-4.0-h-micro). A token at position 0 starts from a zero state,
+    which serves a reused slot and a recomputed (preempted) request
+    alike; a row that is no token (a chunk's pad tail, a frozen slot)
+    leaves the state as it was."""
+
+    def __init__(self, cfg, cache):
+        if cfg.mamba_n_groups != 1:
+            raise ServingNotSupported(
+                f"grouped B and C (mamba_n_groups {cfg.mamba_n_groups}) are "
+                f"not served: every head shares one B and one C here")
+        self.cfg = cfg
+        self.cache = cache
+        self.kv_index = {l: i for i, l in enumerate(cfg.attention_layers)}
+        self.state_index = {l: i for i, l in enumerate(cfg.mamba_layers)}
+
+    def embed(self, params, tok, pos):
+        x = params["embed"][tok].reshape(-1, self.cfg.hidden_size)
+        return x * jnp.asarray(self.cfg.embedding_multiplier, x.dtype)
+
+    def head(self, params, x):
+        x = _rms(x, params["norm_f"], self.cfg.rms_norm_eps)
+        return jnp.einsum("be,ve->bv", x, params["embed"],
+                          preferred_element_type=jnp.float32) \
+            / self.cfg.logits_scaling
+
+    def attend(self, layer, pools, bt, past_lens, C, q, k, v):
+        """Grouped queries over the pools' rows of this attention layer
+        (:func:`_attend_in_lanes`), scores scaled by the configuration's
+        multiplier; no position enters."""
+        return _attend_in_lanes(
+            self.kv_index[layer] * self.cache.num_blocks, pools, bt,
+            past_lens, C, q, k, v, sm_scale=self.cfg.softmax_scale)
+
+    def _mamba(self, m, p, h, pos, real, pools, slot):
+        """The Mamba-2 mixer of rows ``h [B*C, E]``: ``C`` tokens of each
+        of ``B`` slots (a decode step: every slot in order, ``C = 1``,
+        ``slot`` None; a prefill chunk: slot ``slot``, ``B = 1``, through
+        the chunked scan whatever its width), at positions ``pos [B, C]``, the
+        real ones ``real [B, C]``. Returns its output rows and the pools
+        with pool layer ``m`` of those slots' state moved past the real
+        tokens."""
+        cfg = self.cfg
+        B, C = pos.shape
+        Hm, P, N = cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state
+        inner, W = cfg.mamba_inner, cfg.conv_channels
+        taps = cfg.mamba_d_conv - 1
+        f32 = jnp.float32
+        proj = h @ p["in_proj"]
+        z, xbc, dt = (proj[:, :inner], proj[:, inner:inner + W],
+                      proj[:, inner + W:])
+        fresh = pos[:, 0] == 0                  # the first token: state 0
+        if slot is None:                        # every slot, in order
+            prev_conv = pools["conv"][m]
+        else:
+            prev_conv = jax.lax.dynamic_index_in_dim(pools["conv"][m], slot,
+                                                     keepdims=True)
+        held = jnp.where(fresh[:, None], 0, prev_conv).reshape(B, taps, W)
+        window = jnp.concatenate([held.astype(xbc.dtype),
+                                  xbc.reshape(B, C, W)], axis=1)
+        conv = p["conv_b"].astype(f32) + sum(
+            window[:, k:k + C].astype(f32) * p["conv_w"][k].astype(f32)
+            for k in range(taps + 1))
+        conv = jax.nn.silu(conv)                        # [B, C, W]
+        # the last ``taps`` inputs up to the last real token
+        n_real = real.sum(axis=1)
+        tail = jax.vmap(lambda w, n: jax.lax.dynamic_slice_in_dim(
+            w, n, taps))(window, n_real).reshape(B, taps * W)
+        new_conv = jnp.where((n_real > 0)[:, None], tail.astype(
+            prev_conv.dtype), prev_conv)
+        live = real.astype(f32)[:, :, None]
+        x = (conv[..., :inner] * live).reshape(B, C, Hm, P)
+        b, c = conv[..., inner:inner + N], conv[..., inner + N:]
+        step = jax.nn.softplus(dt.astype(f32).reshape(B, C, Hm)
+                               + p["dt_bias"].astype(f32)) * live
+        a = -jnp.exp(p["A_log"].astype(f32))
+        if slot is None:                        # a decode step
+            y, state = ssm.decode_update(
+                pools["ssm"], m, x[:, 0], b[:, 0], c[:, 0], step[:, 0], a,
+                real[:, 0], fresh)
+            pools = dict(pools, ssm=state, conv=pools["conv"].at[m].set(
+                new_conv))
+            y = y[:, None]
+        else:
+            rows = pools["ssm"][m, slot]
+            s0 = jnp.where(fresh[0], 0.0,
+                           ssm.to_heads(rows, Hm, P).astype(f32))
+            y, s_end = ssm.chunk_scan(x[0], b[0], c[0], step[0], a, s0)
+            y = y[None]
+            pools = dict(
+                pools,
+                ssm=pools["ssm"].at[m, slot].set(
+                    ssm.from_heads(s_end).astype(rows.dtype)),
+                conv=pools["conv"].at[m, slot].set(new_conv[0]))
+        y = y + p["D"].astype(f32)[:, None] * x          # [B, C, Hm, P]
+        y = y.reshape(B * C, inner) * jax.nn.silu(z.astype(f32))
+        y = _rms(y, p["norm"], cfg.rms_norm_eps)
+        return y.astype(h.dtype) @ p["out_proj"], pools
+
+    def block(self, layer, params, scales, x, pos, real, attend, pools,
+              slot):
+        """One layer over rows ``x [N, E]``: RMSNorm, the layer's mixer
+        (grouped-query attention, or the Mamba-2 mixer over the slots'
+        state), RMSNorm, SwiGLU, each scaled onto the residual. Returns
+        the rows, the K/V rows an attention layer caches (``None`` for a
+        Mamba layer), no expert counts, and the pools."""
+        cfg = self.cfg
+        p = params[f"h_{layer}"]
+        N = x.shape[0]
+        scale = jnp.asarray(cfg.residual_multiplier, x.dtype)
+        h = _rms(x, p["norm_1"], cfg.rms_norm_eps)
+        rows = None
+        if layer in self.state_index:
+            mixed, pools = self._mamba(self.state_index[layer], p["mamba"],
+                                       h, pos, real, pools, slot)
+        else:
+            a, D = p["attn"], cfg.head_dim
+            q = (h @ a["q"]).reshape(N, -1, D)
+            rows = {"k": (h @ a["k"]).reshape(N, -1, D),
+                    "v": (h @ a["v"]).reshape(N, -1, D)}
+            mixed = attend(q, rows["k"], rows["v"]).reshape(N, -1)
+            mixed = mixed.astype(x.dtype) @ a["o"]
+        x = x + scale * mixed
+        h = _rms(x, p["norm_2"], cfg.rms_norm_eps)
+        gate_up = h @ p["mlp"]["w_in"]
+        width = gate_up.shape[-1] // 2
+        mlp = (jax.nn.silu(gate_up[:, :width]) * gate_up[:, width:]) \
+            @ p["mlp"]["w_out"]
+        return x + scale * mlp, rows, None, pools
 
 
 class PagedRunner:
@@ -355,6 +537,7 @@ class PagedRunner:
         self.decode_steps = int(decode_steps)
         cfg = model.config
         self.blocks = (_MLAMoEBlocks if serves_latent(cfg)
+                       else _SSMHybridBlocks if serves_state(cfg)
                        else _GPT2Blocks)(cfg, cache)
         self.cfg = cfg
         self.cache = cache
@@ -386,14 +569,17 @@ class PagedRunner:
     # -------------------------------------------------------- block copy
     def _copy_block_impl(self, pools, src, dst):
         """Block ``src`` -> block ``dst`` in every layer (rows
-        ``arange(L)*N + src`` -> ``+ dst``) of every leaf: every pool
-        (K, V and the int8 scales, or the latent pool) shares the
-        leading ``[L*N]`` block dim. src/dst are traced int32 scalars,
-        so every fork reuses one compiled program."""
+        ``arange(L)*N + src`` -> ``+ dst``) of every paged leaf: every
+        paged pool (K, V and the int8 scales, or the latent pool) shares
+        the leading ``[L*N]`` block dim; a per-slot pool has no blocks.
+        src/dst are traced int32 scalars, so every fork reuses one
+        compiled program."""
         L = self.cache.n_layer
         src_rows = self.cache.layer_rows(src, n_layers=L)
         dst_rows = self.cache.layer_rows(dst, n_layers=L)
+        paged = self.cache.pool_kinds()
         return {name: p.at[dst_rows].set(p[src_rows])
+                if paged[name] == "paged" else p
                 for name, p in pools.items()}
 
     def copy_block(self, pools, src, dst):
@@ -402,7 +588,7 @@ class PagedRunner:
 
     # ------------------------------------------------------- the forward
     def _forward(self, params, scales, pools, bt, past_lens, tok, pos, write,
-                 n_layers=None, want_logits=True):
+                 n_layers=None, want_logits=True, slot=None):
         """The serving forward pass: ``C`` tokens for each of ``B`` slots.
 
         tok/pos ``[B, C]``: the tokens and their absolute positions
@@ -411,7 +597,9 @@ class PagedRunner:
         candidate past a slot's budget are not: their cache rows go to
         the null block and their output rows are discarded by the
         caller); bt ``[B, MB]``; past_lens ``[B]``: tokens ALREADY in
-        the pool.
+        the pool; slot: the one slot whose per-slot state a chunk
+        (``B = 1``) moves on, a traced scalar (``None``: the ``B`` rows
+        are the slots, in order).
 
         Embeds, runs the first ``n_layers`` blocks (default: all),
         writes those layers' cache rows in ONE scatter per pool, and
@@ -431,19 +619,21 @@ class PagedRunner:
         new, counts = [], None
         for layer in range(self.cfg.n_layer if n_layers is None
                            else int(n_layers)):
-            x, rows, n = blocks.block(
+            x, rows, n, pools = blocks.block(
                 layer, params, scales, x, pos, write,
                 functools.partial(blocks.attend, layer, pools, bt,
-                                  past_lens, C))
-            new.append(rows)
+                                  past_lens, C), pools, slot)
+            if rows is not None:        # a layer with per-slot state: none
+                new.append(rows)
             if n is not None:
                 counts = n if counts is None else counts + n
         row = jnp.take_along_axis(
             bt, jnp.minimum(pos // bs, bt.shape[1] - 1), axis=1)
-        pools = self.cache.write_layers(
-            pools, {name: jnp.stack([rows[name] for rows in new])
-                    for name in new[0]},
-            jnp.where(write, row, 0).reshape(-1), (pos % bs).reshape(-1))
+        if new:
+            pools = self.cache.write_layers(
+                pools, {name: jnp.stack([rows[name] for rows in new])
+                        for name in new[0]},
+                jnp.where(write, row, 0).reshape(-1), (pos % bs).reshape(-1))
         if not want_logits:
             return pools, None, counts
         return pools, blocks.head(params, x), counts
@@ -497,13 +687,16 @@ class PagedRunner:
         return pools, toks, None if counts is None else counts.sum(0)
 
     def _prefill_impl(self, params, scales, pools, bt_row, tokens, start,
-                      n_valid):
+                      n_valid, slot=None):
         """One slot's chunk: the forward at ``B = 1``, no head; the
-        positions past ``n_valid`` are the final chunk's pad."""
+        positions past ``n_valid`` are the final chunk's pad; ``slot``
+        names the slot whose per-slot state the chunk moves on (a model
+        without one does not read it)."""
         idx = jnp.arange(tokens.shape[0], dtype=jnp.int32)
         pools, _, counts = self._forward(
             params, scales, pools, bt_row[None], start[None], tokens[None],
-            (start + idx)[None], (idx < n_valid)[None], want_logits=False)
+            (start + idx)[None], (idx < n_valid)[None], want_logits=False,
+            slot=slot)
         return pools, counts
 
     # -------------------------------------------------------- public API
@@ -523,11 +716,11 @@ class PagedRunner:
         return pools, toks
 
     def prefill_chunk(self, params, scales, pools, bt_row, tokens, start,
-                      n_valid):
-        """Fill ``n_valid`` prompt tokens of one slot's KV; returns
-        updated pools."""
+                      n_valid, slot=None):
+        """Fill ``n_valid`` prompt tokens of slot ``slot``'s KV (and move
+        its per-slot state on); returns updated pools."""
         pools, counts = self._prefill(params, scales or {}, pools, bt_row,
-                                      tokens, start, n_valid)
+                                      tokens, start, n_valid, slot)
         if counts is not None:
             self.expert_counts.append(counts)
         return pools

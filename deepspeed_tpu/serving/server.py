@@ -3,7 +3,8 @@
 ``ServingEngine`` glues the subsystem together on top of an
 ``InferenceEngine`` (which owns params, dtype/int8-weight handling and
 the mesh): a ``PagedKVCache`` block pool whose rows are what the model's
-configuration says it caches (K and V heads, or one latent a token), the
+configuration says it caches (K and V heads, or one latent a token; for
+layers that carry a state, pools of one row a slot beside it), the
 ``PagedRunner``'s two compiled programs, the FCFS continuous-batching scheduler, and chunked
 prefill. The API is deliberately synchronous — ``submit()`` enqueues,
 ``step()`` advances the world by one scheduler iteration (one bounded
@@ -50,7 +51,8 @@ from deepspeed_tpu.serving.kv_cache import PagedKVCache
 from deepspeed_tpu.serving.paged_attention import decode_kernel_runs
 from deepspeed_tpu.serving.prefill import ChunkedPrefill
 from deepspeed_tpu.serving.runner import (PagedRunner, ServingNotSupported,
-                                          cache_rows, serves_latent)
+                                          cache_layers, cache_rows,
+                                          serves_latent, serves_state)
 from deepspeed_tpu.serving.sampling import make_rng_lane
 from deepspeed_tpu.serving.scheduler import (ContinuousBatchingScheduler,
                                              Request, RequestState)
@@ -118,6 +120,26 @@ class RequestOutput:
     preemptions: int
 
 
+def _refuse_with_state(config, engine):
+    """What a model whose layers carry a per-slot state does not compose
+    with, refused by the name of the mechanism."""
+    for on, mechanism in (
+            (getattr(getattr(config, "prefix_cache", None), "enabled",
+                     False),
+             "the prefix cache over per-slot state: a state past a block "
+             "boundary cannot be shared without a snapshot of it"),
+            (getattr(getattr(config, "speculative", None), "enabled",
+                     False),
+             "speculative decoding over per-slot state: a rejected draft "
+             "would need the state rolled back"),
+            (engine.quant_scales is not None,
+             "int8 weights are not served for a state-space hybrid "
+             "(dtype=int8 folds its scales into the GPT-2 block's matmuls "
+             "only)")):
+        if on:
+            raise ServingNotSupported(mechanism)
+
+
 class ServingEngine:
     def __init__(self, engine, config=None, registry=None,
                  guardian=None, obs_server=None, slo=None,
@@ -169,6 +191,8 @@ class ServingEngine:
                     "speculative decoding over a latent cache is not "
                     "served: the draft's layer prefix has no head of its "
                     "own here and a draft module is ROADMAP M7")
+        if serves_state(cfg):
+            _refuse_with_state(config, engine)
         rows = cache_rows(cfg)      # refuses a model with no served block
         n_pos = int(getattr(cfg, "n_positions"))
         self.max_model_len = (min(int(config.max_model_len), n_pos)
@@ -179,10 +203,13 @@ class ServingEngine:
                                     // int(config.block_size))
         num_blocks = int(config.num_blocks) or (
             1 + self.max_batch * self.max_blocks_per_seq)
+        layers = cache_layers(cfg)
         self.cache = PagedKVCache(
-            n_layer=cfg.n_layer, block_size=config.block_size,
+            n_layer=layers["paged"], block_size=config.block_size,
             num_blocks=num_blocks, dtype=engine.dtype, int8_kv=int8_kv,
-            **rows)
+            state_layers=layers["per_slot"], slots=self.max_batch, **rows)
+        # slot-layer states a decode row moves on (0 without state)
+        self._state_layers = layers["per_slot"]
         self.runner = PagedRunner(
             model, self.cache, decode_steps=config.decode_steps)
         # speculative decoding (serving/speculative.py): replaces the
@@ -323,6 +350,11 @@ class ServingEngine:
         self.registry.gauge(
             "serving_kv_pool_bytes",
             "allocated paged-KV pool size").set(self.cache.pool_bytes())
+        if self._state_layers:
+            self.registry.gauge(
+                "serving_state_pool_bytes",
+                "allocated per-slot state pools (one row a slot and "
+                "layer)").set(self.cache.pool_bytes("per_slot"))
         log_dist(
             f"ServingEngine ready: max_batch={self.max_batch} "
             f"block_size={self.cache.block_size} "
@@ -615,9 +647,14 @@ class ServingEngine:
     def _run_prefill(self, req) -> bool:
         slot, start = req.slot, req.cached_len
         t0 = time.perf_counter_ns()
+        # a chunk at position 0 starts its slot's state from zero
+        state = ({"state_from_zero": int(start == 0)}
+                 if self._state_layers else {})
+        if state.get("state_from_zero"):
+            self._count_state_resets(1)
         with trace_span("serving_prefill", req=req.req_id, start=start,
                         tokens=min(self.prefill.chunk_size,
-                                   self.prefill.remaining(req))):
+                                   self.prefill.remaining(req)), **state):
             with self.engine.mesh:
                 self.pools, n_valid, n_recompute, done = self.prefill.run(
                     self.engine.params, self.engine.quant_scales,
@@ -723,6 +760,11 @@ class ServingEngine:
                  prev_row) = self._decode_inputs(decode_slots, prev)
             needed, visited = self._paged_block_counts(pos, active)
             span.set(blocks_needed=needed, blocks_visited=visited)
+            if self._state_layers:
+                # slot-layer states the dispatch moves on (a slot past its
+                # budget is frozen); a token at position 0 starts from 0
+                span.set(state_rows=int(budget.sum()) * self._state_layers)
+                self._count_state_resets(int((active & (pos == 0)).sum()))
             spec = (self.speculative
                     if self._spec_disabled_rule is None else None)
             accepted = None
@@ -807,6 +849,12 @@ class ServingEngine:
                 "scheduled, by what made the step need its tokens",
                 labels={"reason": reason}).inc()
         return True
+
+    def _count_state_resets(self, n):
+        self.registry.counter(
+            "serving_state_resets_total",
+            "prefill chunks and decode rows that start a slot's per-slot "
+            "state from zero (a request's first token)").inc(n)
 
     def _count_expert_pairs(self, counts):
         """Book what the expert layers of the landed dispatches counted

@@ -305,3 +305,79 @@ def test_decode_kernel_fits_the_chip_at_the_cells_slots(one_chip, cell):
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     assert "vmem_limit_bytes" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 8e6
+
+
+# ------------------------------------- the per-slot state pools (the hybrid)
+def _state_programs(one_chip, slots=64, num_blocks=1375, chunk=256):
+    """The decode and prefill programs of the state-space hybrid at the
+    published widths of benchmark/configs/granite-4.0-h-micro.json, two
+    Mamba layers around one attention layer, 128 rows of vocabulary, at
+    the cell's slots, blocks and chunk."""
+    from deepspeed_tpu.models.ssm_hybrid import (SSMHybridConfig,
+                                                 SSMHybridForCausalLM,
+                                                 init_params)
+    from deepspeed_tpu.serving.runner import (PagedRunner, cache_layers,
+                                              cache_rows)
+    spec = _spec(one_chip)
+    cfg = SSMHybridConfig(
+        vocab_size=128, hidden_size=2048, intermediate_size=8192,
+        layer_types=("mamba", "attention", "mamba"), num_attention_heads=32,
+        num_key_value_heads=8, mamba_n_heads=64, mamba_d_head=64,
+        mamba_d_state=128, max_position_embeddings=131072,
+        embedding_multiplier=12.0, attention_multiplier=0.015625,
+        residual_multiplier=0.22, logits_scaling=8.0)
+    params = jax.eval_shape(
+        lambda: init_params(cfg, jax.random.PRNGKey(0), jnp.bfloat16))
+    params = jax.tree.map(lambda a: spec(a.shape, jnp.bfloat16), params)
+    layers = cache_layers(cfg)
+    cache = PagedKVCache(n_layer=layers["paged"], block_size=BLOCK_SIZE,
+                         num_blocks=num_blocks, dtype=jnp.bfloat16,
+                         state_layers=layers["per_slot"], slots=slots,
+                         **cache_rows(cfg))
+    runner = PagedRunner(SSMHybridForCausalLM(cfg), cache)
+    pools = {name: spec(shape, dtype)
+             for name, (shape, dtype) in cache._pool_shapes().items()}
+    B, i32, f32 = slots, jnp.int32, jnp.float32
+    return cache, pools, {
+        "decode": (runner._decode,
+                   [params, {}, pools, spec((B, MAX_BLOCKS), i32),
+                    spec((B,), i32), spec((B,), jnp.bool_), spec((B,), i32),
+                    spec((B,), f32), spec((B,), f32),
+                    spec((B, 2), jnp.uint32), spec((B,), i32),
+                    spec((1, B), i32), spec((B,), i32)]),
+        "prefill": (runner._prefill,
+                    [params, {}, pools, spec((MAX_BLOCKS,), i32),
+                     spec((chunk,), i32), spec((), i32), spec((), i32),
+                     spec((), i32)]),
+    }
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_state_programs_hold_one_state_pool(one_chip, monkeypatch, program):
+    """The hybrid's programs compile for the v5e at the published widths
+    and the cell's 64 slots: every pool is aliased to its output, none is
+    copied whole, and the temporaries hold no second state pool (a Mamba
+    layer writes its slots' state back where it lies, inside the layer
+    loop); decode is one ``ssm_decode`` kernel a Mamba layer beside the
+    attention layer's ``paged_decode``."""
+    from deepspeed_tpu.ops.ssm import decode as ssm_decode
+    monkeypatch.setattr(paged_attention, "_interpret", lambda: False)
+    monkeypatch.setattr(ssm_decode, "_interpret", lambda: False)
+    monkeypatch.setattr(groups, "_MESH", None)
+    cache, pools, programs = _state_programs(one_chip)
+    assert {n: tuple(s.shape) for n, s in pools.items()} == {
+        "k": (1375, 16, 512), "v": (1375, 16, 512),
+        "ssm": (2, 64, 32, 128, 128), "conv": (2, 64, 3 * 4352)}
+    fn, args = programs[program]
+    compiled = fn.lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == {
+        "decode": 3, "prefill": 0}[program]
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= cache.pool_bytes()
+    state = cache.pool_bytes("per_slot")
+    assert mem.temp_size_in_bytes < 0.25 * state, (
+        f"{mem.temp_size_in_bytes / 1e6:.1f} MB of temporaries beside "
+        f"{state / 1e6:.1f} MB of state pools")
+    copies = _pool_copies(text, pools.values())
+    assert not copies, "whole-pool copies:\n" + "\n".join(copies)
