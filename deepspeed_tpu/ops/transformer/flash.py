@@ -14,7 +14,11 @@ kernel forms per pass, dispatched on sequence length (_use_streaming):
 resident (≤ 4096: full K/V staged per program, causal skip via the loop
 bound — ~11% faster at 1024) and streaming (beyond: K/V blocks stream
 through the innermost grid axis with scratch accumulators — O(block)
-VMEM, unbounded seq; the resident form VMEM-OOMs at 8192).
+VMEM, unbounded seq; the resident form VMEM-OOMs at 8192). The resident
+backward is ONE kernel, ``flash_dkv``, that writes dq, dk and dv from each
+score tile once; shapes whose dq does not fit beside it in VMEM
+(_fused_bwd_fits) keep the two-call form, ``flash_dq`` + ``flash_dkv``,
+as does the streaming backward.
 
 All kernels run in interpret mode off-TPU so CPU tests exercise the same
 code path bit-for-bit (tests/unit/test_flash.py).
@@ -30,6 +34,7 @@ from jax.experimental.pallas import tpu as pltpu
 from deepspeed_tpu.ops._platform import interpret as _interpret
 
 NEG_INF = -1e30
+LOG2E = 1.4426950408889634
 LANES = 8  # replication width for per-row stats (lse/delta) — see _fwd_kernel
 
 
@@ -318,6 +323,77 @@ def _dkv_kernel_resident(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
+def _bwd_kernel_resident(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                         dq_ref, dk_ref, dv_ref, dq_acc_ref, *, sm_scale,
+                         causal, block_q, block_k, seq_q, offset):
+    """dq, dk and dv from ONE pass over the score tiles: the dkv kernel
+    above with dq added, so s, p, dP and dS are computed once a tile (the
+    dq/dkv pair computes them twice). Program (b, kj) owns kv block kj; dq
+    for the whole sequence accumulates over kj in f32 scratch and its
+    block, the same for every kj, is written back once per b.
+
+    All three accumulate TRANSPOSED ([D, BK], and [D, Sq] for dq): at head
+    64 a [BQ, D] product result fills half of each 128-lane row and pops
+    twice the MXU results, and dVᵀ += dOᵀ·p, dKᵀ += Qᵀ·dS, dQᵀ += Kᵀ·dSᵀ
+    transpose the small operands where pᵀ·dO and dSᵀ·Q transposed the
+    [BQ, BK] tiles (the v5e loop body at 512 x 512: 2,889 -> 2,016
+    bundles, and the kernel 0.99 -> 0.78 ms at gpt2-medium's call).
+    The products and their operands are the pair's: the gradients are its
+    bit for bit on the chip."""
+    kj = pl.program_id(1)
+    k = k_ref[0]  # [BK, D]
+    v = v_ref[0]
+    kt = k.T      # [D, BK], once a program
+
+    @pl.when(kj == 0)
+    def _init():
+        dq_acc_ref[...] = jnp.zeros_like(dq_acc_ref)
+
+    num_q = pl.cdiv(seq_q, block_q)
+    start_q = jnp.int32(0)
+    if causal:
+        start_q = jnp.maximum(kj * block_k - offset, 0) // block_q
+
+    def body(i, carry):
+        dkt, dvt = carry
+        rows = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
+        q = q_ref[0, rows, :]
+        do = do_ref[0, rows, :]
+        lse = lse_ref[0, rows, 0:1]      # [BQ, 1]
+        delta = delta_ref[0, rows, 0:1]
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * sm_scale
+        if causal:
+            s = _apply_causal_mask(s, i * block_q, kj * block_k,
+                                   block_q, block_k, offset)
+        p = jnp.exp(s - lse)                                # [BQ, BK]
+        dvt = dvt + jax.lax.dot_general(do, p.astype(do.dtype),
+                                        (((0,), (0,)), ((), ())),
+                                        preferred_element_type=jnp.float32)
+        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        ds = (p * (dp - delta) * sm_scale).astype(q.dtype)
+        dkt = dkt + jax.lax.dot_general(q, ds, (((0,), (0,)), ((), ())),
+                                        preferred_element_type=jnp.float32)
+        # dq^T's columns: a lane slice, static where one block spans Sq
+        cols = slice(None) if block_q == seq_q else rows
+        dq_acc_ref[:, cols] += jax.lax.dot_general(
+            kt, ds, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        return dkt, dvt
+
+    d = k.shape[-1]
+    dkt = jnp.zeros((d, block_k), jnp.float32)
+    dvt = jnp.zeros((d, block_k), jnp.float32)
+    dkt, dvt = jax.lax.fori_loop(start_q, num_q, body, (dkt, dvt))
+    dk_ref[0] = dkt.T.astype(dk_ref.dtype)
+    dv_ref[0] = dvt.T.astype(dv_ref.dtype)
+
+    @pl.when(kj == pl.num_programs(1) - 1)
+    def _finalize():
+        dq_ref[0] = dq_acc_ref[...].T.astype(dq_ref.dtype)
+
+
 # ------------------------------------------------------------------ dispatch
 def _pick_block(seq, streaming=False, target=None):
     if target is None:
@@ -428,6 +504,115 @@ def _flash_fwd(q, k, v, causal, sm_scale):
     return out, (q, k, v, out, lse)
 
 
+# The one-pass backward stages Q, dO and dq [Sq, D] and dq^T's f32
+# accumulator [D, Sq] whole per b, beside the K/V blocks. Compiled for the
+# v5e's 16 MiB of scoped VMEM (tests/unit/test_serving_pool_layout.py) it
+# fits at every resident length for heads up to 256 while [Sq, D] of the
+# operand dtype stays within 3 MiB (f32 4096 x 192); f32 4096 x 256 and
+# heads of 512 from 1536 rows do not. Those keep the two-call form, as do
+# q blocks that are not whole lane tiles (dq^T is sliced by columns).
+_FUSED_BWD_MAX_HEAD = 256
+_FUSED_BWD_MAX_ROWS_BYTES = 3 << 20
+
+
+def _fused_bwd_fits(Sq, D, dtype, block_q):
+    return (D <= _FUSED_BWD_MAX_HEAD and
+            Sq * D * jnp.dtype(dtype).itemsize <= _FUSED_BWD_MAX_ROWS_BYTES
+            and (block_q % 128 == 0 or block_q == Sq))
+
+
+def _bwd_resident(qf, kf, vf, dof, lse, delta, *, sm_scale, causal,
+                  block_q, block_k):
+    """The resident backward over [B*H, S, D] operands: ONE call,
+    ``flash_dkv``, that writes dq too (grid (B*H, Sk/BK); Q, dO and the row
+    stats staged whole per b, K/V a block per program)."""
+    BH, Sq, D = qf.shape
+    Sk = kf.shape[1]
+    return pl.pallas_call(
+        functools.partial(
+            _bwd_kernel_resident, sm_scale=sm_scale, causal=causal,
+            block_q=block_q, block_k=block_k, seq_q=Sq, offset=Sk - Sq),
+        grid=(BH, Sk // block_k),
+        in_specs=[
+            pl.BlockSpec((1, Sq, D), lambda b, j: (b, 0, 0)),
+            pl.BlockSpec((1, block_k, D), lambda b, j: (b, j, 0)),
+            pl.BlockSpec((1, block_k, D), lambda b, j: (b, j, 0)),
+            pl.BlockSpec((1, Sq, D), lambda b, j: (b, 0, 0)),
+            pl.BlockSpec((1, Sq, LANES), lambda b, j: (b, 0, 0)),
+            pl.BlockSpec((1, Sq, LANES), lambda b, j: (b, 0, 0)),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, Sq, D), lambda b, j: (b, 0, 0)),
+            pl.BlockSpec((1, block_k, D), lambda b, j: (b, j, 0)),
+            pl.BlockSpec((1, block_k, D), lambda b, j: (b, j, 0)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((BH, Sq, D), qf.dtype),
+            jax.ShapeDtypeStruct((BH, Sk, D), kf.dtype),
+            jax.ShapeDtypeStruct((BH, Sk, D), vf.dtype),
+        ],
+        # dq's block and its accumulator carry across the kv axis
+        scratch_shapes=[pltpu.VMEM((D, Sq), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        name="flash_dkv",
+        interpret=_interpret(),
+    )(qf, kf, vf, dof, lse, delta)
+
+
+def _bwd_resident_pair(qf, kf, vf, dof, lse, delta, *, sm_scale, causal,
+                       block_q, block_k):
+    """The resident backward in two calls, ``flash_dq`` (K/V staged whole,
+    a q block per program) and ``flash_dkv`` (Q/dO staged whole, a kv block
+    per program), each computing the scores: for shapes whose dq does not
+    fit beside the one-pass kernel's staging (:func:`_fused_bwd_fits`)."""
+    BH, Sq, D = qf.shape
+    Sk = kf.shape[1]
+    dq = pl.pallas_call(
+        functools.partial(
+            _dq_kernel_resident, sm_scale=sm_scale, causal=causal,
+            block_q=block_q, block_k=block_k, seq_k=Sk, offset=Sk - Sq),
+        grid=(BH, Sq // block_q),
+        in_specs=[
+            pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, Sk, D), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((1, Sk, D), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, block_q, LANES), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, block_q, LANES), lambda b, i: (b, i, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((BH, Sq, D), qf.dtype),
+        name="flash_dq",
+        interpret=_interpret(),
+    )(qf, kf, vf, dof, lse, delta)
+    dk, dv = pl.pallas_call(
+        functools.partial(
+            _dkv_kernel_resident, sm_scale=sm_scale, causal=causal,
+            block_q=block_q, block_k=block_k, seq_q=Sq, offset=Sk - Sq),
+        grid=(BH, Sk // block_k),
+        in_specs=[
+            pl.BlockSpec((1, Sq, D), lambda b, j: (b, 0, 0)),
+            pl.BlockSpec((1, block_k, D), lambda b, j: (b, j, 0)),
+            pl.BlockSpec((1, block_k, D), lambda b, j: (b, j, 0)),
+            pl.BlockSpec((1, Sq, D), lambda b, j: (b, 0, 0)),
+            pl.BlockSpec((1, Sq, LANES), lambda b, j: (b, 0, 0)),
+            pl.BlockSpec((1, Sq, LANES), lambda b, j: (b, 0, 0)),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, block_k, D), lambda b, j: (b, j, 0)),
+            pl.BlockSpec((1, block_k, D), lambda b, j: (b, j, 0)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((BH, Sk, D), kf.dtype),
+            jax.ShapeDtypeStruct((BH, Sk, D), vf.dtype),
+        ],
+        name="flash_dkv",
+        interpret=_interpret(),
+    )(qf, kf, vf, dof, lse, delta)
+    return dq, dk, dv
+
+
 def _flash_bwd(causal, sm_scale, res, g, g_lse=None):
     q, k, v, out, lse = res
     if sm_scale is None:
@@ -454,48 +639,11 @@ def _flash_bwd(causal, sm_scale, res, g, g_lse=None):
     delta = jnp.broadcast_to(delta_rows, (B * H, Sq, LANES))
 
     if not stream:
-        dq = pl.pallas_call(
-            functools.partial(
-                _dq_kernel_resident, sm_scale=sm_scale, causal=causal,
-                block_q=bq, block_k=bk, seq_k=Sk, offset=Sk - Sq),
-            grid=(B * H, Sq // bq),
-            in_specs=[
-                pl.BlockSpec((1, bq, D), lambda b, i: (b, i, 0)),
-                pl.BlockSpec((1, Sk, D), lambda b, i: (b, 0, 0)),
-                pl.BlockSpec((1, Sk, D), lambda b, i: (b, 0, 0)),
-                pl.BlockSpec((1, bq, D), lambda b, i: (b, i, 0)),
-                pl.BlockSpec((1, bq, LANES), lambda b, i: (b, i, 0)),
-                pl.BlockSpec((1, bq, LANES), lambda b, i: (b, i, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, bq, D), lambda b, i: (b, i, 0)),
-            out_shape=jax.ShapeDtypeStruct((B * H, Sq, D), q.dtype),
-            name="flash_dq",
-            interpret=_interpret(),
-        )(qf, kf, vf, dof, lse, delta)
-        dk, dv = pl.pallas_call(
-            functools.partial(
-                _dkv_kernel_resident, sm_scale=sm_scale, causal=causal,
-                block_q=bq, block_k=bk, seq_q=Sq, offset=Sk - Sq),
-            grid=(B * H, Sk // bk),
-            in_specs=[
-                pl.BlockSpec((1, Sq, D), lambda b, j: (b, 0, 0)),
-                pl.BlockSpec((1, bk, D), lambda b, j: (b, j, 0)),
-                pl.BlockSpec((1, bk, D), lambda b, j: (b, j, 0)),
-                pl.BlockSpec((1, Sq, D), lambda b, j: (b, 0, 0)),
-                pl.BlockSpec((1, Sq, LANES), lambda b, j: (b, 0, 0)),
-                pl.BlockSpec((1, Sq, LANES), lambda b, j: (b, 0, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, bk, D), lambda b, j: (b, j, 0)),
-                pl.BlockSpec((1, bk, D), lambda b, j: (b, j, 0)),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct((B * H, Sk, D), k.dtype),
-                jax.ShapeDtypeStruct((B * H, Sk, D), v.dtype),
-            ],
-            name="flash_dkv",
-            interpret=_interpret(),
-        )(qf, kf, vf, dof, lse, delta)
+        resident = (_bwd_resident if _fused_bwd_fits(Sq, D, q.dtype, bq)
+                    else _bwd_resident_pair)
+        dq, dk, dv = resident(qf, kf, vf, dof, lse, delta,
+                              sm_scale=sm_scale, causal=causal,
+                              block_q=bq, block_k=bk)
         return (dq.reshape(B, H, Sq, D), dk.reshape(B, H, Sk, D),
                 dv.reshape(B, H, Sk, D))
 

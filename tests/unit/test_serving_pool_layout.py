@@ -27,6 +27,10 @@ width the compiler also relayouts the 1,600-wide ``wte`` (161 MB at the
 published vocabulary), which is no pool and would drown what is measured,
 and the sampler's sort over 50,257 logits is most of a compile's time.
 
+The training flash backward's shape rule is held to the same compile at
+its edges (``test_flash_backward_fits_the_chip``): which shapes take the
+one-pass kernel, and that each compiles within the scoped VMEM.
+
 Every libtpu call sits in a fixture or a test: only the worker that is
 given this file may load the library (on-chip-measurement guide, §2).
 """
@@ -381,3 +385,37 @@ def test_state_programs_hold_one_state_pool(one_chip, monkeypatch, program):
         f"{state / 1e6:.1f} MB of state pools")
     copies = _pool_copies(text, pools.values())
     assert not copies, "whole-pool copies:\n" + "\n".join(copies)
+
+
+# The training flash backward's shape rule (``flash._fused_bwd_fits``),
+# held to the v5e's 16 MiB of scoped VMEM here because this is the file
+# that describes the chip: (q shape, k shape, dtype, causal) -> Mosaic
+# calls of the gradient. 2 = ``flash_fwd`` + the one-pass ``flash_dkv``;
+# 3 = the two-call backward, kept where the one pass does not fit.
+FLASH_SHAPES = {
+    "gpt2_medium_train": ((8, 16, 1024, 64), (8, 16, 1024, 64),
+                          jnp.bfloat16, True, 2),
+    "bf16_4096_head_256": ((1, 2, 4096, 256), (1, 2, 4096, 256),
+                           jnp.bfloat16, True, 2),
+    "f32_4096_head_192": ((1, 2, 4096, 192), (1, 2, 4096, 192),
+                          jnp.float32, True, 2),
+    "f32_4096_head_256": ((1, 2, 4096, 256), (1, 2, 1024, 256),
+                          jnp.float32, False, 3),
+    "bf16_2048_head_512": ((1, 2, 2048, 512), (1, 2, 2048, 512),
+                           jnp.bfloat16, True, 3),
+}
+
+
+@pytest.mark.parametrize("shape", list(FLASH_SHAPES))
+def test_flash_backward_fits_the_chip(one_chip, monkeypatch, shape):
+    from deepspeed_tpu.ops.transformer import flash
+    monkeypatch.setattr(flash, "_interpret", lambda: False)
+    monkeypatch.delenv("DS_FLASH_BLOCK", raising=False)
+    monkeypatch.delenv("DS_FLASH_STREAM", raising=False)
+    qs, ks, dtype, causal, calls = FLASH_SHAPES[shape]
+    spec = _spec(one_chip)
+    grad = jax.grad(lambda q, k, v: jnp.sum(flash.flash_attention(
+        q, k, v, causal).astype(jnp.float32)), argnums=(0, 1, 2))
+    text = jax.jit(grad).lower(spec(qs, dtype), spec(ks, dtype),
+                               spec(ks, dtype)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == calls
