@@ -444,9 +444,13 @@ class DeepSpeedEngine:
         # compiled entry points can be compile-watch wrapped right after
         # _build_step_fns constructs them. Rank-0 only; every surface is a
         # no-op when the config block is absent/disabled.
-        from deepspeed_tpu.telemetry import TelemetryManager
+        from deepspeed_tpu.telemetry import TelemetryManager, get_registry
+        from deepspeed_tpu.telemetry.tracer import watch_gc
         self.telemetry = TelemetryManager(self.config.telemetry,
                                           rank=dist.get_rank())
+        # this loop owns the process's garbage collections until close()
+        self._gc = watch_gc("train",
+                            self.telemetry.registry or get_registry(), self)
 
         # ---- goodput ledger (telemetry/ledger.py) -------------------------
         # Host-side wall-clock attribution only — it never changes the
@@ -3564,6 +3568,7 @@ class DeepSpeedEngine:
                 with self._led_attr("checkpoint_save"):
                     self._ckpt_writer.close()
         finally:
+            self._gc.close()
             if self._fleet_aggregator is not None:
                 try:
                     # before the obs server: the aggregator's routes are

@@ -61,7 +61,7 @@ from deepspeed_tpu.telemetry import metrics as _metrics
 from deepspeed_tpu.telemetry.compile_watch import CompileWatch
 from deepspeed_tpu.telemetry.serving_observatory import (
     SERVING_HEALTH_SCHEMA, ServingObservatory)
-from deepspeed_tpu.telemetry.tracer import trace_span
+from deepspeed_tpu.telemetry.tracer import trace_span, watch_gc
 from deepspeed_tpu.utils.logging import log_dist
 
 # latency histograms: serving cares about the 0.1 ms .. 10 s band
@@ -243,6 +243,8 @@ class ServingEngine:
             decode_steps=dispatch_tokens)
         self.registry = registry if registry is not None \
             else _metrics.get_registry()
+        # this loop owns the process's garbage collections until close()
+        self._gc = watch_gc("serving", self.registry, self)
         # serving observatory (telemetry/serving_observatory.py): pure
         # host bookkeeping — timelines, the slot-step ledger, SLO rules.
         # None when disabled, so every call site is one attribute check.
@@ -654,11 +656,13 @@ class ServingEngine:
             self._count_state_resets(1)
         with trace_span("serving_prefill", req=req.req_id, start=start,
                         tokens=min(self.prefill.chunk_size,
-                                   self.prefill.remaining(req)), **state):
+                                   self.prefill.remaining(req)),
+                        **state) as span:
             with self.engine.mesh:
                 self.pools, n_valid, n_recompute, done = self.prefill.run(
                     self.engine.params, self.engine.quant_scales,
                     self.pools, req, self.max_blocks_per_seq)
+            span.set(recompute=n_recompute)
         t1 = time.perf_counter_ns()
         self.registry.counter("serving_prefill_chunks_total",
                               "prefill chunks executed").inc()
@@ -759,7 +763,13 @@ class ServingEngine:
                 (bt, pos, active, tok, temp, top_p, lanes, budget,
                  prev_row) = self._decode_inputs(decode_slots, prev)
             needed, visited = self._paged_block_counts(pos, active)
-            span.set(blocks_needed=needed, blocks_visited=visited)
+            # the rows asked for and the sum of their input positions
+            # (a slot's rows sit at pos, pos + 1, ..., pos + budget - 1)
+            rows = budget.astype(np.int64)
+            span.set(blocks_needed=needed, blocks_visited=visited,
+                     rows=int(rows.sum()),
+                     positions=int((rows * pos + rows * (rows - 1) // 2)
+                                   .sum()))
             if self._state_layers:
                 # slot-layer states the dispatch moves on (a slot past its
                 # budget is frozen); a token at position 0 starts from 0
@@ -1398,6 +1408,7 @@ class ServingEngine:
         object. Tokens still in flight land first, so the snapshot and
         a last ``collect()`` hold them."""
         self._land("drain")
+        self._gc.close()
         if self._obs_server is not None:
             self._obs_server.unregister("serving")
             self._obs_server = None

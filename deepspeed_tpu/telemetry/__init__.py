@@ -4,7 +4,9 @@ Four pieces (see the per-module docstrings):
 
 * ``tracer`` — nested ``trace_span`` contexts -> Chrome-trace JSON
   (live when enabled or while a JAX profiler session runs, when each span
-  is also a ``jax.profiler.TraceAnnotation`` in the capture);
+  is also a ``jax.profiler.TraceAnnotation`` in the capture), and the
+  process's one garbage-collection hook (``watch_gc``: each collection a
+  ``<loop>_gc`` span and counters of the engine loop that owns it);
 * ``compile_watch`` — XLA compile counting + retrace culprit reports;
 * ``metrics`` — counters / gauges / histograms + device-memory stats;
 * ``sinks`` — JSONL event writer and Prometheus text-format exporter

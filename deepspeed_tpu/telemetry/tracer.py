@@ -17,12 +17,27 @@ The hot path is the one that is not live: ``trace_span`` then costs one
 manager — no allocation, no clock read (tests/perf/telemetry_overhead.py
 asserts < 2 µs/span). A live span costs two ``perf_counter_ns`` reads, the
 annotation and one locked list append.
+
+Python's cyclic collector stops the host loop whole, and no span of the
+loop can say so: a pause inside ``serving_decode_wait`` looks like the
+wait. So one process-wide ``gc.callbacks`` hook (:func:`watch_gc`) names
+each collection after the host loop that registered last (``serving``,
+``train``). While the tracer is live a collection is a span
+``<loop>_gc`` (``generation``; ``collected``, set at its end), opened at
+the collection's start and closed at its stop on the collecting thread,
+so it nests under whatever span was open. Always, it books
+``<loop>_gc_collections_total{generation}``,
+``<loop>_gc_seconds_total{generation}`` and the gauge
+``<loop>_gc_pause_max_seconds`` in the loop's registry: two clock reads,
+one liveness check and the counter updates when the tracer is not live.
 """
 
+import gc
 import json
 import os
 import threading
 import time
+import weakref
 
 from jax.profiler import TraceAnnotation
 
@@ -79,7 +94,9 @@ class Tracer:
         self.max_events = max_events
         self.dropped = 0
         self._events = []
-        self._lock = threading.Lock()
+        # re-entrant: a collection can start inside ``events()``'s copy
+        # and record its span on the same thread before the copy ends
+        self._lock = threading.RLock()
         self._pid = os.getpid()
         self._process_label = None
         self._process_sort = None
@@ -237,3 +254,96 @@ def set_tracer(tracer):
 
 def trace_span(name, **args):
     return _GLOBAL.span(name, **args)
+
+
+# ---------------------------------------------------------------- GC pauses
+class GCLoop:
+    """One host loop's registration with the collection hook (see
+    :func:`watch_gc`); ``close`` ends it, and is idempotent."""
+
+    __slots__ = ("span_name", "_collections", "_seconds", "_pause_max")
+
+    def __init__(self, loop, registry):
+        self.span_name = f"{loop}_gc"
+        gens = [{"generation": str(g)} for g in range(3)]
+        self._collections = [registry.counter(
+            f"{loop}_gc_collections_total",
+            "Python garbage collections while this loop owned the "
+            "process", labels=g) for g in gens]
+        self._seconds = [registry.counter(
+            f"{loop}_gc_seconds_total",
+            "seconds the host loop stood in garbage collection",
+            labels=g) for g in gens]
+        self._pause_max = registry.gauge(
+            f"{loop}_gc_pause_max_seconds",
+            "the longest garbage collection so far")
+
+    def book(self, generation, seconds):
+        self._collections[generation].inc()
+        self._seconds[generation].inc(seconds)
+        if seconds > self._pause_max.value:
+            self._pause_max.set(seconds)
+
+    def close(self):
+        _GC_WATCH.unregister(self)
+
+
+class _GCWatch:
+    """The ``gc.callbacks`` hook. Collections do not nest (the collector
+    runs one at a time), so one open collection is all the state."""
+
+    def __init__(self):
+        self._loops = []        # registrations; the newest owns the process
+        self._owner = None      # the loop of the collection under way
+        self._span = None
+        self._t0 = 0
+
+    def register(self, loop, registry, owner):
+        if self not in gc.callbacks:
+            # first, so that the pause it times holds the other hooks'
+            # work at both ends (jax's own runs at start and stop)
+            gc.callbacks.insert(0, self)
+        handle = GCLoop(loop, registry)
+        self._loops.append(handle)
+        # an owner dropped without close() takes its registration with it
+        weakref.finalize(owner, self.unregister, handle)
+        return handle
+
+    def unregister(self, handle):
+        if handle in self._loops:
+            self._loops.remove(handle)
+
+    def __call__(self, phase, info):
+        if phase == "start":
+            if self._owner is not None or not self._loops:
+                return
+            self._owner = self._loops[-1]
+            if _GLOBAL.live:
+                self._span = _Span(_GLOBAL, self._owner.span_name,
+                                   {"generation": info["generation"]})
+                self._span.__enter__()
+            self._t0 = time.perf_counter_ns()
+            return
+        if self._owner is None:
+            return
+        seconds = (time.perf_counter_ns() - self._t0) * 1e-9
+        owner, self._owner = self._owner, None
+        owner.book(info["generation"], seconds)
+        span, self._span = self._span, None
+        if span is not None:
+            span.set(collected=info["collected"])
+            span.__exit__(None, None, None)
+
+
+_GC_WATCH = _GCWatch()
+
+
+def watch_gc(loop, registry, owner):
+    """Register the host loop ``loop`` (``serving``, ``train``) of
+    ``owner`` with the process's one collection hook, installing the hook
+    on first use: until the returned handle is closed, or ``owner`` is
+    collected, each collection is booked in ``registry`` under
+    ``<loop>_gc_*`` and, while the tracer is live, recorded as a span
+    ``<loop>_gc``. Of several loops registered, the newest owns the
+    collections."""
+    return _GC_WATCH.register(loop, registry, owner)

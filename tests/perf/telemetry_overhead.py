@@ -74,6 +74,9 @@ Asserts:
   — adds ZERO train-step compiles; with ``jax.device_get`` poisoned
   the aggregator keeps scraping and every merged view still answers
   (a fleet scrape is host HTTP over host snapshots, nothing more);
+* the garbage-collection hook (``tracer.watch_gc``): with the tracer not
+  live, booking one collection (its start and its stop: two clock reads,
+  one liveness check, the counter updates) costs < 2 µs, like a span;
 * ``guardian``: an ARMED guardian with no anomalies is free — a 20-step
   run with guardian + health on still compiles the train step exactly
   ONCE (the guardian owns zero compiled programs, statically guarded:
@@ -1217,6 +1220,44 @@ def check_chronicle_disabled_emit_under_2us(iters=100_000):
     print(f"disabled chronicle path: {per_us:.3f} us/emit, 0 retained")
 
 
+def check_gc_hook_quiet_under_2us(iters=100_000):
+    """A registered loop with the tracer not live: the hook's start and
+    stop of one collection (called as the collector calls them) fit the
+    disabled span's budget, and book the collection and nothing else."""
+    from deepspeed_tpu.telemetry import MetricsRegistry, Tracer, set_tracer
+    from deepspeed_tpu.telemetry import tracer as tracer_mod
+
+    class Owner:
+        pass
+
+    old = set_tracer(Tracer(enabled=False))
+    registry, owner = MetricsRegistry(), Owner()
+    handle = tracer_mod.watch_gc("serving", registry, owner)
+    hook = tracer_mod._GC_WATCH
+    start = {"generation": 0, "collected": 0, "uncollectable": 0}
+    stop = dict(start, collected=3)
+
+    def per_collection_us():
+        t0 = time.perf_counter()
+        for _ in range(iters):
+            hook("start", start)
+            hook("stop", stop)
+        return (time.perf_counter() - t0) / iters * 1e6
+    try:
+        per_us = min(per_collection_us() for _ in range(3))  # best of 3
+        assert tracer_mod.get_tracer().events() == []
+    finally:
+        handle.close()
+        set_tracer(old)
+    booked = registry.counter("serving_gc_collections_total",
+                              labels={"generation": "0"}).value
+    assert booked >= 3 * iters, booked
+    assert per_us < DISABLED_BUDGET_US, (
+        f"quiet gc hook {per_us:.3f} us/collection exceeds the "
+        f"{DISABLED_BUDGET_US} us budget")
+    print(f"quiet gc hook: {per_us:.3f} us/collection")
+
+
 def check_chronicle_writer_books_nothing_into_ledger(events=500):
     """The background stream writer runs under the ledger's
     ``suppress_attribution()`` — shipping events must leave every booked
@@ -1397,6 +1438,7 @@ def main(iters=200_000):
     check_guardian_disabled_inert()
     check_chronicle_armed_zero_extra_compiles()
     check_chronicle_disabled_emit_under_2us()
+    check_gc_hook_quiet_under_2us()
     check_chronicle_writer_books_nothing_into_ledger()
     check_federation_zero_extra_compiles()
     check_federation_no_device_access()

@@ -53,9 +53,13 @@ def _inside(child, parent):
 
 def _children(events, parent):
     """Events (other than ``parent``) that lie inside it, by start; ties in
-    the microsecond clock keep the order the spans closed in."""
+    the microsecond clock keep the order the spans closed in. A garbage
+    collection's span (``serving_gc``, ``train_gc``) lies under whatever
+    span was open when the collector ran, so it is no part of the step's
+    structure and is left out."""
     return sorted((e for e in events if e is not parent
-                   and e["ph"] == "X" and _inside(e, parent)),
+                   and e["ph"] == "X" and not e["name"].endswith("_gc")
+                   and _inside(e, parent)),
                   key=lambda e: e["ts"])
 
 
@@ -254,6 +258,10 @@ def test_serving_step_spans_nest_and_count(fresh_tracer, tmp_path,
             assert n_tokens == in_flight
             in_flight = 0
     assert not in_flight
+    for e in events:
+        if e["name"] == "serving_gc":
+            assert e["args"]["generation"] in (0, 1, 2)
+            assert "collected" in e["args"]
     assert sum(n for n, *_ in per_step) > 4     # the prompts took chunks
     assert (needed_c.value, visited_c.value) == (total_needed, total_visited)
     assert 0 < total_needed <= total_visited
