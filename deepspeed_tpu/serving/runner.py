@@ -34,11 +34,13 @@ The four compiled programs are the forward's callers:
   (serving/sampling.py), ``decode_steps`` times in one dispatch.
   Compiled once for the whole serving lifetime — request churn only
   changes tensor *values*.
-* ``prefill_chunk`` — fills one slot's prompt KV ``chunk`` tokens at a
-  time (serving/prefill.py plans the chunks) so a long prompt never
-  stalls the decode batch: the forward at ``B = 1``, no head. Also
-  compiled once: the final short chunk is padded and its tail writes are
-  routed to the null block.
+* ``prefill_chunk`` — fills prompt KV ``chunk`` tokens at a time
+  (serving/prefill.py plans the chunks) so a long prompt never stalls
+  the decode batch: a step's chunks ``R`` at a time, one slot a row, the
+  forward at ``B = R``, no head (at ``R = 1`` the lone-slot form, with no
+  row dimension). Also compiled once: a short chunk is padded, the last
+  dispatch of a step with pad rows, and their writes are routed to the
+  null block.
 * the speculative draft and verify programs (serving/speculative.py):
   the forward at ``C = 1`` over a layer prefix, and at ``C = K+1``.
 
@@ -437,10 +439,12 @@ class _SSMHybridBlocks:
     def _mamba(self, m, p, h, pos, real, pools, slot):
         """The Mamba-2 mixer of rows ``h [B*C, E]``: ``C`` tokens of each
         of ``B`` slots (a decode step: every slot in order, ``C = 1``,
-        ``slot`` None; a prefill chunk: slot ``slot``, ``B = 1``, through
-        the chunked scan whatever its width), at positions ``pos [B, C]``, the
-        real ones ``real [B, C]``. Returns its output rows and the pools
-        with pool layer ``m`` of those slots' state moved past the real
+        ``slot`` None; prefill chunks through the chunked scan whatever
+        their width: of slot ``slot`` at ``B = 1``, or of the slots
+        ``slot [B]``, one a row, where a row with no real token is a pad
+        row and moves no state), at positions ``pos [B, C]``, the real
+        ones ``real [B, C]``. Returns its output rows and the pools with
+        pool layer ``m`` of those slots' state moved past the real
         tokens."""
         cfg = self.cfg
         B, C = pos.shape
@@ -454,6 +458,8 @@ class _SSMHybridBlocks:
         fresh = pos[:, 0] == 0                  # the first token: state 0
         if slot is None:                        # every slot, in order
             prev_conv = pools["conv"][m]
+        elif slot.ndim:                         # a slot a row
+            prev_conv = pools["conv"][m, slot]
         else:
             prev_conv = jax.lax.dynamic_index_in_dim(pools["conv"][m], slot,
                                                      keepdims=True)
@@ -483,6 +489,19 @@ class _SSMHybridBlocks:
             pools = dict(pools, ssm=state, conv=pools["conv"].at[m].set(
                 new_conv))
             y = y[:, None]
+        elif slot.ndim:                         # chunks, a slot a row
+            rows = pools["ssm"][m, slot]
+            s0 = jnp.where(fresh[:, None, None, None], 0.0,
+                           ssm.to_heads(rows, Hm, P).astype(f32))
+            y, s_end = jax.vmap(ssm.chunk_scan, (0, 0, 0, 0, None, 0))(
+                x, b, c, step, a, s0)
+            # a pad row's index lies past the slots: the scatters drop it
+            to = jnp.where(n_real > 0, slot, pools["ssm"].shape[1])
+            pools = dict(
+                pools,
+                ssm=pools["ssm"].at[m, to].set(
+                    ssm.from_heads(s_end).astype(rows.dtype), mode="drop"),
+                conv=pools["conv"].at[m, to].set(new_conv, mode="drop"))
         else:
             rows = pools["ssm"][m, slot]
             s0 = jnp.where(fresh[0], 0.0,
@@ -589,6 +608,20 @@ class PagedRunner:
     # ------------------------------------------------------- the forward
     def _forward(self, params, scales, pools, bt, past_lens, tok, pos, write,
                  n_layers=None, want_logits=True, slot=None):
+        """The serving forward pass (:meth:`_layers`), its cache rows
+        written in ONE scatter per pool, and the head. Returns ``(pools,
+        logits [B*C, V], counts)``, the logits ``None`` unless wanted."""
+        pools, x, counts, rows = self._layers(
+            params, scales, pools, bt, past_lens, tok, pos, write, n_layers,
+            slot)
+        if rows is not None:
+            pools = self.cache.write_layers(pools, *rows)
+        if not want_logits:
+            return pools, None, counts
+        return pools, self.blocks.head(params, x), counts
+
+    def _layers(self, params, scales, pools, bt, past_lens, tok, pos, write,
+                n_layers=None, slot=None):
         """The serving forward pass: ``C`` tokens for each of ``B`` slots.
 
         tok/pos ``[B, C]``: the tokens and their absolute positions
@@ -597,15 +630,18 @@ class PagedRunner:
         candidate past a slot's budget are not: their cache rows go to
         the null block and their output rows are discarded by the
         caller); bt ``[B, MB]``; past_lens ``[B]``: tokens ALREADY in
-        the pool; slot: the one slot whose per-slot state a chunk
-        (``B = 1``) moves on, a traced scalar (``None``: the ``B`` rows
-        are the slots, in order).
+        the pool; slot: the slots whose per-slot state prefill chunks
+        move on, a traced scalar for one chunk (``B = 1``) or ``[B]``, one
+        a row (``None``: the ``B`` rows are the slots, in order).
 
-        Embeds, runs the first ``n_layers`` blocks (default: all),
-        writes those layers' cache rows in ONE scatter per pool, and
-        returns ``(pools, logits [B*C, V], counts)``, the logits ``None``
-        unless wanted, the counts the expert layers' summed (``None``
-        for a model without experts).
+        Embeds, runs the first ``n_layers`` blocks (default: all) and
+        returns ``(pools, x [B*C, E], counts, rows)``: the pools as the
+        layers left them (moved on only where a layer carries per-slot
+        state), the last layer's output rows, the expert layers' counts
+        summed (``None`` for a model without experts), and what
+        ``cache.write_layers`` takes to write those layers' cache rows:
+        ``(new, block_ids, offsets)``, or ``None`` where no layer caches a
+        row.
 
         ``n_layers < cfg.n_layer`` is the truncated-layer self-draft of
         serving/speculative.py: the SAME params pytree traced over a
@@ -627,16 +663,14 @@ class PagedRunner:
                 new.append(rows)
             if n is not None:
                 counts = n if counts is None else counts + n
+        if not new:
+            return pools, x, counts, None
         row = jnp.take_along_axis(
             bt, jnp.minimum(pos // bs, bt.shape[1] - 1), axis=1)
-        if new:
-            pools = self.cache.write_layers(
-                pools, {name: jnp.stack([rows[name] for rows in new])
-                        for name in new[0]},
-                jnp.where(write, row, 0).reshape(-1), (pos % bs).reshape(-1))
-        if not want_logits:
-            return pools, None, counts
-        return pools, blocks.head(params, x), counts
+        return pools, x, counts, (
+            {name: jnp.stack([rows[name] for rows in new])
+             for name in new[0]},
+            jnp.where(write, row, 0).reshape(-1), (pos % bs).reshape(-1))
 
     # ---------------------------------------------------------- programs
     def _decode_impl(self, params, scales, pools, bt, pos, active, tok,
@@ -686,17 +720,73 @@ class PagedRunner:
             body, (pools, tok), jnp.arange(K, dtype=jnp.int32))
         return pools, toks, None if counts is None else counts.sum(0)
 
-    def _prefill_impl(self, params, scales, pools, bt_row, tokens, start,
+    @staticmethod
+    def _chunk_rows(bt, tokens, start, n_valid):
+        """The forward's ``bt, past_lens, tok, pos, write`` for prefill
+        chunks: one a row (bt ``[R, MB]``, tokens ``[R, C]``, the rest
+        ``[R]``), or one chunk without the row dimension (bt ``[MB]``,
+        tokens ``[C]``, scalars), which the walk runs with no batch
+        dimension (:func:`_attend_in_lanes`)."""
+        if tokens.ndim == 1:
+            idx = jnp.arange(tokens.shape[0], dtype=jnp.int32)
+            return (bt[None], start[None], tokens[None],
+                    (start + idx)[None], (idx < n_valid)[None])
+        idx = jnp.arange(tokens.shape[1], dtype=jnp.int32)
+        return (bt, start, tokens, start[:, None] + idx,
+                idx < n_valid[:, None])
+
+    def _prefill_impl(self, params, scales, pools, bt, tokens, start,
                       n_valid, slot=None):
-        """One slot's chunk: the forward at ``B = 1``, no head; the
-        positions past ``n_valid`` are the final chunk's pad; ``slot``
-        names the slot whose per-slot state the chunk moves on (a model
-        without one does not read it)."""
-        idx = jnp.arange(tokens.shape[0], dtype=jnp.int32)
-        pools, _, counts = self._forward(
-            params, scales, pools, bt_row[None], start[None], tokens[None],
-            (start + idx)[None], (idx < n_valid)[None], want_logits=False,
-            slot=slot)
+        """``R`` slots' chunks, one a row (:meth:`_chunk_rows`), no head:
+        the positions past a row's ``n_valid`` are its chunk's pad;
+        ``slot [R]`` names the slot whose per-slot state each row moves on
+        (a model without one does not read it). Rows are filled in order,
+        so a pad row (``n_valid`` 0, a table of zeros: it writes only to
+        the null block and moves no state) follows the real ones.
+
+        A call that holds one chunk runs it alone, in the lone-slot form:
+        a row costs about a whole chunk wherever it is padding (on a v5e at
+        gpt2-medium's widths 1.64 of a chunk's 2.05 ms; PERF.md,
+        Findings). The conditional that runs the layers only computes the
+        rows to cache (a pool written inside it is copied whole); the
+        first chunk's rows are written after it, the other rows' only
+        where the call holds more than one chunk, each write in place. So
+        a model whose layers write their per-slot state as they go runs
+        every row, and so does a call of one row. One chunk handed over
+        without the row dimension is the lone-slot form and nothing
+        else."""
+        if tokens.ndim == 1 or len(tokens) == 1 or serves_state(self.cfg):
+            pools, _, counts = self._forward(
+                params, scales, pools, *self._chunk_rows(bt, tokens, start,
+                                                         n_valid),
+                want_logits=False, slot=slot)
+            return pools, counts
+        width = tokens.size
+
+        def cached(*chunks):
+            """The rows the chunks cache (padded to the call's width:
+            the rest go to the null block) and the expert counts."""
+            _, _, counts, (new, ids, offs) = self._layers(
+                params, scales, pools, *self._chunk_rows(*chunks))
+            pad = width - ids.shape[0]
+            new = {name: jnp.pad(v, [(0, 0), (0, pad)] + [(0, 0)] * (
+                v.ndim - 2)) for name, v in new.items()}
+            return new, jnp.pad(ids, (0, pad)), jnp.pad(offs, (0, pad)), \
+                counts
+
+        more = jnp.any(n_valid[1:] > 0)
+        new, ids, offs, counts = jax.lax.cond(
+            more, lambda: cached(bt, tokens, start, n_valid),
+            lambda: cached(bt[0], tokens[0], start[0], n_valid[0]))
+
+        def rows(lo, hi):
+            return ({name: v[:, lo:hi] for name, v in new.items()},
+                    ids[lo:hi], offs[lo:hi])
+        C = tokens.shape[1]
+        pools = self.cache.write_layers(pools, *rows(0, C))
+        pools = jax.lax.cond(
+            more, lambda p: self.cache.write_layers(p, *rows(C, width)),
+            lambda p: p, pools)
         return pools, counts
 
     # -------------------------------------------------------- public API
@@ -715,11 +805,13 @@ class PagedRunner:
             self.expert_counts.append(counts)
         return pools, toks
 
-    def prefill_chunk(self, params, scales, pools, bt_row, tokens, start,
+    def prefill_chunk(self, params, scales, pools, bt, tokens, start,
                       n_valid, slot=None):
-        """Fill ``n_valid`` prompt tokens of slot ``slot``'s KV (and move
-        its per-slot state on); returns updated pools."""
-        pools, counts = self._prefill(params, scales or {}, pools, bt_row,
+        """Fill ``n_valid`` prompt tokens of each row's slot ``slot``'s KV
+        (and move its per-slot state on), one chunk a row or, without the
+        row dimension, one chunk (:meth:`_prefill_impl`); returns updated
+        pools."""
+        pools, counts = self._prefill(params, scales or {}, pools, bt,
                                       tokens, start, n_valid, slot)
         if counts is not None:
             self.expert_counts.append(counts)

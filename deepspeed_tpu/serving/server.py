@@ -8,7 +8,8 @@ layers that carry a state, pools of one row a slot beside it), the
 ``PagedRunner``'s two compiled programs, the FCFS continuous-batching scheduler, and chunked
 prefill. The API is deliberately synchronous — ``submit()`` enqueues,
 ``step()`` advances the world by one scheduler iteration (one bounded
-prefill chunk per still-prefilling slot + one decode dispatch),
+prefill chunk per still-prefilling slot, several slots' chunks to a
+prefill dispatch, + one decode dispatch),
 ``collect()`` drains finished requests — so a caller (or
 ``serve_forever``) owns the loop and there is no hidden thread to
 reason about.
@@ -330,7 +331,8 @@ class ServingEngine:
             self._verify_fn = self._watch.wrap(
                 self.speculative.verify_step, name="serving_verify_step")
         self.prefill = ChunkedPrefill(self._prefill_fn,
-                                      chunk_size=config.prefill_chunk)
+                                      chunk_size=config.prefill_chunk,
+                                      max_batch=self.max_batch)
         from jax.sharding import NamedSharding, PartitionSpec
         self.pools = self.cache.init_pools(
             NamedSharding(engine.mesh, PartitionSpec()))
@@ -363,7 +365,8 @@ class ServingEngine:
             f"blocks={num_blocks} (usable "
             f"{self.cache.allocator.num_usable}) "
             f"max_model_len={self.max_model_len} "
-            f"prefill_chunk={self.prefill.chunk_size} "
+            f"prefill_chunk={self.prefill.chunk_size}"
+            f"x{self.prefill.rows} "
             f"kv={'int8' if int8_kv else 'native'}"
             + (f" speculative=k{self.speculative.k}/"
                f"L{self.speculative.draft_layers}"
@@ -422,7 +425,8 @@ class ServingEngine:
     # -------------------------------------------------------------- step
     def step(self) -> bool:
         """One scheduler iteration: admission, one prefill chunk per
-        still-prefilling slot, one decode dispatch, and the landing of
+        still-prefilling slot (``prefill.rows`` chunks to a dispatch, in
+        the plan's order), one decode dispatch, and the landing of
         the decode dispatch of the step BEFORE. Returns True when any
         work was done, so also while tokens are in flight.
 
@@ -452,8 +456,9 @@ class ServingEngine:
             # land before any dispatch reads or writes it
             for req in plan.cow_forks:
                 progress |= self._run_cow_fork(req)
-            for req in plan.prefill:
-                progress |= self._run_prefill(req)
+            rows = self.prefill.rows
+            for i in range(0, len(plan.prefill), rows):
+                progress |= self._run_prefill(plan.prefill[i:i + rows])
             ahead = bool(plan.decode_slots) and self._in_flight is not None
             if plan.decode_slots:
                 self._run_decode(plan.decode_slots)
@@ -646,24 +651,45 @@ class ServingEngine:
                 req.block_table[b])
             req.indexed_blocks += 1
 
-    def _run_prefill(self, req) -> bool:
-        slot, start = req.slot, req.cached_len
+    def _run_prefill(self, reqs) -> bool:
+        """One prefill dispatch: the next chunk of each of ``reqs``, each
+        of its own slot, one ``serving_prefill`` span a chunk inside the
+        dispatch's span."""
         t0 = time.perf_counter_ns()
-        # a chunk at position 0 starts its slot's state from zero
-        state = ({"state_from_zero": int(start == 0)}
-                 if self._state_layers else {})
-        if state.get("state_from_zero"):
-            self._count_state_resets(1)
-        with trace_span("serving_prefill", req=req.req_id, start=start,
-                        tokens=min(self.prefill.chunk_size,
-                                   self.prefill.remaining(req)),
-                        **state) as span:
+        chunks = []
+        with trace_span("serving_prefill_dispatch", rows=self.prefill.rows,
+                        chunks=len(reqs)):
+            for req in reqs:
+                # a chunk at position 0 starts its slot's state from zero
+                state = ({"state_from_zero": int(req.cached_len == 0)}
+                         if self._state_layers else {})
+                if state.get("state_from_zero"):
+                    self._count_state_resets(1)
+                with trace_span("serving_prefill", req=req.req_id,
+                                start=req.cached_len, **state) as span:
+                    chunk = self.prefill.plan(req)
+                    span.set(tokens=chunk.n_valid,
+                             recompute=chunk.n_recompute)
+                chunks.append(chunk)
             with self.engine.mesh:
-                self.pools, n_valid, n_recompute, done = self.prefill.run(
+                self.pools = self.prefill.dispatch(
                     self.engine.params, self.engine.quant_scales,
-                    self.pools, req, self.max_blocks_per_seq)
-            span.set(recompute=n_recompute)
+                    self.pools, chunks, self.max_blocks_per_seq)
         t1 = time.perf_counter_ns()
+        self.registry.counter(
+            "serving_prefill_dispatches_total",
+            "prefill program calls, each of one or more chunks").inc()
+        for chunk in chunks:
+            self._book_prefill(chunk, t0, t1)
+        return True
+
+    def _book_prefill(self, chunk, t0, t1):
+        """A dispatched chunk's counters, slot-step act, prefix index and
+        observatory record; a request whose prompt is cached runs."""
+        req, n_valid, n_recompute = chunk.req, chunk.n_valid, \
+            chunk.n_recompute
+        slot = req.slot
+        done = self.prefill.remaining(req) == 0
         self.registry.counter("serving_prefill_chunks_total",
                               "prefill chunks executed").inc()
         self.registry.counter("serving_prefill_tokens_total",
@@ -684,11 +710,10 @@ class ServingEngine:
                                   else "prefill"), n_valid)
         self._index_blocks(req)
         if self.observatory is not None:
-            self.observatory.record_prefill(req, slot, start, n_valid,
+            self.observatory.record_prefill(req, slot, chunk.start, n_valid,
                                             n_recompute, t0, t1, done)
         if done:
             req.state = RequestState.RUNNING
-        return True
 
     def _decode_inputs(self, decode_slots, prev):
         """The decode program's host-built arguments: the block tables

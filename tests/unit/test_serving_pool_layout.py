@@ -27,6 +27,13 @@ width the compiler also relayouts the 1,600-wide ``wte`` (161 MB at the
 published vocabulary), which is no pool and would drown what is measured,
 and the sampler's sort over 50,257 logits is most of a compile's time.
 
+The prefill program is compiled in both its forms: one chunk a dispatch,
+and the rows that chunks of 128 take (``prefill_rows``: two). At the
+latent and the state-space models' chunk widths (512 and 256) the engine
+keeps one row, and the program it dispatches lowers to the text of the
+lone-slot program, which one chunk a dispatch ran before rows existed
+(``test_a_wide_chunks_prefill_is_the_lone_slot_program``).
+
 The training flash backward's shape rule is held to the same compile at
 its edges (``test_flash_backward_fits_the_chip``): which shapes take the
 one-pass kernel, and that each compiles within the scoped VMEM.
@@ -38,15 +45,18 @@ given this file may load the library (on-chip-measurement guide, §2).
 import functools
 import os
 import re
+import types
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
 from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2LMHeadModel
 from deepspeed_tpu.serving import paged_attention
 from deepspeed_tpu.serving.kv_cache import PagedKVCache
+from deepspeed_tpu.serving.prefill import ChunkedPrefill, prefill_rows
 from deepspeed_tpu.serving.runner import PagedGPT2Runner
 from deepspeed_tpu.serving.speculative import SpeculativeDecoder
 from deepspeed_tpu.utils import groups
@@ -58,6 +68,7 @@ CELLS = {
     "gpt2-xl": dict(n_embd=1600, n_head=25, slots=8, num_blocks=513),
 }
 N_LAYER, BLOCK_SIZE, MAX_BLOCKS, CHUNK, SPEC_K = 2, 16, 64, 32, 3
+ROWS_CHUNK = 128        # the gpt2 serve cells' chunk: two rows a dispatch
 # the decode program's temporaries at these shapes before it took the
 # dispatch before's tokens as an input (commit fb6d7b3, this compiler)
 DECODE_TEMP_BYTES = {("gpt2-medium", False): 2270720,
@@ -116,6 +127,7 @@ def _programs(cell, int8_kv, one_chip):
     pools = {name: spec(shape, dtype)
              for name, (shape, dtype) in cache._pool_shapes().items()}
     B, i32, f32 = cell["slots"], jnp.int32, jnp.float32
+    R = prefill_rows(ROWS_CHUNK, B)
     slot = [spec((B, MAX_BLOCKS), i32), spec((B,), i32),
             spec((B,), jnp.bool_)]                      # bt, pos, active
     sampling = [spec((B,), f32), spec((B,), f32),
@@ -129,6 +141,11 @@ def _programs(cell, int8_kv, one_chip):
         "prefill": (runner._prefill,
                     [params, {}, pools, spec((MAX_BLOCKS,), i32),
                      spec((CHUNK,), i32), spec((), i32), spec((), i32)]),
+        # R chunks of 128, one a row: tables, tokens, starts, lengths
+        "prefill_rows": (runner._prefill,
+                         [params, {}, pools, spec((R, MAX_BLOCKS), i32),
+                          spec((R, ROWS_CHUNK), i32), spec((R,), i32),
+                          spec((R,), i32)]),
         "copy_block": (runner._copy_block,
                        [pools, spec((), i32), spec((), i32)]),
         "draft": (spec_dec._draft,
@@ -174,6 +191,33 @@ def test_program_leaves_the_pools_in_place(one_chip, monkeypatch, config,
         f"only {mem.alias_size_in_bytes} of the pools' {pool_bytes} "
         f"bytes are updated in place")
     copies = _pool_copies(compiled.as_text(), pools.values())
+    assert not copies, "whole-pool copies:\n" + "\n".join(copies)
+
+
+@pytest.mark.parametrize("int8_kv", [False, True], ids=["kv-bf16", "kv-int8"])
+@pytest.mark.parametrize("config", list(CELLS))
+def test_the_rows_prefill_program_leaves_the_pools_in_place(
+        one_chip, monkeypatch, config, int8_kv):
+    """The prefill program at two rows of 128 (a call of two chunks, or
+    of one run alone: both branches in one program) updates every pool in
+    place and copies none whole. Its temporaries are the two rows'
+    activations: 5.6-7.6 MB at these widths, 3.7-4.4 times the one-chunk
+    program's at 128 tokens (this compiler), where one pool copied whole
+    would add 71-336 MB (the bound on the programs above, 5% of the
+    pools, is under a two-row call's activations at gpt2-xl's)."""
+    monkeypatch.setattr(paged_attention, "_interpret", lambda: False)
+    monkeypatch.setattr(groups, "_MESH", None)
+    cache, pools, programs = _programs(CELLS[config], int8_kv, one_chip)
+    fn, args = programs["prefill_rows"]
+    assert args[4].shape == (2, ROWS_CHUNK)
+    compiled = fn.lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 0
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 16e6, (
+        f"{mem.temp_size_in_bytes / 1e6:.1f} MB of temporaries")
+    assert mem.alias_size_in_bytes >= cache.pool_bytes()
+    copies = _pool_copies(text, pools.values())
     assert not copies, "whole-pool copies:\n" + "\n".join(copies)
 
 
@@ -226,7 +270,7 @@ def _latent_programs(one_chip, slots=16, num_blocks=257, chunk=128):
     pools = {name: spec(shape, dtype)
              for name, (shape, dtype) in cache._pool_shapes().items()}
     B, i32, f32 = slots, jnp.int32, jnp.float32
-    return cache, pools, {
+    return cache, pools, runner, {
         "decode": (runner._decode,
                    [params, {}, pools, spec((B, MAX_BLOCKS), i32),
                     spec((B,), i32), spec((B,), jnp.bool_), spec((B,), i32),
@@ -254,7 +298,7 @@ def test_latent_program_leaves_its_one_pool_in_place(one_chip, monkeypatch,
     monkeypatch.setattr(paged_attention, "_interpret", lambda: False)
     monkeypatch.setattr(held_experts, "_interpret", lambda: False)
     monkeypatch.setattr(groups, "_MESH", None)
-    cache, pools, programs = _latent_programs(one_chip)
+    cache, pools, _, programs = _latent_programs(one_chip)
     assert {n: tuple(s.shape) for n, s in pools.items()} == {
         "kv": (3 * 257, 16, 640)}
     fn, args = programs[program]
@@ -342,7 +386,7 @@ def _state_programs(one_chip, slots=64, num_blocks=1375, chunk=256):
     pools = {name: spec(shape, dtype)
              for name, (shape, dtype) in cache._pool_shapes().items()}
     B, i32, f32 = slots, jnp.int32, jnp.float32
-    return cache, pools, {
+    return cache, pools, runner, {
         "decode": (runner._decode,
                    [params, {}, pools, spec((B, MAX_BLOCKS), i32),
                     spec((B,), i32), spec((B,), jnp.bool_), spec((B,), i32),
@@ -368,7 +412,7 @@ def test_state_programs_hold_one_state_pool(one_chip, monkeypatch, program):
     monkeypatch.setattr(paged_attention, "_interpret", lambda: False)
     monkeypatch.setattr(ssm_decode, "_interpret", lambda: False)
     monkeypatch.setattr(groups, "_MESH", None)
-    cache, pools, programs = _state_programs(one_chip)
+    cache, pools, _, programs = _state_programs(one_chip)
     assert {n: tuple(s.shape) for n, s in pools.items()} == {
         "k": (1375, 16, 512), "v": (1375, 16, 512),
         "ssm": (2, 64, 32, 128, 128), "conv": (2, 64, 3 * 4352)}
@@ -385,6 +429,66 @@ def test_state_programs_hold_one_state_pool(one_chip, monkeypatch, program):
         f"{state / 1e6:.1f} MB of state pools")
     copies = _pool_copies(text, pools.values())
     assert not copies, "whole-pool copies:\n" + "\n".join(copies)
+
+
+# ------------------ one row a dispatch at the wide chunks (latent, state)
+# the cells' slots and chunks (benchmark/configs/*.json)
+WIDE_CHUNKS = {"dots": dict(slots=96, chunk=512),
+               "granite": dict(slots=64, chunk=256)}
+# a Mosaic kernel's body carries the source locations of its caller
+_MOSAIC_BODY = re.compile(r"\\22body\\22: \\22[A-Za-z0-9+/=]*\\22")
+
+
+def _lone_slot_prefill(runner):
+    """The prefill program as it was before it took rows: one slot's
+    chunk, the forward at ``B = 1`` with no row dimension."""
+    def _prefill_impl(params, scales, pools, bt_row, tokens, start,
+                      n_valid, slot=None):
+        idx = jnp.arange(tokens.shape[0], dtype=jnp.int32)
+        pools, _, counts = runner._forward(
+            params, scales, pools, bt_row[None], start[None], tokens[None],
+            (start + idx)[None], (idx < n_valid)[None], want_logits=False,
+            slot=slot)
+        return pools, counts
+    return jax.jit(_prefill_impl, donate_argnums=(2,))
+
+
+@pytest.mark.parametrize("model", list(WIDE_CHUNKS))
+def test_a_wide_chunks_prefill_is_the_lone_slot_program(one_chip,
+                                                        monkeypatch, model):
+    """At chunks of 256 and 512 a dispatch holds one chunk, handed over
+    without the row dimension, and the program lowers to the lone-slot
+    program's text (the Mosaic kernels' bodies aside, which name their
+    caller's source): the two cells run what they ran."""
+    from deepspeed_tpu.moe import held_experts
+    monkeypatch.setattr(paged_attention, "_interpret", lambda: False)
+    monkeypatch.setattr(held_experts, "_interpret", lambda: False)
+    monkeypatch.setattr(groups, "_MESH", None)
+    cell = WIDE_CHUNKS[model]
+    build = _latent_programs if model == "dots" else _state_programs
+    _, pools, runner, programs = build(one_chip, chunk=cell["chunk"])
+    fn, (params, scales, _, *lone) = programs["prefill"]
+    handed = []
+
+    def prefill_fn(params, scales, pools, *args):
+        handed.append(args)
+        return pools
+    planner = ChunkedPrefill(prefill_fn, cell["chunk"], cell["slots"])
+    assert planner.rows == 1
+    req = types.SimpleNamespace(full_prompt=list(range(2 * cell["chunk"])),
+                                cached_len=0, slot=3, block_table=[5, 6],
+                                max_cached_len=0)
+    planner.dispatch(None, None, None, [planner.plan(req)], MAX_BLOCKS)
+    (args,) = handed
+    spec = _spec(one_chip)
+    args = [spec(np.shape(a), jnp.int32) for a in args]
+    assert [a.shape for a in args] == [a.shape for a in lone] + [()] * (
+        5 - len(lone))
+
+    def text(f, a):
+        return _MOSAIC_BODY.sub("", f.lower(params, scales, pools,
+                                            *a).as_text())
+    assert text(fn, args) == text(_lone_slot_prefill(runner), args)
 
 
 # The training flash backward's shape rule (``flash._fused_bwd_fits``),
