@@ -82,6 +82,9 @@ class MLAMoEConfig:
     rope_mscale: float = 1.0
     rope_mscale_all_dim: float = 0.0
 
+    # the experts' kind (moe/held_experts.py): SwiGLU, as in every file
+    mlp_hidden_act = "silu"
+
     def __post_init__(self):
         first, stop = self.experts_held
         if not 0 <= first < stop <= self.n_routed_experts:

@@ -1,23 +1,42 @@
 """A hybrid decoder: Mamba-2 state-space layers beside grouped-query
-attention layers without positions.
+attention layers without positions, and (in one of the two families)
+layers of routed experts.
 
-The family of ``granitemoehybrid`` language models with no experts
-(``granite-4.0-h-micro`` is the one the benchmark runs): ``layer_types``
-names each layer ``mamba`` or ``attention``. Every layer is RMSNorm, its
-mixer, RMSNorm, a SwiGLU MLP, each scaled by ``residual_multiplier``
-onto the residual; the embedding is scaled by ``embedding_multiplier``,
-the tied head's logits divided by ``logits_scaling``.
+``layer_types`` names each layer ``mamba``, ``attention`` or ``moe``. Two
+published families run through it, told apart by what their
+configurations declare:
 
-* attention: ``num_attention_heads`` query heads over
+* ``granitemoehybrid`` with no experts (``granite-4.0-h-micro``):
+  ``mlp_after_mixer``: every layer is RMSNorm, its mixer, RMSNorm, a
+  SwiGLU MLP, each scaled by ``residual_multiplier`` onto the residual;
+  the embedding is scaled by ``embedding_multiplier``, the tied head's
+  logits divided by ``logits_scaling``.
+* ``nemotron_h`` (``NVIDIA-Nemotron-3-Nano-30B-A3B``): a layer is ONE part
+  alone, RMSNorm and a Mamba-2 mixer, an attention layer or an expert
+  layer, added to the residual; untied head, no multipliers.
+
+The parts:
+
+* attention: ``num_attention_heads`` query heads of ``head_dim`` over
   ``num_key_value_heads`` key/value heads (query head ``i`` reads KV head
   ``i // group``), no rotary and no position term (``nope``), scores
   scaled by ``attention_multiplier``;
 * Mamba-2: ``in_proj`` gives ``z`` (the gate), ``xBC`` (through a causal
   depthwise convolution of ``mamba_d_conv`` taps and a SiLU: the heads'
-  inputs ``x``, and ``B`` and ``C`` of ``mamba_d_state`` shared by every
-  head) and ``dt`` a head; each head carries a ``head_dim x d_state``
-  state (ops/ssm); the output is gated by ``silu(z)``, RMS-normed over all
-  its channels and projected back.
+  inputs ``x``, and ``B`` and ``C`` of ``mamba_d_state`` for each of
+  ``mamba_n_groups`` groups: head ``h`` reads group ``h // (heads /
+  groups)``) and ``dt`` a head; each head carries a ``head_dim x d_state``
+  state (ops/ssm); the output is gated by ``silu(z)``, RMS-normed over
+  each group's channels (one gain over all of them) and projected back;
+* experts (moe/held_experts.py): a sigmoid router over
+  ``n_routed_experts`` outputs with a selection bias, the top
+  ``num_experts_per_tok``, the chosen scores normalised and scaled by
+  ``routed_scaling_factor``; the experts ``experts_held = (first, stop)``
+  of them are held here (a chip's share; the router keeps its published
+  width), each a squared-ReLU MLP (``relu(h U)^2 D``) of
+  ``moe_intermediate_size``, and one shared expert of
+  ``moe_shared_expert_intermediate_size`` beside them. What the absent
+  experts would add is left out.
 
 This file holds the configuration and the parameter tree, nothing that
 runs: the serving forward over them is serving/runner.py's (the one
@@ -26,21 +45,28 @@ There is no training forward here (ROADMAP B says what stays).
 
 Parameter tree (no bias but the convolution's; ``E`` hidden, ``I`` the MLP
 width, ``H``/``K`` query/KV heads of ``D``, ``Hm`` Mamba heads of ``P``,
-``W = Hm*P + 2*d_state`` the convolved channels)::
+``G`` groups, ``W = Hm*P + 2*G*d_state`` the convolved channels, ``X``
+router outputs, ``M``/``S`` the routed/shared experts' width)::
 
-    embed [V, E] (the head is its transpose)     norm_f [E]
-    h_<i>/norm_1, norm_2 [E]
-    h_<i>/mlp: w_in [E, 2I] (gate ‖ up)   w_out [I, E]
+    embed [V, E]    head [V, E] (untied only; else embed's transpose)
+    norm_f [E]
+    h_<i>/norm_1 [E]
+    h_<i>/norm_2 [E], mlp: w_in [E, 2I] (gate ‖ up)   w_out [I, E]
+        (``mlp_after_mixer`` only)
     h_<i>/attn (attention layers): q [E, H*D]  k, v [E, K*D]  o [H*D, E]
-    h_<i>/mamba (the others): in_proj [E, Hm*P + W + Hm] (z ‖ xBC ‖ dt)
+    h_<i>/mamba (Mamba layers): in_proj [E, Hm*P + W + Hm] (z ‖ xBC ‖ dt)
         conv_w [d_conv, W] (tap k meets the input k - d_conv + 1 tokens
         back)   conv_b [W]   A_log, D, dt_bias [Hm]   norm [Hm*P]
         out_proj [Hm*P, E]
+    h_<i>/moe (expert layers): router [E, X]   router_bias [X]
+        shared: up [E, S]   down [S, E]
+        experts: up [held, M, E] (as the published up_proj)
+                 down [held, M, E]
 """
 
 import dataclasses
 import math
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -65,15 +91,45 @@ class SSMHybridConfig:
     embedding_multiplier: float = 1.0
     residual_multiplier: float = 1.0
     logits_scaling: float = 1.0
+    head_dim: Optional[int] = None      # None: hidden_size / query heads
+    tie_word_embeddings: bool = True
+    mlp_after_mixer: bool = True        # False: a layer is its one part
+    # the expert layers (``moe`` in layer_types)
+    n_routed_experts: int = 0           # the router's width
+    experts_held: Tuple[int, int] = (0, 0)  # [first, stop) of them here
+    num_experts_per_tok: int = 0
+    moe_intermediate_size: int = 0
+    moe_shared_expert_intermediate_size: int = 0
+    routed_scaling_factor: float = 1.0
+
+    # the router and the experts' kind (moe/held_experts.py): one routing
+    # group, squared ReLU, as nemotron_h's
+    n_group = topk_group = 1
+    mlp_hidden_act = "relu2"
 
     def __post_init__(self):
-        unknown = set(self.layer_types) - {"mamba", "attention"}
+        unknown = set(self.layer_types) - {"mamba", "attention", "moe"}
         if unknown:
             raise ValueError(f"layer_types names {sorted(unknown)}: a layer "
-                             f"is 'mamba' or 'attention'")
+                             f"is 'mamba', 'attention' or 'moe'")
         if self.num_attention_heads % self.num_key_value_heads:
             raise ValueError("num_attention_heads must divide into "
                              "num_key_value_heads")
+        if self.mamba_n_heads % self.mamba_n_groups:
+            raise ValueError("mamba_n_heads must divide into mamba_n_groups")
+        if self.head_dim is None:
+            object.__setattr__(self, "head_dim", self.hidden_size
+                               // self.num_attention_heads)
+        if self.expert_layers:
+            if self.mlp_after_mixer:
+                raise ValueError("an expert layer is a layer of its own: "
+                                 "'moe' in layer_types needs "
+                                 "mlp_after_mixer False")
+            first, stop = self.experts_held
+            if not 0 <= first < stop <= self.n_routed_experts:
+                raise ValueError(f"experts_held {self.experts_held} is no "
+                                 f"range of the {self.n_routed_experts} "
+                                 f"routed experts")
 
     # the names the server reads of every model
     @property
@@ -83,10 +139,6 @@ class SSMHybridConfig:
     @property
     def n_positions(self) -> int:
         return self.max_position_embeddings
-
-    @property
-    def head_dim(self) -> int:
-        return self.hidden_size // self.num_attention_heads
 
     @property
     def softmax_scale(self) -> float:
@@ -101,6 +153,14 @@ class SSMHybridConfig:
     def mamba_layers(self) -> Tuple[int, ...]:
         return tuple(i for i, t in enumerate(self.layer_types)
                      if t == "mamba")
+
+    @property
+    def expert_layers(self) -> Tuple[int, ...]:
+        return tuple(i for i, t in enumerate(self.layer_types) if t == "moe")
+
+    @property
+    def n_held(self) -> int:
+        return self.experts_held[1] - self.experts_held[0]
 
     @property
     def mamba_inner(self) -> int:
@@ -128,9 +188,10 @@ class SSMHybridForCausalLM:
 
 def init_params(cfg: SSMHybridConfig, key, dtype=jnp.float32, std=0.02):
     """A seeded parameter tree in the layout of the module docstring:
-    matrices N(0, ``std``), norm gains 1, and Mamba-2's published dynamics:
-    ``A_log = log U[1, 16]``, ``dt_bias`` the inverse softplus of a step
-    log-uniform on [0.001, 0.1], ``D = 1``, the convolution U(+-1/2)."""
+    matrices N(0, ``std``), norm gains 1, the router's selection bias 0,
+    and Mamba-2's published dynamics: ``A_log = log U[1, 16]``,
+    ``dt_bias`` the inverse softplus of a step log-uniform on [0.001,
+    0.1], ``D = 1``, the convolution U(+-1/2)."""
     E, I, D = cfg.hidden_size, cfg.intermediate_size, cfg.head_dim
     H, K, Hm = cfg.num_attention_heads, cfg.num_key_value_heads, \
         cfg.mamba_n_heads
@@ -147,14 +208,26 @@ def init_params(cfg: SSMHybridConfig, key, dtype=jnp.float32, std=0.02):
         return draw(lambda k, s: jax.random.uniform(k, s, minval=lo,
                                                     maxval=hi), *shape)
 
+
     tree = {"embed": w(cfg.vocab_size, E), "norm_f": jnp.ones((E,), dtype)}
+    if not cfg.tie_word_embeddings:
+        tree["head"] = w(cfg.vocab_size, E)
     for i, kind in enumerate(cfg.layer_types):
-        layer = {"norm_1": jnp.ones((E,), dtype),
-                 "norm_2": jnp.ones((E,), dtype),
-                 "mlp": {"w_in": w(E, 2 * I), "w_out": w(I, E)}}
+        layer = {"norm_1": jnp.ones((E,), dtype)}
+        if cfg.mlp_after_mixer:
+            layer["norm_2"] = jnp.ones((E,), dtype)
+            layer["mlp"] = {"w_in": w(E, 2 * I), "w_out": w(I, E)}
         if kind == "attention":
             layer["attn"] = {"q": w(E, H * D), "k": w(E, K * D),
                              "v": w(E, K * D), "o": w(H * D, E)}
+        elif kind == "moe":
+            X, M = cfg.n_routed_experts, cfg.moe_intermediate_size
+            S = cfg.moe_shared_expert_intermediate_size
+            layer["moe"] = {
+                "router": w(E, X), "router_bias": jnp.zeros((X,), dtype),
+                "shared": {"up": w(E, S), "down": w(S, E)},
+                "experts": {"up": w(cfg.n_held, M, E),
+                            "down": w(cfg.n_held, M, E)}}
         else:
             step = jnp.exp(uniform(math.log(1e-3), math.log(1e-1), Hm))
             layer["mamba"] = {

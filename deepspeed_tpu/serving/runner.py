@@ -9,7 +9,7 @@ serving/kv_cache.py, ONCE: :meth:`PagedRunner._forward` embeds ``[B, C]``
 tokens at their own positions, walks the blocks, attends over each
 slot's past pages plus the chunk itself, writes the cache rows of every
 layer that ran in one scatter per pool, and returns the logits. The
-model's configuration says which of the two block definitions the
+model's configuration says which of the three block definitions the
 forward walks (no option and no model's name):
 
 * GPT-2's (:class:`_GPT2Blocks`): LayerNorm, fused QKV with heads of
@@ -22,10 +22,13 @@ forward walks (no option and no model's name):
   the expert layer of moe/held_experts.py over the experts this chip
   holds; untied head; one ``kv`` pool.
 * the state-space hybrid (:class:`_SSMHybridBlocks`; models/ssm_hybrid.py):
-  RMSNorm, SwiGLU, and as ``layer_types`` says a Mamba-2 mixer, whose
-  per-slot state (ops/ssm) a layer reads and writes back where it lies
-  inside the layer loop, or grouped-query attention with no positions
-  over K and V pools that hold the attention layers alone; tied head.
+  RMSNorm, and as ``layer_types`` says a Mamba-2 mixer with grouped ``B``
+  and ``C``, whose per-slot state (ops/ssm) a layer reads and writes back
+  where it lies inside the layer loop, grouped-query attention with no
+  positions over K and V pools that hold the attention layers alone, or
+  an expert layer (the one the latent block runs, of the experts' kind
+  the configuration names); each mixer followed by a SwiGLU MLP, or each
+  layer its one part; tied or untied head.
 
 The four compiled programs are the forward's callers:
 
@@ -60,8 +63,9 @@ What is not served is refused at construction with an error that names
 the mechanism (:class:`ServingNotSupported`), never mid-step: a GPT-2
 tree with rotary positions or experts in its blocks, pipeline stages,
 ring / Ulysses / block-sparse attention; over a latent cache, int8
-pools and int8 weights; for a model with per-slot state, grouped ``B`` and
-``C`` (``mamba_n_groups`` > 1); the server refuses what it composes with.
+pools and int8 weights; for a model with per-slot state, groups of ``B``
+and ``C`` whose heads do not fill whole 128-lane rows of the packed state;
+the server refuses what it composes with.
 """
 
 import functools
@@ -290,6 +294,39 @@ def _swiglu(x, p):
     return (jax.nn.silu(x @ p["gate"]) * (x @ p["up"])) @ p["down"]
 
 
+def _mlp(x, p, kind):
+    """A dense MLP of an expert kind (moe/held_experts.py): SwiGLU
+    (``silu``) or ``relu(x U)^2 D`` (``relu2``)."""
+    if kind == "silu":
+        return _swiglu(x, p)
+    return jnp.square(jax.nn.relu(x @ p["up"])) @ p["down"]
+
+
+def _products(pos):
+    """What an expert layer's grouped products are called on the device
+    trace: ``expert_decode`` in a decode step (one token a slot), else
+    ``expert_prefill``."""
+    return "expert_decode" if pos.shape[-1] == 1 else "expert_prefill"
+
+
+def _expert_layer(cfg, m, h, real, name):
+    """The expert layer over rows ``h [N, E]`` (``real [N]``: the rows
+    that are tokens, the only ones routed): the router over all of its
+    experts, the held experts' part (one grouped product a weight, called
+    ``name`` on the device trace) and the shared expert, summed in
+    float32. Returns the rows and the counts."""
+    chosen, weights = route(
+        h, m["router"], m["router_bias"], k=cfg.num_experts_per_tok,
+        n_group=cfg.n_group, topk_group=cfg.topk_group,
+        scale=cfg.routed_scaling_factor)
+    routed, counts = held_expert_mlp(
+        h, chosen, weights, m["experts"], cfg.experts_held[0], real,
+        cfg.mlp_hidden_act, name)
+    y = _mlp(h, m["shared"], cfg.mlp_hidden_act).astype(jnp.float32) \
+        + routed
+    return y.astype(h.dtype), counts
+
+
 class _MLAMoEBlocks:
     """The latent-attention block with experts (models/mla_moe.py) over
     its params pytree; one ``kv`` pool whose row is the token's latent.
@@ -363,6 +400,7 @@ class _MLAMoEBlocks:
         cfg = self.cfg
         p = params[f"h_{layer}"]
         a = p["attn"]
+        products = _products(pos)
         pos, real = pos.reshape(-1), real.reshape(-1)
         N, H = x.shape[0], cfg.num_attention_heads
         nope, lora = cfg.qk_nope_head_dim, cfg.kv_lora_rank
@@ -382,15 +420,8 @@ class _MLAMoEBlocks:
         h = _rms(x, p["norm_2"], eps)
         if "moe" not in p:
             return x + _swiglu(h, p["mlp"]), {"kv": row}, None, pools
-        m = p["moe"]
-        chosen, weights = route(
-            h, m["router"], m["router_bias"], k=cfg.num_experts_per_tok,
-            n_group=cfg.n_group, topk_group=cfg.topk_group,
-            scale=cfg.routed_scaling_factor)
-        routed, counts = held_expert_mlp(h, chosen, weights, m["experts"],
-                                         cfg.experts_held[0], real)
-        y = _swiglu(h, m["shared"]).astype(jnp.float32) + routed
-        return x + y.astype(x.dtype), {"kv": row}, counts, pools
+        y, counts = _expert_layer(cfg, p["moe"], h, real, products)
+        return x + y, {"kv": row}, counts, pools
 
 
 class _SSMHybridBlocks:
@@ -406,13 +437,20 @@ class _SSMHybridBlocks:
     granite-4.0-h-micro). A token at position 0 starts from a zero state,
     which serves a reused slot and a recomputed (preempted) request
     alike; a row that is no token (a chunk's pad tail, a frozen slot)
-    leaves the state as it was."""
+    leaves the state as it was. An expert layer holds no cache.
+
+    ``B`` and ``C`` come in ``mamba_n_groups`` groups; a group's heads fill
+    whole 128-lane rows of the packed state (two heads of 64 a row), so
+    that each row of the decode kernel reads one group's."""
 
     def __init__(self, cfg, cache):
-        if cfg.mamba_n_groups != 1:
+        group = cfg.mamba_n_heads // cfg.mamba_n_groups * cfg.mamba_d_head
+        if cfg.mamba_n_groups > 1 and group % ssm.scan.LANES:
             raise ServingNotSupported(
-                f"grouped B and C (mamba_n_groups {cfg.mamba_n_groups}) are "
-                f"not served: every head shares one B and one C here")
+                f"grouped B and C whose group's heads do not fill whole "
+                f"{ssm.scan.LANES}-lane rows of the packed state "
+                f"({cfg.mamba_n_groups} groups of {group} channels) are not "
+                f"served: a row of the decode kernel reads one group's")
         self.cfg = cfg
         self.cache = cache
         self.kv_index = {l: i for i, l in enumerate(cfg.attention_layers)}
@@ -424,7 +462,8 @@ class _SSMHybridBlocks:
 
     def head(self, params, x):
         x = _rms(x, params["norm_f"], self.cfg.rms_norm_eps)
-        return jnp.einsum("be,ve->bv", x, params["embed"],
+        table = params["embed" if self.cfg.tie_word_embeddings else "head"]
+        return jnp.einsum("be,ve->bv", x, table,
                           preferred_element_type=jnp.float32) \
             / self.cfg.logits_scaling
 
@@ -449,7 +488,7 @@ class _SSMHybridBlocks:
         cfg = self.cfg
         B, C = pos.shape
         Hm, P, N = cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state
-        inner, W = cfg.mamba_inner, cfg.conv_channels
+        G, inner, W = cfg.mamba_n_groups, cfg.mamba_inner, cfg.conv_channels
         taps = cfg.mamba_d_conv - 1
         f32 = jnp.float32
         proj = h @ p["in_proj"]
@@ -470,15 +509,19 @@ class _SSMHybridBlocks:
             window[:, k:k + C].astype(f32) * p["conv_w"][k].astype(f32)
             for k in range(taps + 1))
         conv = jax.nn.silu(conv)                        # [B, C, W]
-        # the last ``taps`` inputs up to the last real token
+        # the last ``taps`` inputs up to the last real token (a decode row
+        # that moves on has one: a static slice, which keeps the pool's
+        # layout where the per-slot slice of 384 slots had it relaid)
         n_real = real.sum(axis=1)
-        tail = jax.vmap(lambda w, n: jax.lax.dynamic_slice_in_dim(
-            w, n, taps))(window, n_real).reshape(B, taps * W)
+        tail = (window[:, 1:] if C == 1 else jax.vmap(
+            lambda w, n: jax.lax.dynamic_slice_in_dim(w, n, taps))(
+                window, n_real)).reshape(B, taps * W)
         new_conv = jnp.where((n_real > 0)[:, None], tail.astype(
             prev_conv.dtype), prev_conv)
         live = real.astype(f32)[:, :, None]
         x = (conv[..., :inner] * live).reshape(B, C, Hm, P)
-        b, c = conv[..., inner:inner + N], conv[..., inner + N:]
+        b = conv[..., inner:inner + G * N].reshape(B, C, G, N)
+        c = conv[..., inner + G * N:].reshape(B, C, G, N)
         step = jax.nn.softplus(dt.astype(f32).reshape(B, C, Hm)
                                + p["dt_bias"].astype(f32)) * live
         a = -jnp.exp(p["A_log"].astype(f32))
@@ -515,25 +558,33 @@ class _SSMHybridBlocks:
                 conv=pools["conv"].at[m, slot].set(new_conv[0]))
         y = y + p["D"].astype(f32)[:, None] * x          # [B, C, Hm, P]
         y = y.reshape(B * C, inner) * jax.nn.silu(z.astype(f32))
-        y = _rms(y, p["norm"], cfg.rms_norm_eps)
-        return y.astype(h.dtype) @ p["out_proj"], pools
+        # the gated RMSNorm over each group's channels, one gain
+        y = _rms(y.reshape(B * C, G, inner // G),
+                 p["norm"].reshape(G, inner // G), cfg.rms_norm_eps)
+        return y.reshape(B * C, inner).astype(h.dtype) @ p["out_proj"], \
+            pools
 
     def block(self, layer, params, scales, x, pos, real, attend, pools,
               slot):
-        """One layer over rows ``x [N, E]``: RMSNorm, the layer's mixer
-        (grouped-query attention, or the Mamba-2 mixer over the slots'
-        state), RMSNorm, SwiGLU, each scaled onto the residual. Returns
-        the rows, the K/V rows an attention layer caches (``None`` for a
-        Mamba layer), no expert counts, and the pools."""
+        """One layer over rows ``x [N, E]``: RMSNorm and the layer's part
+        (grouped-query attention, the Mamba-2 mixer over the slots' state,
+        or the expert layer), scaled onto the residual; then, where every
+        mixer is followed by one, RMSNorm and the SwiGLU MLP likewise.
+        Returns the rows, the K/V rows an attention layer caches (``None``
+        for the others), the expert layer's counts (``None`` for the
+        others), and the pools."""
         cfg = self.cfg
         p = params[f"h_{layer}"]
         N = x.shape[0]
         scale = jnp.asarray(cfg.residual_multiplier, x.dtype)
         h = _rms(x, p["norm_1"], cfg.rms_norm_eps)
-        rows = None
+        rows = counts = None
         if layer in self.state_index:
             mixed, pools = self._mamba(self.state_index[layer], p["mamba"],
                                        h, pos, real, pools, slot)
+        elif "moe" in p:
+            mixed, counts = _expert_layer(cfg, p["moe"], h, real.reshape(-1),
+                                          _products(pos))
         else:
             a, D = p["attn"], cfg.head_dim
             q = (h @ a["q"]).reshape(N, -1, D)
@@ -542,12 +593,13 @@ class _SSMHybridBlocks:
             mixed = attend(q, rows["k"], rows["v"]).reshape(N, -1)
             mixed = mixed.astype(x.dtype) @ a["o"]
         x = x + scale * mixed
-        h = _rms(x, p["norm_2"], cfg.rms_norm_eps)
-        gate_up = h @ p["mlp"]["w_in"]
-        width = gate_up.shape[-1] // 2
-        mlp = (jax.nn.silu(gate_up[:, :width]) * gate_up[:, width:]) \
-            @ p["mlp"]["w_out"]
-        return x + scale * mlp, rows, None, pools
+        if cfg.mlp_after_mixer:
+            h = _rms(x, p["norm_2"], cfg.rms_norm_eps)
+            gate_up = h @ p["mlp"]["w_in"]
+            width = gate_up.shape[-1] // 2
+            x = x + scale * ((jax.nn.silu(gate_up[:, :width])
+                              * gate_up[:, width:]) @ p["mlp"]["w_out"])
+        return x, rows, counts, pools
 
 
 class PagedRunner:
@@ -561,7 +613,7 @@ class PagedRunner:
         self.cfg = cfg
         self.cache = cache
         # the expert layers' counts of the dispatches since they were
-        # last taken: int32 [3] device arrays (held_experts.py), which
+        # last taken: int32 [4] device arrays (held_experts.py), which
         # the server lands with the step's tokens; stays empty for a
         # model without experts
         self.expert_counts = []

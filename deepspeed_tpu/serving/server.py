@@ -105,9 +105,10 @@ class _Dispatch:
     toks: object            # [K, B] device array, its host copy under way
     accepted: object        # [B] device array under speculation, else None
     t0: int                 # perf_counter_ns when the dispatch began
-    expert_counts: list     # int32 [3] device arrays: the expert layers'
-    #                         counts of this dispatch and of the prefill
-    #                         chunks queued before it (none without experts)
+    expert_counts: list     # int32 [4] device arrays: the expert layers'
+    #                         counts of the prefill chunks queued before
+    #                         this dispatch and, last, of the dispatch
+    #                         itself (none without experts)
 
 
 @dataclasses.dataclass
@@ -849,9 +850,10 @@ class ServingEngine:
             if spec is not None:
                 self._land("speculation")
             if self._landed_pairs is not None:
-                held, absent, most = self._landed_pairs
+                held, absent, most, _, decoded, touched = self._landed_pairs
                 span.set(pairs_held=held, pairs_absent=absent,
-                         pairs_max=most)
+                         pairs_max=most, decode_pairs_held=decoded,
+                         experts_touched=touched)
 
     def _land(self, reason=None) -> bool:
         """Read back the decode dispatch in flight and hand its tokens
@@ -874,7 +876,7 @@ class ServingEngine:
             counts = [np.asarray(c) for c in flight.expert_counts]
         t1 = time.perf_counter_ns()
         if counts:
-            self._count_expert_pairs(np.sum(counts, axis=0))
+            self._count_expert_pairs(np.sum(counts, axis=0), counts[-1])
         with trace_span("serving_deliver"):
             self._deliver_decoded(flight, toks, accepted, t1)
         if reason is not None:
@@ -891,13 +893,17 @@ class ServingEngine:
             "prefill chunks and decode rows that start a slot's per-slot "
             "state from zero (a request's first token)").inc(n)
 
-    def _count_expert_pairs(self, counts):
+    def _count_expert_pairs(self, counts, decoded):
         """Book what the expert layers of the landed dispatches counted
         (moe/held_experts.py): token-expert choices whose expert is held
-        here and not, and the most pairs that one held expert got in a
-        dispatch and layer, summed."""
-        held, absent, most = (int(n) for n in counts)
-        self._landed_pairs = (held, absent, most)
+        here and not, the most pairs that one held expert got in a
+        dispatch and layer, and the held experts with a pair in one,
+        summed; and of the decode dispatch alone (``decoded``, the last
+        of them) its pairs held and its experts with a pair, which its
+        grouped products read the weights of."""
+        held, absent, most, touched = (int(n) for n in counts)
+        self._landed_pairs = (held, absent, most, touched,
+                              int(decoded[0]), int(decoded[3]))
         for name, n, what in (
                 ("serving_moe_pairs_held_total", held,
                  "token-expert choices whose expert this chip holds"),
@@ -906,6 +912,9 @@ class ServingEngine:
                  "left out of the partial sum"),
                 ("serving_moe_expert_load_max_total", most,
                  "the most pairs any one held expert got, summed over "
+                 "dispatches and expert layers"),
+                ("serving_moe_experts_touched_total", touched,
+                 "held experts with at least one pair, summed over "
                  "dispatches and expert layers")):
             self.registry.counter(name, what).inc(n)
 

@@ -168,7 +168,7 @@ def test_held_experts_equal_a_loop_over_them(real_rows):
     np.testing.assert_allclose(np.asarray(out), want, atol=2e-5)
     assert list(np.asarray(counts)) == [load.sum(),
                                         real.sum() * k - load.sum(),
-                                        load.max()]
+                                        load.max(), (load > 0).sum()]
 
 
 # ----------------------------- (b) absorbed over the paged pool = expanded
@@ -295,7 +295,8 @@ def test_one_decode_and_one_prefill_program_and_the_counters_land():
     from deepspeed_tpu.telemetry import metrics
     registry = metrics.get_registry()
     names = ("serving_moe_pairs_held_total", "serving_moe_pairs_absent_total",
-             "serving_moe_expert_load_max_total")
+             "serving_moe_expert_load_max_total",
+             "serving_moe_experts_touched_total")
     before = {n: registry.counter(n).value for n in names}
     cfg = tiny_config()
     srv = _serve(cfg, {"max_batch": 3, "block_size": 8, "prefill_chunk": 6,
@@ -310,14 +311,16 @@ def test_one_decode_and_one_prefill_program_and_the_counters_land():
     stats = srv.compile_stats()
     assert stats["decode_signatures"] == 1 and stats["retraces"] == 0
     assert stats["prefill_signatures"] == 1
-    held, absent, most = (registry.counter(n).value - before[n]
-                          for n in names)
+    held, absent, most, touched = (registry.counter(n).value - before[n]
+                                   for n in names)
     # every real token of every expert layer makes k choices: the prompts
     # less their last token through prefill, then 9 decode inputs a request
     tokens = sum(n - 1 for n in lens) + 9 * len(lens)
     expert_layers = cfg.num_hidden_layers - cfg.first_k_dense_replace
     assert held + absent == tokens * expert_layers * cfg.num_experts_per_tok
     assert 0 < held < absent and 0 < most <= held
+    # the held experts with a pair, which the expert roofline reads
+    assert 0 < touched <= held
     assert srv.cache.pool_bytes() == (3 * srv.cache.num_blocks * 8 * 128 * 4)
     srv.close()
 

@@ -292,8 +292,10 @@ def test_latent_program_leaves_its_one_pool_in_place(one_chip, monkeypatch,
     widths: one ``kv`` pool of 640-lane rows, aliased to its output and
     never copied whole; the decode walk is the ``paged_decode`` kernel (one
     call a layer, the queries 128 dense rows a slot), and an expert layer
-    two grouped products (Mosaic as well). A prefill chunk needs no head,
-    so the compiler drops the last layer's MLP and its two products."""
+    two grouped products (Mosaic as well, named by the phase: the trace's
+    operations that the expert roofline reads). A prefill chunk needs no
+    head, so the compiler drops the last layer's MLP and its two
+    products."""
     from deepspeed_tpu.moe import held_experts
     monkeypatch.setattr(paged_attention, "_interpret", lambda: False)
     monkeypatch.setattr(held_experts, "_interpret", lambda: False)
@@ -307,6 +309,10 @@ def test_latent_program_leaves_its_one_pool_in_place(one_chip, monkeypatch,
     kernels = text.count('custom_call_target="tpu_custom_call"')
     assert kernels == {"decode": 3 + 4, "prefill": 2, "copy_block": 0}[
         program], f"{kernels} Mosaic calls in the {program} program"
+    calls = re.findall(r"%([A-Za-z_]+)[.\d]* = [^\n]*tpu_custom_call", text)
+    assert sorted(set(calls)) == {
+        "decode": ["expert_decode", "paged_decode"],
+        "prefill": ["expert_prefill"], "copy_block": []}[program]
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= cache.pool_bytes()
     copies = _pool_copies(text, pools.values())
@@ -429,6 +435,101 @@ def test_state_programs_hold_one_state_pool(one_chip, monkeypatch, program):
         f"{state / 1e6:.1f} MB of state pools")
     copies = _pool_copies(text, pools.values())
     assert not copies, "whole-pool copies:\n" + "\n".join(copies)
+
+
+def _expert_state_programs(one_chip, slots=384, num_blocks=45710, chunk=128):
+    """The decode and prefill programs of the hybrid whose layers are one
+    part each, at the published widths of
+    benchmark/configs/NVIDIA-Nemotron-3-Nano-30B-A3B-BF16.json: a Mamba
+    layer (8 groups of B and C), an expert layer (64 held of 128, squared
+    ReLU), the attention layer and a second Mamba layer, 128 rows of
+    vocabulary, at the cell's slots, blocks and chunk (two rows a prefill
+    call)."""
+    from deepspeed_tpu.models.ssm_hybrid import (SSMHybridConfig,
+                                                 SSMHybridForCausalLM,
+                                                 init_params)
+    from deepspeed_tpu.serving.runner import (PagedRunner, cache_layers,
+                                              cache_rows)
+    spec = _spec(one_chip)
+    cfg = SSMHybridConfig(
+        vocab_size=128, hidden_size=2688, intermediate_size=1856,
+        layer_types=("mamba", "moe", "attention", "mamba"),
+        num_attention_heads=32,
+        num_key_value_heads=2, head_dim=128, mamba_n_heads=64,
+        mamba_d_head=64, mamba_d_state=128, mamba_n_groups=8,
+        max_position_embeddings=262144, attention_multiplier=128 ** -0.5,
+        tie_word_embeddings=False, mlp_after_mixer=False,
+        n_routed_experts=128, experts_held=(0, 64), num_experts_per_tok=6,
+        moe_intermediate_size=1856, moe_shared_expert_intermediate_size=3712,
+        routed_scaling_factor=2.5)
+    params = jax.eval_shape(
+        lambda: init_params(cfg, jax.random.PRNGKey(0), jnp.bfloat16))
+    params = jax.tree.map(lambda a: spec(a.shape, jnp.bfloat16), params)
+    layers = cache_layers(cfg)
+    cache = PagedKVCache(n_layer=layers["paged"], block_size=BLOCK_SIZE,
+                         num_blocks=num_blocks, dtype=jnp.bfloat16,
+                         state_layers=layers["per_slot"], slots=slots,
+                         **cache_rows(cfg))
+    runner = PagedRunner(SSMHybridForCausalLM(cfg), cache)
+    pools = {name: spec(shape, dtype)
+             for name, (shape, dtype) in cache._pool_shapes().items()}
+    B, R, i32, f32 = slots, prefill_rows(chunk, slots), jnp.int32, \
+        jnp.float32
+    return cache, pools, params, {
+        "decode": (runner._decode,
+                   [params, {}, pools, spec((B, MAX_BLOCKS), i32),
+                    spec((B,), i32), spec((B,), jnp.bool_), spec((B,), i32),
+                    spec((B,), f32), spec((B,), f32),
+                    spec((B, 2), jnp.uint32), spec((B,), i32),
+                    spec((1, B), i32), spec((B,), i32)]),
+        "prefill": (runner._prefill,
+                    [params, {}, pools, spec((R, MAX_BLOCKS), i32),
+                     spec((R, chunk), i32), spec((R,), i32), spec((R,), i32),
+                     spec((R,), i32)]),
+    }
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_expert_state_programs_read_the_weights_where_they_lie(
+        one_chip, monkeypatch, program):
+    """The one-part-a-layer hybrid's programs compile for the v5e at the
+    published widths and the cell's 384 slots: no pool and no weight is
+    copied whole (an expert's up matrix held ``[1856, 2688]``, as
+    published, fills whole 128-lane tiles; held ``[2688, 1856]`` the
+    compiler laid it out the other way and copied all 64 experts' to the
+    kernel's layout in every decode step: 0.68 GB of temporaries), the
+    temporaries stay under 5% of the state pools, and decode runs an
+    ``ssm_decode`` call a Mamba layer, the expert layer's two grouped
+    products under their phase's name and one ``paged_decode`` call. (A
+    Mamba layer's convolution rows, 14 MB of the pool at 384 slots, are
+    still relaid out and back in each decode step: PERF.md, section 7.)"""
+    from deepspeed_tpu.moe import held_experts
+    from deepspeed_tpu.ops.ssm import decode as ssm_decode
+    monkeypatch.setattr(paged_attention, "_interpret", lambda: False)
+    monkeypatch.setattr(ssm_decode, "_interpret", lambda: False)
+    monkeypatch.setattr(held_experts, "_interpret", lambda: False)
+    monkeypatch.setattr(groups, "_MESH", None)
+    cache, pools, params, programs = _expert_state_programs(one_chip)
+    assert {n: tuple(s.shape) for n, s in pools.items()} == {
+        "k": (45710, 16, 256), "v": (45710, 16, 256),
+        "ssm": (2, 384, 32, 128, 128), "conv": (2, 384, 3 * 6144)}
+    fn, args = programs[program]
+    compiled = fn.lower(*args).compile()
+    text = compiled.as_text()
+    calls = re.findall(r"%([A-Za-z_]+)[.\d]* = [^\n]*tpu_custom_call", text)
+    assert sorted(calls) == {
+        "decode": ["expert_decode", "expert_decode", "paged_decode",
+                   "ssm_decode", "ssm_decode"],
+        "prefill": ["expert_prefill", "expert_prefill"]}[program]
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= cache.pool_bytes()
+    state = cache.pool_bytes("per_slot")
+    assert mem.temp_size_in_bytes < 0.05 * state, (
+        f"{mem.temp_size_in_bytes / 1e6:.1f} MB of temporaries beside "
+        f"{state / 1e6:.1f} MB of state pools")
+    copies = _pool_copies(text, list(pools.values()) + [
+        a for a in jax.tree.leaves(params) if a.size > 1 << 20])
+    assert not copies, "whole-array copies:\n" + "\n".join(copies)
 
 
 # ------------------ one row a dispatch at the wide chunks (latent, state)

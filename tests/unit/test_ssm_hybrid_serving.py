@@ -60,7 +60,7 @@ def test_the_chunked_scan_equals_the_recurrence(start):
     a = -jnp.exp(_rnd(5, (H,)))
     s0 = (jnp.zeros((H, P, N)) if start == "zero"
           else _rnd(6, (H, P, N)))
-    y, s_end = ssm.chunk_scan(x, b, c, dt, a, s0)
+    y, s_end = ssm.chunk_scan(x, b[:, None], c[:, None], dt, a, s0)
     want_y, want_s = _recurrence(x, b, c, dt, a, s0)
     np.testing.assert_allclose(y, want_y, rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(s_end, want_s, rtol=1e-5, atol=1e-5)
@@ -129,9 +129,10 @@ def test_the_decode_kernel_equals_jnp_and_keeps_frozen_slots(live):
     u = _rnd(5, (B, R, 128))
     live = jnp.asarray(live, bool)
     fresh = jnp.asarray([False, False, True]) & live
-    y, out = ssm_decode._decode_call(pool, 1, b, c, decay, u, live, fresh,
-                                     interpret=True)
-    want_y, want_s = ssm_decode.step(pool[1], b, c, decay, u, live, fresh)
+    y, out = ssm_decode._decode_call(pool, 1, b[:, None], c[:, None], decay,
+                                     u, live, fresh, interpret=True)
+    want_y, want_s = ssm_decode.step(pool[1], b[:, None], c[:, None], decay,
+                                     u, live, fresh)
     np.testing.assert_allclose(y, want_y, rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(out[1], want_s, rtol=1e-6, atol=1e-6)
     np.testing.assert_array_equal(out[0], pool[0])
@@ -187,7 +188,8 @@ def test_the_decode_state_keeps_float32_over_the_longest_request(
         return jnp.asarray(v[None], jnp.float32)
 
     for t in range(T):
-        y, pool = update(pool, 0, f32(x[t]), f32(b[t]), f32(c[t]),
+        y, pool = update(pool, 0, f32(x[t]), f32(b[t][None]),
+                         f32(c[t][None]),
                          f32(dt[t]), jnp.asarray(a, jnp.float32),
                          jnp.ones((1,), bool), jnp.asarray([t == 0]))
         got.append(np.asarray(y[0]))
